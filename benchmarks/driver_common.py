@@ -23,7 +23,9 @@ gate rides on:
 Importing this module puts the repo root on ``sys.path`` (the drivers
 live in ``benchmarks/`` which is inserted by each driver's two-line
 header), so ``from opendht_tpu import ...`` works however the driver
-is launched — CLI, heredoc, or ``spec_from_file_location``.
+is launched — CLI, heredoc, or ``spec_from_file_location`` — and
+places the persistent compile cache (``opendht_tpu.compile_cache``):
+every driver imports this before its first compile.
 """
 
 from __future__ import annotations
@@ -36,6 +38,10 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
+
+from opendht_tpu.compile_cache import ensure_compile_cache  # noqa: E402
+
+ensure_compile_cache()
 
 CAPTURES = os.path.join(ROOT, "captures")
 

@@ -113,7 +113,7 @@ def main(argv=None) -> int:
     rec = {
         "driver": "exp_chaos_r18",
         "platform": jax.devices()[0].platform,
-        # headline row for ci/assemble_trajectory.py's captures section
+        # headline row of the capture
         "metric": ("p50 swarm_step wall-clock per tick, %d-node storm"
                    % args.nodes),
         "unit": "ms",

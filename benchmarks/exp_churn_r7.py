@@ -15,7 +15,7 @@ attribute cost with fusion effects included.  The variants:
               platform)
   unpacked    merge_pack=1 — the pre-round-7 row-per-query merge;
               (unpacked − packed) is the measured lane-packing win at
-              this shape, the number VERDICT r5 weak #1 asked for
+              this shape, the number the round-5 review asked for
   no_merge    base lookup + delta cascade, results consumed but never
               merged; (full − no_merge) bounds the whole merge stage
   no_rebuild  pre-built delta structures; (full − no_rebuild) is the

@@ -215,8 +215,8 @@ def main(argv=None) -> int:
     if "t4" in results:
         r4 = results["t4"]
         rec["swap_ms"] = r4["swap_ms"]
-        # trajectory headline (ci/assemble_trajectory.py convention):
-        # the t=4 rebalance factor under the Zipf flood
+        # capture headline: the t=4 rebalance factor under the Zipf
+        # flood
         rec["metric"] = (
             "load-aware resharding: max/mean shard load imbalance of a "
             "Zipf(%.1f) stream folded at the uniform t=4 split vs the "

@@ -1,7 +1,7 @@
 """CI perf-regression gate over the kernel cost ledger (ROADMAP item 3).
 
-Five rounds of kernel perf (920× → 46× → 121× → 131× → 213×,
-PERF_TRAJECTORY.json) previously had no gate: a refactor could double a
+Five rounds of kernel perf (920× → 46× → 121× → 131× → 213× vs the
+scalar baseline) previously had no gate: a refactor could double a
 kernel's HBM traffic and every tier-1 test would stay green.  This gate
 closes that hole with the only perf signal that is DETERMINISTIC on a
 shared CPU runner — the XLA cost model of each shipped kernel lowered

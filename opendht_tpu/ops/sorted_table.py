@@ -1037,7 +1037,8 @@ def packed_churn_merge(m_dist, m_idx, d_dist, d_idx, n_base, *, k: int,
     minor dim to 128 lanes: at the protocol k=8 each elementwise mask /
     sentinel / sort step moves 16× the useful bytes — measured ~8 ms of
     the 13.6 ms churny-vs-static gap at 131K queries
-    (benchmarks/exp_churn2_r5.py, VERDICT r5 weak #1).  The standard
+    (benchmarks/exp_churn2_r5.py, the round-5 review's weak point 1).
+    The standard
     lane-occupancy trick from batched serving kernels applies because
     the per-query merges are independent: pack P queries' k-lane planes
     into one [Q/P, P·k] physical row (P·k = 128 exactly at k=8), pay

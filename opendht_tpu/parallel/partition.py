@@ -53,26 +53,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax moved shard_map out of experimental AND (separately, later)
-# renamed check_rep → check_vma; the two changes don't coincide, so the
-# kwarg is chosen by the resolved function's own signature rather than
-# by where it lives (a mid-window release has top-level jax.shard_map
-# that still takes check_rep).  Resolved once here; parallel/sharded.py
-# imports the resolved pair so every shard_map builder in the package
-# is version-agnostic.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:                                     # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
-import inspect as _inspect
-try:
-    _sm_params = _inspect.signature(shard_map).parameters
-except (TypeError, ValueError):           # C-level/odd callables
-    _sm_params = {}
-SHARD_MAP_KW = ({"check_vma": False} if "check_vma" in _sm_params
-                else {"check_rep": False} if "check_rep" in _sm_params
-                else {})
-
 
 def tree_paths(tree):
     """Pytree of '/'-joined string names, one per leaf (dict keys and
@@ -264,11 +244,11 @@ def _build_state_luts(mesh: Mesh, shard_n: int, lut_bits: int,
         block_lut = lax.psum(part, "t")
         return lut[None], block_lut
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("t", None), P()),
         out_specs=(P("t", None), P()),
-        **SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -291,11 +271,11 @@ def _build_state_luts_weighted(mesh: Mesh, lut_bits: int, block_bits: int):
         block_lut = lax.psum(part, "t")
         return lut[None], block_lut
 
-    fn = shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("t", None), P("t", None)),
         out_specs=(P("t", None), P()),
-        **SHARD_MAP_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -51,11 +51,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# the shard_map version shim (experimental move + check_rep→check_vma
-# rename) lives with the declarative placement layer now; both names
-# are re-exported here for the callers that grew up against them
-from .partition import (shard_map as _shard_map, SHARD_MAP_KW as _SM_KW,
-                        TABLE_AXIS_RULES, DP_AXIS_RULES, TableState,
+from .partition import (TABLE_AXIS_RULES, DP_AXIS_RULES, TableState,
                         shard_put, shard_table_state)
 
 from ..ops.ids import N_LIMBS
@@ -137,11 +133,11 @@ def _build_sharded_xor_topk(mesh: Mesh, k: int, tile: int, shard_n: int):
         gidx = jnp.where(idx >= 0, idx + ti * shard_n, -1)
         return _gather_and_merge(dist, gidx, n_t, k)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("q", None), P("t", None), P("t")),
         out_specs=(P("q", None, None), P("q", None)),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -176,11 +172,11 @@ def _build_sharded_sort(mesh: Mesh):
         sorted_ids, perm, n_valid = sort_table(tbl, val)
         return sorted_ids, perm, jnp.asarray(n_valid, jnp.int32)[None]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("t", None), P("t")),
         out_specs=(P("t", None), P("t"), P("t")),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -209,11 +205,11 @@ def _build_sharded_expand(mesh: Mesh, bits: int):
         lut = build_prefix_lut(sorted_ids, n_valid_shard[0], bits=bits)
         return expanded, lut[None]
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("t", None), P("t")),
         out_specs=(P("t", None), P("t", None)),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -270,12 +266,12 @@ def _build_sharded_window_lookup(mesh: Mesh, k: int, window: int,
         gidx = jnp.where(rows >= 0, rows + ti * shard_n, -1)
         return _gather_and_merge(dist2, gidx, n_t, k)
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("q", None), P("t", None), P("t"), P("t"),
                   P("t", None), P("t", None)),
         out_specs=(P("q", None, None), P("q", None)),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -435,12 +431,12 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     in_specs = ((P("t", None), P("t", None), P(), P(), P("t", None),
                  P("q", None), P()) if weighted else
                 (P("t", None), P("t", None), P(), P(), P("q", None), P()))
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs={"nodes": P("q", None), "dist": P("q", None, None),
                    "hops": P("q"), "converged": P("q")},
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -549,11 +545,11 @@ def _build_sharded_maintenance(mesh: Mesh):
             self_id, jnp.arange(radix.ID_BITS, dtype=jnp.int32), key)
         return counts, last, stale, targets
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P("t", None), P("t"), P("t"), P(), P(), P()),
         out_specs=(P(), P(), P(), P(None, None)),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -622,11 +618,11 @@ def _build_sharded_sketch(mesh: Mesh, depth: int, width: int):
         ph = jnp.zeros_like(hist).at[bins].add(w)
         return sketch + lax.psum(part, "t"), hist + lax.psum(ph, "t")
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P("t", None), P("t")),
         out_specs=(P(), P()),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -671,11 +667,11 @@ def _build_sharded_cache_probe(mesh: Mesh, capacity: int):
                          jnp.int32(-1))
         return hit, slot
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P("t", None)),
         out_specs=(P("t"), P("t")),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 
@@ -720,11 +716,11 @@ def _build_sharded_listener_match(mesh: Mesh, capacity: int):
                          jnp.int32(-1))
         return hit, slot
 
-    fn = _shard_map(
+    fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P("t", None)),
         out_specs=(P("t"), P("t")),
-        **_SM_KW,
+        check_vma=False,
     )
     return jax.jit(fn)
 

@@ -1,7 +1,7 @@
 """Kernel cost ledger: what every shipped kernel costs, by construction.
 
 Five rounds of kernel work (920× → 46× → 121× → 131× → 213× vs the
-scalar baseline, PERF_TRAJECTORY.json) are protected by wall-clock
+scalar baseline) are protected by wall-clock
 smokes only — and wall-clock on shared CPU runners is noise.  The
 device-side costs XLA itself computes are not: for a fixed kernel at a
 fixed shape, the lowered executable's ``cost_analysis()`` (flops, bytes
@@ -64,28 +64,27 @@ __all__ = [
 ]
 
 # --------------------------------------------------------------------------
-# Per-platform peaks for roofline attribution.  Matched by substring on
-# jax's device_kind (first) then platform name.  These are ATTRIBUTION
+# Per-device peaks for roofline attribution, keyed by the ``device_kind``
+# string jax reports (compared lower-cased), each with its source.  A
+# device that is not in the table is an error, never a default: a share
+# of an assumed peak is not a measurement.  These are ATTRIBUTION
 # DENOMINATORS, not claims: the committed budgets gate the cost model
 # (deterministic), never the roofline % (which inherits wall-clock
-# noise and these nominal peaks).  The cpu row is deliberately coarse —
-# a shared CI runner has no stable peak; its roofline output is labeled
-# indicative.  TPU rows are the published per-chip numbers.
+# noise and these nominal peaks).
 # --------------------------------------------------------------------------
 PLATFORM_PEAKS = {
-    # device_kind/platform substring -> peaks (per chip)
-    "v5e":  {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
-             "note": "TPU v5e: 197 TFLOP/s bf16, 819 GB/s HBM"},
-    "v5p":  {"flops_per_s": 459e12, "hbm_bytes_per_s": 2765e9,
-             "note": "TPU v5p: 459 TFLOP/s bf16, 2765 GB/s HBM"},
-    "v4":   {"flops_per_s": 275e12, "hbm_bytes_per_s": 1228e9,
-             "note": "TPU v4: 275 TFLOP/s bf16, 1228 GB/s HBM"},
-    "tpu":  {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
-             "note": "unrecognized TPU generation: v5e numbers assumed"},
-    "cpu":  {"flops_per_s": 2e11, "hbm_bytes_per_s": 2e10,
-             "note": "nominal shared-runner core (indicative only)"},
-    "gpu":  {"flops_per_s": 312e12, "hbm_bytes_per_s": 2039e9,
-             "note": "A100-class default (indicative)"},
+    # one v5e chip reports device_kind "TPU v5 lite" (read on the chip
+    # by chip_smoke.py: jax 0.9.0 / libtpu 0.0.34)
+    "tpu v5 lite": {
+        "flops_per_s": 197e12, "hbm_bytes_per_s": 819e9,
+        "note": "TPU v5e, per chip: 197 TFLOP/s bf16, 819 GB/s HBM "
+                "(Google Cloud documentation, \"TPU v5e\")"},
+    # the CPU backend the tests run on — a shared runner has no stable
+    # peak; anything divided by this row is indicative, never a device
+    # figure
+    "cpu": {
+        "flops_per_s": 2e11, "hbm_bytes_per_s": 2e10,
+        "note": "nominal shared-runner core, for tests (indicative only)"},
 }
 
 
@@ -109,15 +108,15 @@ def platform_peaks(device=None) -> dict:
 
 
 def _match_peaks(device) -> dict:
-    kind = (getattr(device, "device_kind", "") or "").lower()
-    plat = (getattr(device, "platform", "") or "").lower()
-    for key, row in PLATFORM_PEAKS.items():
-        if key in kind:
-            return dict(row, peak_key=key)
-    for key, row in PLATFORM_PEAKS.items():
-        if key in plat:
-            return dict(row, peak_key=key)
-    return dict(PLATFORM_PEAKS["cpu"], peak_key="cpu")
+    kind = getattr(device, "device_kind", "") or ""
+    key = kind.lower()
+    row = PLATFORM_PEAKS.get(key)
+    if row is None:
+        raise KeyError(
+            f"no peaks recorded for device_kind {kind!r} (known: "
+            f"{sorted(PLATFORM_PEAKS)}); add its published per-chip "
+            "peaks, with their source, to profiling.PLATFORM_PEAKS")
+    return dict(row, peak_key=key)
 
 
 # --------------------------------------------------------------------------
@@ -547,12 +546,8 @@ KERNEL_SPECS = {
 
 
 def _cost_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions (a
-    dict on new jax, a 1-list of dicts on older) to one flat dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``compiled.cost_analysis()`` as a plain dict (``None`` → empty)."""
+    return dict(compiled.cost_analysis() or {})
 
 
 class KernelLedger:

@@ -367,15 +367,24 @@ class Dht:
         sort, windowed top-k) so the first real packet doesn't stall the
         protocol thread behind a multi-second first-compile.  The top-k
         kernel is specialized per static ``k``, so warm every k the live
-        path uses.  Compiled executables are cached per-process."""
-        now = self.scheduler.time()
+        path uses.  Compiled executables are cached per-process.
+
+        Resolves through :meth:`find_closest_nodes_batched` — the route
+        the request handlers and the ingest waves take — so a node with
+        a resolve mesh warms the sharded executable it will serve with,
+        not the single-device one.  An empty table has nothing to
+        compile (``DhtRunner.run`` calls this before any peer is
+        known); a node that loads its table afterwards calls it again.
+        A failure is logged at error level and the node starts anyway
+        (the first request then pays the compile, or fails the same
+        way in the open)."""
         target = [InfoHash.get_random()]
-        for table in self.tables.values():
+        for af in self.tables:
             try:
                 for k in (TARGET_NODES, SEARCH_NODES):
-                    table.find_closest(target, k=k, now=now)
+                    self.find_closest_nodes_batched(target, af, k)
             except Exception:
-                log.debug("kernel warmup failed", exc_info=True)
+                log.exception("kernel warmup failed")
 
     # ======================================================== routing plumbing
     def find_closest_nodes(self, target: InfoHash, af: int,

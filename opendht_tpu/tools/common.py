@@ -26,7 +26,7 @@ crypto = lazy_module("opendht_tpu.crypto")
 # canonical definition lives in the (crypto-free) package __init__ so
 # the virtual harness can use it without this module's runner imports;
 # re-exported here for the CLI tools and back-compat
-from . import force_cpu_jax  # noqa: F401,E402
+from . import force_cpu_jax, require_tpu  # noqa: F401,E402
 
 
 def make_arg_parser(description: str) -> argparse.ArgumentParser:
@@ -49,10 +49,11 @@ def make_arg_parser(description: str) -> argparse.ArgumentParser:
     p.add_argument("--proxyclient", default="",
                    help="use a REST proxy at host:port instead of UDP")
     p.add_argument("--tpu", action="store_true",
-                   help="let JAX pick the accelerator backend (default: "
-                        "force CPU — a CLI node's tables are small, and "
-                        "first-time accelerator init would stall the "
-                        "protocol thread)")
+                   help="serve from the TPU, or exit non-zero if JAX finds "
+                        "none (default: the CPU backend — a CLI node's "
+                        "table is small and resolves on the host, and a "
+                        "chip belongs to one process, so a cluster of "
+                        "CLI nodes could not share it)")
     return p
 
 
@@ -95,7 +96,9 @@ def setup_node(args) -> DhtRunner:
     tools/dhtnode.cpp:480-545)."""
     if args.verbose:
         logging.basicConfig(level=logging.DEBUG)
-    if not getattr(args, "tpu", False):
+    if getattr(args, "tpu", False):
+        require_tpu()
+    else:
         force_cpu_jax()
     ident = None
     if args.save_identity:

@@ -371,21 +371,14 @@ class OpenBoundTracker:
                            if v.get("open")}
         except Exception:
             pass                    # no budgets file: tracker degrades
-        self.platform = self._detect_platform()
+        import jax
+        self.platform = str(jax.default_backend())
         self.status = ("unsettled" if self.platform == "cpu"
                        else "candidate")
         self._g = {k: self._reg.gauge("dht_open_bound", key=k,
                                       status=self.status)
                    for k in self.bounds}
         self._last: Dict[str, Optional[float]] = {}
-
-    @staticmethod
-    def _detect_platform() -> str:
-        try:
-            import jax
-            return str(jax.default_backend())
-        except Exception:
-            return "cpu"
 
     # -------------------------------------------------------- measurements
     def _measure(self, key: str) -> Optional[float]:

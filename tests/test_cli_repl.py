@@ -46,8 +46,12 @@ def net():
     node.join()
 
 
-def repl(node, script, monkeypatch):
-    """Run cmd_loop feeding `script` lines; returns captured stdout."""
+def repl(node, script, monkeypatch, until=""):
+    """Run cmd_loop feeding `script` lines; returns captured stdout.
+    ``until``: text an asynchronous command (``q?``) prints from the DHT
+    thread — waited for (bounded) while stdout is still redirected, so
+    the late print lands here and not in the middle of pytest's own
+    progress line."""
     lines = iter(script)
 
     def fake_input(prompt=""):
@@ -60,6 +64,8 @@ def repl(node, script, monkeypatch):
     out = io.StringIO()
     with contextlib.redirect_stdout(out):
         cmd_loop(node, None)
+        if until:
+            wait_for(lambda: until in out.getvalue(), timeout=10.0)
     return out.getvalue()
 
 
@@ -78,7 +84,7 @@ def test_repl_core_ops(net, monkeypatch):
         "bogus-op",
         "g",                      # missing argument
         "x",
-    ], monkeypatch)
+    ], monkeypatch, until="fields:")
     assert "Put: True" in out
     assert "hello from repl" in out and re.search(r"Get: \d+ value", out)
     assert "PutSigned: True" in out

@@ -237,16 +237,3 @@ def test_snapshot_folds_live_series(ledger):
     e = snap["maintenance_sweep"]
     assert e["series"] == "dht_maintenance_sweep_seconds"
     assert e["live_count"] >= 1 and e["live_p50_s"] > 0
-
-
-# ------------------------------------------------------------ trajectory
-def test_trajectory_committed_and_in_sync():
-    """PERF_TRAJECTORY.json must exist and equal a fresh assembly of
-    its sources (BENCH_r*/captures/TP_SCALING) — the same both-ways
-    check ci/check_docs.py runs."""
-    asm = _load_ci_module("assemble_trajectory")
-    assert asm.main(["--check"]) == 0
-    fresh = asm.build()
-    claimed = [r for r in fresh["rounds"] if "superseded" not in r]
-    assert len(claimed) >= 4
-    assert all(r["vs_baseline"] for r in fresh["rounds"])

@@ -61,7 +61,7 @@ def test_ipv6_dual_stack_put_get():
 
 def test_ipv6_python_fallback_put_get():
     """v6 with the native engine DISABLED: the Python-socket fallback
-    path must keep serving dual-stack on its own (VERDICT r5 ask 7's
+    path must keep serving dual-stack on its own (the round-5 review's
     'Python fallback preserved' clause — the native v6 path is covered
     by test_native.py and test_ipv6_dual_stack_put_get)."""
     import socket

@@ -86,3 +86,16 @@ def test_arg_parser_defaults():
         ["-p", "4222", "-b", "h:1", "-i", "--proxyserver", "8080"])
     assert (args.port, args.bootstrap, args.identity, args.proxyserver) == \
         (4222, "h:1", True, 8080)
+
+
+def test_tpu_flag_exits_non_zero_without_a_tpu():
+    """``--tpu`` gets the chip or the node does not start — it must
+    never come up on the CPU backend unnoticed (the tests run on it)."""
+    from opendht_tpu.tools import require_tpu
+    from opendht_tpu.tools.common import setup_node
+    with pytest.raises(SystemExit) as e:
+        require_tpu()
+    assert e.value.code not in (0, None)
+    args = make_arg_parser("t").parse_args(["--tpu"])
+    with pytest.raises(SystemExit):
+        setup_node(args)
