@@ -7,7 +7,7 @@ must cost < 3% over the tracer-disabled run — inside the band
 `captures/telemetry_overhead.json` established — and with sampling
 OFF (tracer enabled but no context active, the production idle state)
 the cost must be unmeasurable (< 0.5%).  The instrumentation is
-host-side only: the wave/round spans are recorded from the envelope's
+host-side only: the wave span is recorded from the envelope's
 already-measured elapsed AFTER the compiled computation returns, so
 the expectation is noise-level; this driver measures both modes and
 commits the result as ``captures/trace_overhead.json``.
@@ -153,7 +153,7 @@ def main(argv=None) -> int:
         "note": "8192-wave search round, median of per-rep paired "
                 "deltas over rotation-interleaved trips (per-mode "
                 "medians also recorded): traced (root context active, "
-                "wave+round spans recorded) / enabled-but-untraced vs "
+                "wave span recorded) / enabled-but-untraced vs "
                 "tracer disabled (host-side envelope only; same "
                 "executable; telemetry on in all modes)",
     }

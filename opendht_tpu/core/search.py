@@ -73,6 +73,7 @@ from ..ops.radix import _PREFIX_MASKS
 from ..ops.sorted_table import (_lex_lt, _lower_bound, _lut_bits,
                                 build_prefix_lut, default_lut_bits,
                                 fused_gather_planar, lut_budget_steps)
+from ..telemetry import device_stage
 
 _U32 = jnp.uint32
 
@@ -304,6 +305,18 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     table-sharded twin the same change removes one of the per-round
     psum sites (parallel/sharded.py).
 
+    DEVICE STAGES: each part of a round runs through
+    ``telemetry.device_stage`` — ``select``, ``block_bounds``,
+    ``reply_rows``, ``fetch_ids``, ``merge``, ``converge`` — an inner
+    jit named ``stage_<name>``.  A part that is a function of its
+    arguments alone is decorated where it is defined; one that closes
+    over values of the trace it runs in (the table, the LUT, the seed)
+    is wrapped where it is CALLED, a fresh jit per call.  XLA inlines
+    them; their only effect is the ``jit(stage_<name>)`` component in every
+    operation's ``op_name``, by which a device trace charges kernel
+    time to a stage.  The bootstrap round outside the loop goes through
+    the same functions and so the same names.
+
     ``q_index``/``q_total`` are each query's GLOBAL index and the global
     batch size — the deterministic reply hash is seeded by global query
     identity, so a sharded run is bit-identical to the unsharded one.
@@ -338,6 +351,12 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
     pos_t_full = lower(targets)                        # [Q], fallback replies
 
+    def fetch_ids(rows, limbs):
+        """Stage ``fetch_ids``: limb planes of table rows — the round's
+        one fused gather, and the final id fetch."""
+        return device_stage("fetch_ids")(
+            lambda r: gather_planar(r, limbs))(rows)
+
     def reply_gather(tgt, pt, qidx, x_rows, round_no, x_d0=None):
         """Simulated answers of the α queried nodes per search.
         x_rows [W, alpha] int32 (−1 = no request) → node rows [W, R].
@@ -346,7 +365,6 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         carried from the candidate state (the ROUND-FUSED form — see
         the round body), or None to gather it from the table (the
         bootstrap call, whose peer is not a candidate yet)."""
-        W = tgt.shape[0]
         if block_bounds is not None:
             # 1-LIMB cb: the LUT block read clamps prefixes at its
             # ≤24-bit width, so any cb ≥ 32 yields the same clamped
@@ -361,18 +379,31 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             # gather is the fused [W·α·k] reply gather in merge().
             # block_mode="exact" keeps the full-width gathered path.
             if x_d0 is None:
-                x0 = gather_planar(x_rows, 1)[0]
-                x_d0 = x0 ^ tgt[:, 0:1]
-            b = clz32(x_d0)                      # clz32(0) == 32 by contract
-            lo, ub = block_bounds(tgt[:, 0:1], b + 1)
+                x_d0 = fetch_ids(x_rows, 1)[0] ^ tgt[:, 0:1]
+
+            def edges(x_d0):
+                b = clz32(x_d0)                  # clz32(0) == 32 by contract
+                return block_bounds(tgt[:, 0:1], b + 1)
+
+            lo, ub = device_stage("block_bounds")(edges)(x_d0)
         else:
-            x_l = gather_planar(x_rows, N_LIMBS)     # full ids: exact cb
-            t_l = [tgt[:, l:l + 1] for l in range(N_LIMBS)]
-            b = _common_bits_planar(x_l, t_l)                        # [W,a]
-            prefix_len = jnp.clip(b + 1, 0, ID_BITS)
-            lo, ub = _prefix_block_bounds(lower, n, tgt[:, None, :]
-                                          .repeat(x_rows.shape[1], 1),
-                                          prefix_len)
+            def edges(x_l):
+                t_l = [tgt[:, l:l + 1] for l in range(N_LIMBS)]
+                b = _common_bits_planar(x_l, t_l)                    # [W,a]
+                prefix_len = jnp.clip(b + 1, 0, ID_BITS)
+                return _prefix_block_bounds(lower, n, tgt[:, None, :]
+                                            .repeat(x_rows.shape[1], 1),
+                                            prefix_len)
+
+            lo, ub = device_stage("block_bounds")(edges)(
+                fetch_ids(x_rows, N_LIMBS))          # full ids: exact cb
+        return device_stage("reply_rows")(reply_rows)(
+            pt, qidx, x_rows, round_no, lo, ub)
+
+    def reply_rows(pt, qidx, x_rows, round_no, lo, ub):
+        """The reply model proper: block edges → the α·k sampled (or
+        fallback-window) rows per search."""
+        W = pt.shape[0]
         size = jnp.maximum(ub - lo, 0)                                     # [W,a]
 
         qi = qidx.astype(_U32)[:, None, None]          # GLOBAL query ids
@@ -401,12 +432,18 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         return rows.reshape(W, R)
 
     def merge(tgt, cand_node, cand_l, queried, new_rows):
+        """Fetch the replies' ids (stage ``fetch_ids``) and insert them
+        (stage ``merge``)."""
+        return insert(tgt, cand_node, cand_l, queried, new_rows,
+                      fetch_ids(new_rows, NL))                    # NL×[W,R]
+
+    @device_stage("merge")
+    def insert(tgt, cand_node, cand_l, queried, new_rows, new_l):
         """Insert replies, dedupe by node, keep the S closest
         (↔ Search::insertNode, src/search.h:636-722).  ``cand_l`` is the
         candidate distance as NL limb planes [W, S]; everything stays
         2-D."""
         W = tgt.shape[0]
-        new_l = gather_planar(new_rows, NL)                       # NL×[W,R]
         node = jnp.concatenate([cand_node, new_rows], axis=1)     # [W,S+R]
         d_l = [jnp.concatenate([cand_l[l], new_l[l] ^ tgt[:, l:l + 1]],
                                axis=1) for l in range(NL)]
@@ -456,6 +493,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     cand_node, cand_l, queried = merge(targets, cand_node, cand_l, queried,
                                        first)
 
+    @device_stage("converge")
     def synced(cand_node, queried):
         """First min(k, #candidates) candidates all answered
         (↔ isSynced, search.h:734-747).  Replies are instantaneous in this
@@ -465,51 +503,60 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         return jnp.all(~present | (queried[:, :k] > 0), axis=1) & \
             jnp.any(present, axis=1)
 
+    @device_stage("select")
+    def select(cand_node, cand_l0, queried, done):
+        """Stage ``select``: the closest α unqueried candidates per active
+        search (↔ searchSendGetValues picking SearchNodes with canGet,
+        src/dht.cpp:628-639) as rows [W, alpha] (−1 pad), marked queried."""
+        can = (cand_node >= 0) & (queried == 0) & ~done[:, None]
+        rank = jnp.cumsum(can.astype(jnp.int32), axis=1)
+        sel = can & (rank <= alpha)
+        # gather selected rows into [W, alpha] (−1 pad): α static
+        # masked max-reductions — a scatter-max here measured slower
+        x_rows = jnp.stack(
+            [jnp.max(jnp.where(sel & (rank == j + 1), cand_node, -1),
+                     axis=1) for j in range(alpha)], axis=1)
+        if block_bounds is not None:
+            # ROUND FUSION: the selected peers' top distance limb
+            # rides the same masked max-reductions (cand_l[0] is
+            # x0 ^ t0 — computed by the merge that first admitted
+            # the peer), so reply_gather needs NO table access to
+            # position the reply blocks and the round's only
+            # gather is the fused α·k-row reply fetch.  Bit-exact:
+            # a selected lane is unique per rank (cumsum), and
+            # unselected slots (x_rows = -1) get d0 = 0 → their
+            # replies are masked exactly as the gathered path
+            # masked them.
+            x_d0 = jnp.stack(
+                [jnp.max(jnp.where(sel & (rank == j + 1), cand_l0,
+                                   _U32(0)), axis=1)
+                 for j in range(alpha)], axis=1)
+        else:
+            x_d0 = None
+        return sel, x_rows, x_d0, jnp.where(sel, 1, queried)
+
+    @device_stage("converge")
+    def converge(cand_node, queried, sel, hops, done):
+        """Stage ``converge``: hop counts and the done flags after a
+        round's merge."""
+        now_done = synced(cand_node, queried)
+        stalled = ~jnp.any((cand_node >= 0) & (queried == 0), axis=1)
+        sent = jnp.any(sel, axis=1)
+        # a stalling round sends nothing → costs no hop (matches the
+        # scalar reference's stall return path)
+        hops = jnp.where(~done & sent, hops + 1, hops)
+        return hops, done | now_done | stalled
+
     def make_body(tgt, pt, qidx):
         def body(state):
             cand_node, cand_l, queried, hops, done, round_no = state
-            # select the closest α unqueried candidates per active search
-            # (↔ searchSendGetValues picking SearchNodes with canGet,
-            #  src/dht.cpp:628-639)
-            can = (cand_node >= 0) & (queried == 0) & ~done[:, None]
-            rank = jnp.cumsum(can.astype(jnp.int32), axis=1)
-            sel = can & (rank <= alpha)
-            # gather selected rows into [W, alpha] (−1 pad): α static
-            # masked max-reductions — a scatter-max here measured slower
-            x_rows = jnp.stack(
-                [jnp.max(jnp.where(sel & (rank == j + 1), cand_node, -1),
-                         axis=1) for j in range(alpha)], axis=1)
-            if block_bounds is not None:
-                # ROUND FUSION: the selected peers' top distance limb
-                # rides the same masked max-reductions (cand_l[0] is
-                # x0 ^ t0 — computed by the merge that first admitted
-                # the peer), so reply_gather needs NO table access to
-                # position the reply blocks and the round's only
-                # gather is the fused α·k-row reply fetch.  Bit-exact:
-                # a selected lane is unique per rank (cumsum), and
-                # unselected slots (x_rows = -1) get d0 = 0 → their
-                # replies are masked exactly as the gathered path
-                # masked them.
-                x_d0 = jnp.stack(
-                    [jnp.max(jnp.where(sel & (rank == j + 1), cand_l[0],
-                                       _U32(0)), axis=1)
-                     for j in range(alpha)], axis=1)
-            else:
-                x_d0 = None
-
+            sel, x_rows, x_d0, queried = select(cand_node, cand_l[0],
+                                                queried, done)
             new_rows = reply_gather(tgt, pt, qidx, x_rows, round_no + 1,
                                     x_d0)
-            queried = jnp.where(sel, 1, queried)
             cand_node, cand_l, queried = merge(
                 tgt, cand_node, cand_l, queried, new_rows)
-
-            now_done = synced(cand_node, queried)
-            stalled = ~jnp.any((cand_node >= 0) & (queried == 0), axis=1)
-            sent = jnp.any(sel, axis=1)
-            # a stalling round sends nothing → costs no hop (matches the
-            # scalar reference's stall return path)
-            hops = jnp.where(~done & sent, hops + 1, hops)
-            done = done | now_done | stalled
+            hops, done = converge(cand_node, queried, sel, hops, done)
             return cand_node, cand_l, queried, hops, done, round_no + 1
         return body
 
@@ -583,7 +630,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     else:
         # reconstruct the full 160-bit distances from the final node ids
         # in ONE gather — the merge loop never carried limbs 2-4
-        id_l = gather_planar(nodes_k, N_LIMBS)
+        id_l = fetch_ids(nodes_k, N_LIMBS)
         dist = jnp.stack(
             [jnp.where(nodes_k >= 0, id_l[l] ^ targets[:, l:l + 1],
                        jnp.uint32(0xFFFFFFFF)) for l in range(N_LIMBS)],
@@ -702,34 +749,31 @@ def _is_tracer(x) -> bool:
         return False                # instrumentation, never a crash)
 
 
-_TRACE_MAX_ROUND_SPANS = 64
-
-
 def record_wave(out, elapsed_s: float, wave_width: int, *,
                 mode: str = "single", mesh_t: int = 1) -> None:
     """Feed one completed search wave into the telemetry spine
     (ISSUE-3): ``dht_search_wave_seconds`` (the OPEN ≤8 ms 1024-wave
     p50 bound is exactly this histogram's p50 at width 1024, PARITY.md),
-    per-round latency (wave wall / deepest round — rounds advance in
-    lockstep inside the compiled while_loop, so the per-round figure is
-    the wave quotient, not a per-round host probe), and the wave-width /
-    hops distributions.  Shared by the single-device engine and the
-    tp-sharded twin (``mode="tp"``, parallel/sharded.py).
+    ``dht_search_round_seconds`` (a QUOTIENT, not a timing: wave wall /
+    deepest lookup's rounds — the rounds run in lockstep inside the
+    compiled while_loop and no host probe sees one; where a round's
+    time goes is read off a device trace by the stages
+    ``_lookup_engine`` names), and the wave-width / hops
+    distributions.  Shared by the single-device engine and the
+    tp-sharded twin (``mode="tp"``, parallel/sharded.py); both time the
+    whole of this call as ``dht_search_record_seconds``.
 
     ISSUE-4: when an ambient trace context is active the same envelope
-    records the wave into the distributed tracer — one
-    ``dht.search.wave`` child span plus one ``dht.search.round`` child
-    per round.  Context-gated ON PURPOSE: an untraced bench loop would
-    otherwise mint ~rounds+1 root spans per wave into the shared ring
-    and evict the flight-recorder events it exists to retain (found by
+    records the wave into the distributed tracer as ONE
+    ``dht.search.wave`` child span (attribute ``rounds``: the deepest
+    lookup's hops).  Context-gated ON PURPOSE: an untraced bench loop
+    would otherwise mint a root span per wave into the shared ring and
+    evict the flight-recorder events it exists to retain (found by
     review) — to trace a wave, activate a root first (``with
     tracing.activate(TraceContext.new_root()): simulate_lookups(...)``,
     the exact recipe PARITY gives for settling the OPEN p95-wave bound
-    on chip).  Round spans carry the wave-quotient duration — the
-    rounds run in lockstep inside the compiled while_loop, so the even
-    split IS the attribution the telemetry histogram reports.
-    Host-side only: the traced computation ran BEFORE this call —
-    tracing cannot perturb the kernels (pinned in
+    on chip).  Host-side only: the traced computation ran BEFORE this
+    call — tracing cannot perturb the kernels (pinned in
     tests/test_tracing.py)."""
     from .. import telemetry, tracing
     reg = telemetry.get_registry()
@@ -767,24 +811,23 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
         from .. import profiling
         cost = profiling.wave_attrs(int(wave_width), rounds, elapsed_s,
                                     mode=mode, mesh_t=mesh_t)
-        wave_ctx = tr.record("dht.search.wave", start, elapsed_s,
-                             parent=ctx, mode=mode,
-                             width=int(wave_width), rounds=rounds, **cost)
-        if wave_ctx is not None and 0 < rounds <= _TRACE_MAX_ROUND_SPANS:
-            per_round = elapsed_s / rounds
-            for i in range(rounds):
-                tr.record("dht.search.round", start + i * per_round,
-                          per_round, parent=wave_ctx, mode=mode, round=i)
+        tr.record("dht.search.wave", start, elapsed_s, parent=ctx,
+                  mode=mode, width=int(wave_width), rounds=rounds, **cost)
 
 
 def simulate_lookups(sorted_ids, n_valid, targets, **kw):
     """Run Q iterative lookups to convergence — the public entry point;
     see :func:`_simulate_lookups_jit` for the full argument contract.
 
-    Telemetry envelope over the compiled engine: times the wave with
-    a host-side span (``perf_counter`` around ``block_until_ready``,
-    plus the matching ``jax.profiler.TraceAnnotation``) and records the
-    wave/hops histograms.  Host-side ONLY — the traced computation is
+    Telemetry envelope over the compiled engine: three host-side spans
+    (``perf_counter`` plus the matching ``jax.profiler.TraceAnnotation``,
+    so all three lie on a device trace's clock) —
+    ``dht_search_wave_seconds`` around dispatch and ``block_until_ready``,
+    inside it ``dht_search_dispatch_seconds`` around the jit call until
+    it returns, and after it ``dht_search_record_seconds`` around
+    :func:`record_wave` (the ``hops`` fetch and the wave/hops
+    histograms).  With one wave in flight the device idles exactly
+    during the second and the third.  Host-side ONLY — the traced computation is
     byte-for-byte :func:`_simulate_lookups_jit`, so results are
     bit-identical with telemetry on or off (pinned in
     tests/test_telemetry.py).  Under an outer trace (e.g. the bench
@@ -796,9 +839,11 @@ def simulate_lookups(sorted_ids, n_valid, targets, **kw):
     if not reg.enabled or _is_tracer(targets) or _is_tracer(sorted_ids):
         return _simulate_lookups_jit(sorted_ids, n_valid, targets, **kw)
     with reg.span("dht_search_wave_seconds", record=False) as sp:
-        out = _simulate_lookups_jit(sorted_ids, n_valid, targets, **kw)
+        with reg.span("dht_search_dispatch_seconds", mode="single"):
+            out = _simulate_lookups_jit(sorted_ids, n_valid, targets, **kw)
         jax.block_until_ready(out)
-    record_wave(out, sp.elapsed, targets.shape[0], mode="single")
+    with reg.span("dht_search_record_seconds", mode="single"):
+        record_wave(out, sp.elapsed, targets.shape[0], mode="single")
     return out
 
 

@@ -479,16 +479,23 @@ class NetworkEngine:
         # parented to the sender's per-hop client span — that link is
         # what the cross-node assembler stitches trees from.
         tctx = msg.trace_ctx
+        is_request = msg.type in REQUEST_TYPES
         span = (self._tracer.span("dht.server." + msg.type.value,
                                   parent=tctx, kind="server",
                                   node=self._node_tag,
                                   peer=str(from_addr))
-                if (tctx is not None and tctx.sampled
-                    and msg.type in REQUEST_TYPES
+                if (is_request and tctx is not None and tctx.sampled
                     and self._tracer.enabled)
                 else tracing.NOOP_SPAN)
+        # every served request is timed, sampled sender or not: handler,
+        # resolve and reply send, as dht_server_request_seconds{type} on
+        # the registry's clock (the tracer span above needs a wire
+        # context and stays for the cross-node assembler)
+        timed = (telemetry.get_registry().span(
+                     "dht_server_request_seconds", type=msg.type.value)
+                 if is_request else tracing.NOOP_SPAN)
         try:
-            with span:
+            with span, timed:
                 try:
                     self._dispatch(msg, node, from_addr, now)
                 except DhtProtocolException as e:

@@ -62,6 +62,7 @@ from ..ops.sorted_table import (sort_table, window_topk, build_prefix_lut,
 from ..core.search import (simulate_lookups, _lookup_engine,
                            _guarded_lower_bound, _lut_block_bounds,
                            TARGET_NODES, ALPHA, SEARCH_NODES)
+from ..telemetry import device_stage
 
 _U32 = jnp.uint32
 
@@ -417,7 +418,9 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             g = jnp.take(sorted_t[:limbs], jnp.clip(flat, 0, shard_n - 1),
                          axis=1)
             g = jnp.where(ok[None, :], g, _U32(0))
-            g = lax.psum(g, "t")
+            # the round's one collective, a device stage of its own
+            g = device_stage("owner_merge")(
+                lambda part: lax.psum(part, "t"))(g)
             return [g[l].reshape(rows.shape) for l in range(limbs)]
 
         q_index = (lax.axis_index("q").astype(jnp.int32) * q_local
@@ -514,15 +517,17 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
     # same host-side envelope as the single-device entry (core/search.py
     # simulate_lookups): the traced computation is untouched, the span
     # blocks and the wave/hops series land under mode="tp" — and via
-    # record_wave the distributed tracer gets the mode="tp" wave/round
-    # spans too (ISSUE-4), so a sharded lookup shows up in the same
+    # record_wave the distributed tracer gets the mode="tp" wave span
+    # too (ISSUE-4), so a sharded lookup shows up in the same
     # Chrome/Perfetto timeline as the single-device one
     with reg.span("dht_search_wave_seconds", record=False) as sp:
-        out = fn(*args)
+        with reg.span("dht_search_dispatch_seconds", mode="tp"):
+            out = fn(*args)
         jax.block_until_ready(out)
     from ..core.search import record_wave
-    record_wave(out, sp.elapsed, Q, mode="tp",
-                mesh_t=mesh.shape["t"])
+    with reg.span("dht_search_record_seconds", mode="tp"):
+        record_wave(out, sp.elapsed, Q, mode="tp",
+                    mesh_t=mesh.shape["t"])
     return out
 
 

@@ -19,6 +19,10 @@ all feed (↔ the reference exposing ``Dht::getNodesStats`` and the proxy
   that wrap ``block_until_ready``.  Instrumentation stays off the
   kernel trace: spans time *around* compiled calls, never inside them,
   so kernels remain bit-identical with telemetry enabled.
+- :func:`device_stage` — the names *inside* a compiled call: a part of
+  a kernel as an inner ``jax.jit`` named ``stage_<name>``, so the device
+  trace's operations carry the stage in their ``op_name`` (and the name
+  survives the compile cache).
 - Export: :meth:`snapshot` (JSON-able dict — ``DhtRunner.get_metrics``),
   :meth:`prometheus` (text exposition v0.0.4 — the proxy ``GET /stats``
   route), and the ``stats`` REPL command in tools/dhtnode.py.
@@ -43,6 +47,7 @@ from typing import Dict, Iterable, List, Optional, Tuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Span",
+    "device_stage",
     "get_registry", "quantile_from_buckets", "snapshot_diff",
 ]
 
@@ -272,6 +277,38 @@ def _trace_annotation():
         except Exception:
             _TRACE_ANNOTATION = [None]
     return _TRACE_ANNOTATION[0]
+
+
+def device_stage(name: str):
+    """``@device_stage("merge")`` — the device-side twin of
+    :meth:`MetricsRegistry.span`: returns the function as an inner
+    ``jax.jit`` named ``stage_merge``, so that inside an outer jit every
+    operation it traces carries ``.../jit(stage_merge)/...`` in its HLO
+    ``op_name`` and a device trace splits by stage, not by
+    ``fusion.164``.  The ``stage_`` prefix is how a trace reader knows a
+    stage from any other jitted function; whoever owns the kernel owns
+    its stage names (the lookup round's: ``core/search.py
+    _lookup_engine``).
+
+    An inner jit and not ``jax.named_scope``: a scope lives in debug
+    locations, which the persistent compilation cache strips before it
+    hashes a module, so a scopes-only change loads the executable an
+    earlier, unnamed build left there and the names never reach the
+    trace.  The jitted function's name is a symbol of the lowered
+    module (``func.func private @stage_merge``), part of the cache key.
+    XLA inlines the call: the optimized module has no ``call`` left,
+    and results are those of the undecorated function.  The function
+    may close over values of the outer trace; its arguments are arrays
+    or pytrees of them."""
+    def wrap(fn):
+        import jax
+
+        def staged(*args):
+            return fn(*args)
+
+        staged.__name__ = staged.__qualname__ = "stage_" + name
+        return jax.jit(staged)
+    return wrap
 
 
 def _label_key(labels: dict) -> Tuple[Tuple[str, str], ...]:

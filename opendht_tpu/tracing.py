@@ -29,8 +29,8 @@ PR 3's metrics answer "how fast is the system"; this module answers
   tree from every cluster node's ring.
 
 Host-side only, like the telemetry spine: spans wrap the SAME
-uninstrumented jitted engines (core/search.py records the wave/round
-spans from the already-measured envelope elapsed — the compiled
+uninstrumented jitted engines (core/search.py records the wave
+span from the already-measured envelope elapsed — the compiled
 computation is untouched, kernels bit-identical with tracing on,
 pinned in tests/test_tracing.py).
 

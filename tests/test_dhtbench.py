@@ -1,0 +1,55 @@
+"""The benchmark's own tests (``dhtbench/tests/``), collected into Tier-1.
+
+They live with the benchmark so that ``python3 -m pytest dhtbench/tests``
+runs them alone; this module imports them so that the repo's one test
+command counts them too.
+
+ONE CASE IS DROPPED FROM THE IMPORT AND RESTATED HERE, under another name:
+``test_sim_cell_at_toy_size_prints_the_contract_line`` pins the per-layer
+metrics a CPU rehearsal reports to the one registry metric the cell had
+when it was written (``{"sim_round_ms"}``); the cell has three since PR 26,
+so its ``[True]`` case fails where it stands (``python3 -m pytest
+dhtbench/tests``).  A file the benchmark already has may be edited only by
+a ``benchmark`` PR, which mends that one line and then deletes the copy
+and the ``del`` below (PERF.md §7).  The copy asserts everything the
+original does.
+"""
+
+import json
+
+import pytest
+
+pytest.register_assert_rewrite("dhtbench.tests.test_dhtbench",
+                               "dhtbench.tests.test_stage_source")
+
+from dhtbench import run                            # noqa: E402
+from dhtbench.tests.test_dhtbench import *          # noqa: E402,F401,F403
+from dhtbench.tests.test_dhtbench import RESULT_KEYS, manifest  # noqa: E402,F401
+from dhtbench.tests.test_stage_source import *      # noqa: E402,F401,F403
+
+del test_sim_cell_at_toy_size_prints_the_contract_line      # noqa: F821
+
+# the per-layer metrics of the sim cell that read the program's registry,
+# and so read something on the CPU too; the trace ones read nothing there
+SIM_REGISTRY_METRICS = {"sim_round_ms", "sim_record_ms_per_wave",
+                        "sim_dispatch_ms_per_wave"}
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_sim_cell_at_toy_size_reports_every_registry_metric(manifest, trace):  # noqa: F811
+    line = run.run_cell("sim-10m.wave-65536", 2 ** 31 + 12345, 1.0, trace,
+                        rehearsal={"n_ids": 4096, "wave_targets": 256,
+                                   "target_sets": 4})
+    line = json.loads(json.dumps(line))
+    assert set(line) == RESULT_KEYS         # no breakdown: the CPU has no device plane
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0 and line["attempted"] % 256 == 0
+    assert set(line["device"]) == {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    if trace:
+        assert set(line["metrics"]) == SIM_REGISTRY_METRICS
+    else:
+        assert set(line["metrics"]) == {"sim_lookups_per_s",
+                                        "sim_wave_p90_ms", "setup_s"}
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0

@@ -177,6 +177,34 @@ def test_find_node_returns_sorted_truncated_nodes(net):
     assert dists == sorted(dists)
 
 
+def test_served_request_is_timed_without_a_sampled_sender(net):
+    """Every incoming request lands in dht_server_request_seconds{type},
+    wire context or none; the dht.server.* tracer span still needs one.
+    A reply is no request and is not timed."""
+    from opendht_tpu import telemetry, tracing
+    reg, tr = telemetry.get_registry(), tracing.get_tracer()
+    find = reg.histogram("dht_server_request_seconds", type="find")
+    a, b, node_b, _ = make_pair(net, cbs_b=EngineCallbacks(
+        on_find_node=lambda node, t, want: RequestAnswer()))
+    seen = []
+    process = b.process_message
+    b.process_message = lambda data, src: (
+        seen.append(ParsedMessage.from_bytes(data).trace_ctx),
+        process(data, src))
+    count, spans = find.count, len(tr.spans())
+    try:
+        tr.enabled = False              # the sender attaches no context
+        a.send_find_node(node_b, InfoHash.get("target"), want=1)
+        net.pump()
+    finally:
+        tr.enabled = True
+    assert seen == [None]
+    assert find.count == count + 1 and find.sum > 0
+    assert len(tr.spans()) == spans
+    assert (("type", "reply"),) not in reg.series(
+        "dht_server_request_seconds")
+
+
 def test_get_values_inline_and_token(net):
     val = Value(b"payload", value_id=42)
 

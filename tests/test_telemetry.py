@@ -6,6 +6,7 @@ get_node_message_stats), and kernel bit-identity with telemetry on/off."""
 import json
 import math
 import os
+import re
 import socket
 
 import numpy as np
@@ -402,17 +403,21 @@ def test_get_nodes_stats_field_by_field():
 
 
 # ------------------------------------------- kernel bit-identity (tentpole)
+def _toy_table(seed, N=2048, Q=64):
+    rng = np.random.default_rng(seed)
+    raw = rng.integers(0, 2 ** 32, (N, 5), dtype=np.uint32)
+    ids = raw[np.lexsort([raw[:, i] for i in range(4, -1, -1)])]
+    return ids, rng.integers(0, 2 ** 32, (Q, 5), dtype=np.uint32)
+
+
 def test_simulate_lookups_bitidentical_with_telemetry():
     """Telemetry enabled vs disabled must not change a single bit of the
     search engine's output (host-side envelope only), while the wave
     histograms advance only when enabled."""
     from opendht_tpu.core.search import simulate_lookups
 
-    rng = np.random.default_rng(5)
-    N, Q = 2048, 64
-    raw = rng.integers(0, 2 ** 32, (N, 5), dtype=np.uint32)
-    ids = raw[np.lexsort([raw[:, i] for i in range(4, -1, -1)])]
-    targets = rng.integers(0, 2 ** 32, (Q, 5), dtype=np.uint32)
+    ids, targets = _toy_table(5)
+    N, Q = len(ids), len(targets)
 
     reg = telemetry.get_registry()
     wave = reg.histogram("dht_search_wave_seconds")
@@ -433,6 +438,121 @@ def test_simulate_lookups_bitidentical_with_telemetry():
     for k in ("nodes", "dist", "hops", "converged"):
         assert np.array_equal(np.asarray(out_on[k]),
                               np.asarray(out_off[k])), k
+
+
+def test_wave_envelope_spans_dispatch_and_record():
+    """One wave = one observation in each of the three envelope
+    histograms, dispatch + record within the caller's wall time; none
+    with the registry disabled, outputs equal."""
+    import time
+    from opendht_tpu.core.search import simulate_lookups
+
+    ids, targets = _toy_table(6)
+    reg = telemetry.get_registry()
+    simulate_lookups(ids, len(ids), targets, seed=4)        # compile
+    names = ("dht_search_wave_seconds", "dht_search_dispatch_seconds",
+             "dht_search_record_seconds")
+    hists = [reg.histogram(n, mode="single") for n in names]
+    before = [(h.count, h.sum) for h in hists]
+    t0 = time.perf_counter()
+    out_on = simulate_lookups(ids, len(ids), targets, seed=4)
+    wall = time.perf_counter() - t0
+    took = [h.sum - s for h, (_, s) in zip(hists, before)]
+    assert [h.count - c for h, (c, _) in zip(hists, before)] == [1, 1, 1]
+    wave, dispatch, record = took
+    assert 0 < dispatch <= wave and record > 0
+    assert wave + record <= wall
+    try:
+        reg.enabled = False
+        counts = [h.count for h in hists]
+        out_off = simulate_lookups(ids, len(ids), targets, seed=4)
+        assert [h.count for h in hists] == counts
+    finally:
+        reg.enabled = True
+    for k in ("nodes", "dist", "hops", "converged"):
+        assert np.array_equal(np.asarray(out_on[k]),
+                              np.asarray(out_off[k])), k
+
+
+# ------------------------------------------- device stages, named in the IR
+ROUND_STAGES = ["select", "block_bounds", "reply_rows", "fetch_ids",
+                "merge", "converge"]
+
+
+@pytest.fixture(scope="module")
+def staged_engine():
+    """(lowered text, compiled text) of the wave engine at a toy size."""
+    import jax.numpy as jnp
+    from opendht_tpu.core.search import _simulate_lookups_jit
+    from opendht_tpu.ops.sorted_table import build_prefix_lut, default_lut_bits
+    ids, targets = _toy_table(7, N=4096, Q=32)
+    lut = build_prefix_lut(jnp.asarray(ids), len(ids),
+                           bits=default_lut_bits(len(ids)))
+    lowered = _simulate_lookups_jit.lower(
+        ids, len(ids), targets, seed=1, k=8, alpha=3, search_nodes=14,
+        lut=lut, state_limbs=2)
+    return lowered.as_text(), lowered.compile().as_text()
+
+
+@pytest.fixture(scope="module")
+def staged_tp_engine():
+    """The same for the table-sharded twin on a t=4 CPU mesh."""
+    import jax.numpy as jnp
+    from opendht_tpu.parallel import make_mesh, shard_table_state
+    from opendht_tpu.parallel.sharded import build_tp_lookup
+    mesh = make_mesh(4, q=1, t=4)
+    ids, targets = _toy_table(8, N=4096, Q=32)
+    state = shard_table_state(mesh, ids, len(ids))
+    a = state.arrays
+    lowered = build_tp_lookup(mesh, state.shard_n, 32, 8, 3, 14, 48, 2).lower(
+        a["sorted_ids"], a["local_lut"], a["block_lut"], a["n_valid"],
+        targets, jnp.asarray(1, jnp.int32))
+    return lowered.as_text(), lowered.compile().as_text()
+
+
+@pytest.mark.parametrize("stage", ROUND_STAGES)
+def test_stage_is_a_symbol_of_the_lowered_module(staged_engine, stage):
+    """The name is in the IR proper — a function symbol, printed with
+    debug info off — so it is part of the compile cache's key, which a
+    named_scope (a debug location) is not."""
+    lowered, _ = staged_engine
+    assert "loc(" not in lowered                    # no debug info printed
+    assert re.search(r"func\.func private @stage_%s(_\d+)?\(" % stage,
+                     lowered)
+
+
+@pytest.mark.parametrize("stage", ROUND_STAGES)
+def test_stage_names_the_operations_of_the_compiled_round(staged_engine,
+                                                          stage):
+    """Every stage shows in the optimized module's op_names under the
+    round loop, and XLA inlined the inner jits: no call is left."""
+    _, compiled = staged_engine
+    assert re.search(r'op_name="[^"]*/while/body/(?:[^"/]+/)*jit\(stage_%s\)/'
+                     % stage, compiled)
+    assert not re.search(r"[ =]call\(", compiled)
+
+
+@pytest.mark.parametrize("stage", ROUND_STAGES + ["owner_merge"])
+def test_stage_names_reach_the_four_way_sharded_round(staged_tp_engine,
+                                                      stage):
+    """The tp twin runs the same engine under shard_map and inherits its
+    stages; its one in-loop collective is a stage of its own, the
+    all-reduce still one operation of the loop body, no call left."""
+    lowered, compiled = staged_tp_engine
+    assert re.search(r"func\.func private @stage_%s(_\d+)?\(" % stage,
+                     lowered)
+    assert re.search(r'op_name="[^"]*/while/body/(?:[^"/]+/)*jit\(stage_%s\)/'
+                     % stage, compiled)
+    assert not re.search(r"[ =]call\(", compiled)
+    assert re.search(r'all-reduce[^\n]*op_name="[^"]*/while/body/(?:[^"/]+/)*'
+                     r'jit\(stage_owner_merge\)/', compiled)
+
+
+def test_device_stage_names_the_jit_and_nothing_else():
+    import jax.numpy as jnp
+    double = telemetry.device_stage("merge")(lambda x: x * 2)
+    assert double.__name__ == "stage_merge"
+    assert int(double(jnp.int32(21))) == 42
 
 
 # ------------------------------------------------ monitor (satellite 2)
