@@ -15,7 +15,7 @@ matrix, and merges them back — all as fixed-shape array ops inside a
 steps instead of millions of scalar iterations.  As of round 6 the
 steady-state round is ROUND-FUSED: all α·k reply rows of the whole wave
 are fetched by ONE fused gather (``ops.sorted_table.fused_gather_planar``
-over a single [W·α·k] index vector), the reply blocks are positioned
+over a single [α·k·W] index vector, slot-major), the reply blocks are positioned
 from the *carried* candidate distance limb instead of a per-round peer
 gather, and both LUT block edges ride one stacked read — so a round's
 serial chain is one gather + one LUT read + two merge sorts, the
@@ -264,6 +264,55 @@ def _common_bits_planar(a_l, b_l):
     return out
 
 
+def _reply_rows(pt, qidx, x_rows, round_no, lo, ub, *, n, k, q_total,
+                seed_u):
+    """The reply model proper (stage ``reply_rows``): block edges → the
+    α·k sampled (or fallback-window) rows per search, as a SLOT-MAJOR
+    plane ``[R, W]`` (R = α·k, slot r = a·k + j is sample j of peer a).
+
+    ``pt``, ``qidx`` [W]; ``x_rows``, ``lo``, ``ub`` PEER-MAJOR
+    ``[alpha, W]``.  Every value here keeps the W lookups on the minor
+    axis: the logical shape ``[W, α, k]`` this used to compute in is
+    tiled (4, 128) over its minor dims (3, 8) on the TPU — 21× the
+    bytes of the dense array, written once and read twice more per
+    round by the reshapes to the gather's flat index — which made a
+    hash, a modulo and two selects the second stage of the round
+    (35 ms of a 186 ms wave at W=65,536; PERF.md §6, PR 27).  A peer's
+    value reaches its k slots as a replication along the major axis
+    (``jnp.repeat`` — a sublane broadcast), never through ``[W, α, k]``.
+    The numbers are those of the ``[W, α, k]`` formula, element for
+    element (tests/test_search.py renders it in numpy).
+    """
+    alpha = x_rows.shape[0]
+    R = alpha * k
+
+    def per_slot(x):                    # [alpha, W] → [R, W]
+        return jnp.repeat(x, k, axis=0)
+
+    size = jnp.maximum(ub - lo, 0)                                   # [a,W]
+    # slot = a·k + j, so ((c·α + a)·k + j) == c·(α·k) + slot (mod 2^32)
+    slot = jnp.arange(R, dtype=_U32)[:, None]
+    qi = qidx.astype(_U32)[None, :]                # GLOBAL query ids
+    ctr = ((round_no.astype(_U32) * _U32(q_total) + qi) * _U32(R)
+           + slot) ^ seed_u
+    h = _mix32(ctr)                                                  # [R,W]
+
+    blk = per_slot(lo) + (h % per_slot(jnp.maximum(size, 1).astype(_U32))
+                          ).astype(jnp.int32)
+    # fallback: block too small → the peer knows the target's
+    # neighborhood and answers with rows from the (alpha·k)-wide
+    # window straddling pos_t, each queried slot contributing a
+    # distinct k-slice so one round covers the window determinist-
+    # ically (a real node replies with the closest set it knows, not
+    # a uniform sample — the round-1 uniform model overestimated
+    # terminal hops ~2x; validated against the live protocol path in
+    # tests/test_hop_parity.py)
+    base = jnp.clip(pt - R // 2, 0, jnp.maximum(n - R, 0))[None, :]
+    fb = jnp.clip(base + slot.astype(jnp.int32), 0, jnp.maximum(n - 1, 0))
+    rows = jnp.where(per_slot(size >= k), blk, fb)
+    return jnp.where(per_slot(x_rows >= 0), rows, -1)
+
+
 def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                    seed_u, *, k, alpha, search_nodes, max_hops,
                    state_limbs: int = N_LIMBS,
@@ -280,8 +329,11 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     partial computation + one ``psum`` over the table axis):
 
       gather_planar(rows [...]) -> 5×[...] uint32 limb planes of the
-          globally-sorted table rows (callers pre-clip to [0, n));
-          entries for out-of-range rows may be garbage — every caller
+          globally-sorted table rows, in ``rows``' own shape and order
+          (the engine hands over peer-major [alpha, W] and slot-major
+          [R, W] indices; the gather is elementwise in its index, so
+          any shape means the same); entries for out-of-range rows
+          (the −1 of an unsent slot) may be garbage — every caller
           masks them.
       lower(flat [M, 5]) -> [M] int32 global lower-bound positions.
       block_bounds(t0, prefix_len) -> (lo, ub) prefix-block edges
@@ -295,7 +347,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
     ROUND-FUSED GATHER (round 6): with ``block_bounds`` provided, the
     steady-state round body issues exactly ONE ``gather_planar`` call —
-    the fused [W·α·k] reply-distance fetch inside the merge.  The
+    the fused [α·k·W] reply-distance fetch inside the merge.  The
     round-5 engine also gathered the α queried peers' top limb each
     round (to position the reply blocks); that value is ``x0 ^ t0`` —
     the very distance limb the candidate state already carries — so it
@@ -304,6 +356,23 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     goldens so any reply-stream drift fails loudly).  In the
     table-sharded twin the same change removes one of the per-round
     psum sites (parallel/sharded.py).
+
+    REPLY-PATH LAYOUT (PR 27): from ``select``'s output to ``insert``'s
+    concatenate every per-peer value is a PEER-MAJOR plane [alpha, W]
+    and every per-reply value a SLOT-MAJOR plane [R, W] (R = α·k, slot
+    r = sample r % k of peer r // k) — the W lookups on the minor axis,
+    which the TPU puts on the 128 lanes.  That is the layout XLA already
+    gives the candidate state: a logical [W, S] array is stored
+    {0,1:T(8,128)}, physically [S, W], dense, and the merge sorts run
+    along the slot axis there; so ``.T`` of a slot-major plane joins
+    the state without a copy.  The logical shape [W, α, k] the reply
+    model used to compute in is tiled (4, 128) over its minor dims
+    (3, 8): 21× the bytes (134 MB for each u32[65536,3,8], five a
+    round, each read twice more by the reshapes to the gather's index),
+    and the gather's flat index in lookup-major order cost a transpose
+    before the gather and one per limb plane after it — together a
+    fifth of the wave (:func:`_reply_rows`,
+    ``ops.sorted_table.fused_gather_planar``; PERF.md §6, PR 27).
 
     DEVICE STAGES: each part of a round runs through
     ``telemetry.device_stage`` — ``select``, ``block_bounds``,
@@ -359,7 +428,8 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
     def reply_gather(tgt, pt, qidx, x_rows, round_no, x_d0=None):
         """Simulated answers of the α queried nodes per search.
-        x_rows [W, alpha] int32 (−1 = no request) → node rows [W, R].
+        x_rows [alpha, W] int32 (−1 = no request) → node rows [R, W]
+        (peer-major in, slot-major out: W stays on the lanes).
 
         ``x_d0``: the queried peers' top distance limb ``x0 ^ t0``
         carried from the candidate state (the ROUND-FUSED form — see
@@ -376,76 +446,52 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             # the per-round 1-plane peer gather of round 5 (~1 ms of
             # the ~5.5 ms round at W=16K, and one whole psum site in
             # the sharded engine) disappears: the round's ONLY table
-            # gather is the fused [W·α·k] reply gather in merge().
+            # gather is the fused [α·k·W] reply gather in merge().
             # block_mode="exact" keeps the full-width gathered path.
+            t0 = tgt[:, 0][None, :]              # [1, W] against [alpha, W]
             if x_d0 is None:
-                x_d0 = fetch_ids(x_rows, 1)[0] ^ tgt[:, 0:1]
+                x_d0 = fetch_ids(x_rows, 1)[0] ^ t0
 
             def edges(x_d0):
                 b = clz32(x_d0)                  # clz32(0) == 32 by contract
-                return block_bounds(tgt[:, 0:1], b + 1)
+                return block_bounds(t0, b + 1)
 
             lo, ub = device_stage("block_bounds")(edges)(x_d0)
         else:
             def edges(x_l):
-                t_l = [tgt[:, l:l + 1] for l in range(N_LIMBS)]
-                b = _common_bits_planar(x_l, t_l)                    # [W,a]
+                t_l = [tgt[:, l][None, :] for l in range(N_LIMBS)]
+                b = _common_bits_planar(x_l, t_l)                    # [a,W]
                 prefix_len = jnp.clip(b + 1, 0, ID_BITS)
-                return _prefix_block_bounds(lower, n, tgt[:, None, :]
-                                            .repeat(x_rows.shape[1], 1),
-                                            prefix_len)
+                return _prefix_block_bounds(
+                    lower, n,
+                    jnp.broadcast_to(tgt[None], x_rows.shape + (N_LIMBS,)),
+                    prefix_len)
 
             lo, ub = device_stage("block_bounds")(edges)(
                 fetch_ids(x_rows, N_LIMBS))          # full ids: exact cb
-        return device_stage("reply_rows")(reply_rows)(
+        return device_stage("reply_rows")(functools.partial(
+            _reply_rows, n=n, k=k, q_total=q_total, seed_u=seed_u))(
             pt, qidx, x_rows, round_no, lo, ub)
-
-    def reply_rows(pt, qidx, x_rows, round_no, lo, ub):
-        """The reply model proper: block edges → the α·k sampled (or
-        fallback-window) rows per search."""
-        W = pt.shape[0]
-        size = jnp.maximum(ub - lo, 0)                                     # [W,a]
-
-        qi = qidx.astype(_U32)[:, None, None]          # GLOBAL query ids
-        ai = jnp.arange(x_rows.shape[1], dtype=_U32)[None, :, None]
-        ji = jnp.arange(k, dtype=_U32)[None, None, :]
-        ctr = (((round_no.astype(_U32) * _U32(q_total) + qi) * _U32(alpha)
-                + ai) * _U32(k) + ji) ^ seed_u
-        h = _mix32(ctr)                                                     # [W,a,k]
-
-        blk = lo[..., None] + (h % jnp.maximum(size[..., None], 1).astype(_U32)
-                               ).astype(jnp.int32)
-        # fallback: block too small → the peer knows the target's
-        # neighborhood and answers with rows from the (alpha·k)-wide
-        # window straddling pos_t, each queried slot contributing a
-        # distinct k-slice so one round covers the window determinist-
-        # ically (a real node replies with the closest set it knows, not
-        # a uniform sample — the round-1 uniform model overestimated
-        # terminal hops ~2x; validated against the live protocol path in
-        # tests/test_hop_parity.py)
-        base = jnp.clip(pt[:, None, None] - R // 2, 0,
-                        jnp.maximum(n - R, 0))
-        fb = jnp.clip(base + (ai * _U32(k) + ji).astype(jnp.int32), 0,
-                      jnp.maximum(n - 1, 0))
-        rows = jnp.where((size[..., None] >= k), blk, fb)
-        rows = jnp.where((x_rows >= 0)[..., None], rows, -1)
-        return rows.reshape(W, R)
 
     def merge(tgt, cand_node, cand_l, queried, new_rows):
         """Fetch the replies' ids (stage ``fetch_ids``) and insert them
         (stage ``merge``)."""
         return insert(tgt, cand_node, cand_l, queried, new_rows,
-                      fetch_ids(new_rows, NL))                    # NL×[W,R]
+                      fetch_ids(new_rows, NL))                    # NL×[R,W]
 
     @device_stage("merge")
     def insert(tgt, cand_node, cand_l, queried, new_rows, new_l):
         """Insert replies, dedupe by node, keep the S closest
         (↔ Search::insertNode, src/search.h:636-722).  ``cand_l`` is the
-        candidate distance as NL limb planes [W, S]; everything stays
-        2-D."""
+        candidate distance as NL limb planes [W, S]; ``new_rows`` and
+        ``new_l`` arrive slot-major [R, W].  On the TPU a [W, S] array
+        is laid out with W on the lanes (physically [S, W]), so the
+        ``.T`` of a slot-major plane is a change of name, not a copy,
+        and the concatenate joins along the physical major axis."""
         W = tgt.shape[0]
-        node = jnp.concatenate([cand_node, new_rows], axis=1)     # [W,S+R]
-        d_l = [jnp.concatenate([cand_l[l], new_l[l] ^ tgt[:, l:l + 1]],
+        node = jnp.concatenate([cand_node, new_rows.T], axis=1)   # [W,S+R]
+        d_l = [jnp.concatenate([cand_l[l],
+                                (new_l[l] ^ tgt[:, l][None, :]).T],
                                axis=1) for l in range(NL)]
         qd = jnp.concatenate([queried, jnp.zeros((W, R), jnp.int32)], axis=1)
         inv = (node < 0).astype(jnp.int32)
@@ -481,7 +527,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     # -- bootstrap: cold start from ONE pseudo-random bootstrap peer per
     # search (like a node boots from a single well-known host) ------------
     empty = n <= 0
-    boot = jnp.full((Q, alpha), -1, jnp.int32).at[:, 0].set(
+    boot = jnp.full((alpha, Q), -1, jnp.int32).at[0].set(
         jnp.where(
             empty, -1,
             (_mix32(q_index.astype(_U32) ^ seed_u)
@@ -507,15 +553,17 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     def select(cand_node, cand_l0, queried, done):
         """Stage ``select``: the closest α unqueried candidates per active
         search (↔ searchSendGetValues picking SearchNodes with canGet,
-        src/dht.cpp:628-639) as rows [W, alpha] (−1 pad), marked queried."""
+        src/dht.cpp:628-639) as peer-major rows [alpha, W] (−1 pad),
+        marked queried."""
         can = (cand_node >= 0) & (queried == 0) & ~done[:, None]
         rank = jnp.cumsum(can.astype(jnp.int32), axis=1)
         sel = can & (rank <= alpha)
-        # gather selected rows into [W, alpha] (−1 pad): α static
-        # masked max-reductions — a scatter-max here measured slower
+        # gather selected rows into [alpha, W] (−1 pad): α static
+        # masked max-reductions — a scatter-max here measured slower —
+        # handed over as the α [W] vectors they are (W on the lanes)
         x_rows = jnp.stack(
             [jnp.max(jnp.where(sel & (rank == j + 1), cand_node, -1),
-                     axis=1) for j in range(alpha)], axis=1)
+                     axis=1) for j in range(alpha)], axis=0)
         if block_bounds is not None:
             # ROUND FUSION: the selected peers' top distance limb
             # rides the same masked max-reductions (cand_l[0] is
@@ -530,7 +578,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             x_d0 = jnp.stack(
                 [jnp.max(jnp.where(sel & (rank == j + 1), cand_l0,
                                    _U32(0)), axis=1)
-                 for j in range(alpha)], axis=1)
+                 for j in range(alpha)], axis=0)
         else:
             x_d0 = None
         return sel, x_rows, x_d0, jnp.where(sel, 1, queried)
@@ -629,7 +677,11 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         dist = jnp.stack([cl[:, :k] for cl in cand_l], axis=-1)
     else:
         # reconstruct the full 160-bit distances from the final node ids
-        # in ONE gather — the merge loop never carried limbs 2-4
+        # in ONE gather — the merge loop never carried limbs 2-4.
+        # Lookup-major on purpose: a lookup's k nodes are neighbours in
+        # the sorted table, and in that order this once-a-wave fetch
+        # measured 1.75 ms on each of four table shards against 8.90
+        # slot-major (one chip: 7.60 against 6.81; PERF.md §6, PR 27)
         id_l = fetch_ids(nodes_k, N_LIMBS)
         dist = jnp.stack(
             [jnp.where(nodes_k >= 0, id_l[l] ^ targets[:, l:l + 1],
@@ -713,6 +765,14 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
     # behind a device-side soundness guard (_guarded_lower_bound):
     # clustered tables whose largest bucket exceeds the bounded
     # in-bucket budget take the full-depth search instead.
+    # The same rule for the reply path (PR 27): a [Q, alpha, k]
+    # intermediate pads (3, 8) to a (4, 128) tile, 21× the bytes, so a
+    # round's replies are slot-major planes [alpha·k, Q] from the reply
+    # model through the gather to the merge's concatenate — Q on the
+    # lanes, where XLA keeps the [Q, S] state anyway (physically
+    # [S, Q]) — and the gather's flat index and planes use that order,
+    # so neither side of it transposes (_lookup_engine, REPLY-PATH
+    # LAYOUT).
     sorted_t = sorted_ids.T                            # [5, N] one transpose
     if lut is None:
         # callers with a stable table should build this once with

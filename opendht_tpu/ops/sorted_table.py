@@ -163,6 +163,21 @@ def fused_gather_planar(sorted_t, rows, limbs: int = N_LIMBS):
     gather pads its minor dim 5 → 128 in TPU tiled layout; [5, M]
     planes stay unpadded.
 
+    LAYOUT: the gather is elementwise in its index, so the flat index
+    and the flat planes are simply ``rows`` raveled and un-raveled in
+    ``rows``' own order — whoever wants a cheap flatten hands over
+    ``rows`` with its long axis last (the engine: slot-major [α·k, W],
+    one dense reshape each way and no transpose).  ``rows`` is pinned
+    as the caller computed it by an optimization barrier: the gather
+    wants its index as ``[M, 1]``, and without the barrier XLA moves
+    that reshape UP through every elementwise producer, so the
+    caller's whole index arithmetic runs in ``[M, 1]`` — one sublane of
+    eight in use on the TPU (6.5 ms a wave at M = 1.57M, PERF.md §6,
+    PR 27) — and is charged to this gather's stage.  Behind the barrier
+    ``[M, 1]`` is a bare view of a finished flat vector.  The take says
+    what happens to an out-of-range row (``mode="clip"``), so there is
+    no negative-index wrap before the gather and no fill after it.
+
     Exact by construction and pinned against the full-materialization
     oracle :func:`~opendht_tpu.ops.xor_topk.gather_rows`
     (tests/test_topk.py).  Out-of-range rows (e.g. the engine's -1
@@ -170,9 +185,8 @@ def fused_gather_planar(sorted_t, rows, limbs: int = N_LIMBS):
     every caller masks them (the oracle returns the all-ones sentinel
     there instead).
     """
-    N = sorted_t.shape[1]
-    cl = jnp.clip(rows, 0, N - 1).reshape(-1)
-    g = jnp.take(sorted_t[:limbs], cl, axis=1)          # [limbs, M]
+    flat = lax.optimization_barrier(rows).reshape(-1)
+    g = jnp.take(sorted_t[:limbs], flat, axis=1, mode="clip")   # [limbs, M]
     return [g[l].reshape(rows.shape) for l in range(limbs)]
 
 

@@ -58,7 +58,7 @@ from ..ops.ids import N_LIMBS
 from ..ops.xor_topk import xor_topk, select_topk, mask_invalid
 from ..ops.sorted_table import (sort_table, window_topk, build_prefix_lut,
                                 default_lut_bits, expand_table, expanded_topk,
-                                _EROW)
+                                fused_gather_planar, _EROW)
 from ..core.search import (simulate_lookups, _lookup_engine,
                            _guarded_lower_bound, _lut_block_bounds,
                            TARGET_NODES, ALPHA, SEARCH_NODES)
@@ -409,19 +409,22 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             # (the α·k reply fetch): the per-round 1-limb peer fetch's
             # psum site is gone — the engine reads the carried
             # candidate distance instead (core/search.py).
-            flat = (rows - base).reshape(-1)
+            # The index keeps ``rows``' own shape up to the gather
+            # (the engine's: slot-major, W on the lanes) and the planes
+            # come back in it — ops.sorted_table.fused_gather_planar,
+            # which clips to this shard's [0, shard_n).
+            loc = rows - base
             # ownership test: weighted shards own exactly n_local rows
             # (the [b_i, b_{i+1}) ranges partition the valid prefix);
             # the uniform test keeps the static width — equivalent for
             # valid rows, and it leaves the uniform program unchanged
-            ok = (flat >= 0) & (flat < (n_local if weighted else shard_n))
-            g = jnp.take(sorted_t[:limbs], jnp.clip(flat, 0, shard_n - 1),
-                         axis=1)
-            g = jnp.where(ok[None, :], g, _U32(0))
+            ok = (loc >= 0) & (loc < (n_local if weighted else shard_n))
+            g = jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
+                           fused_gather_planar(sorted_t, loc, limbs)])
             # the round's one collective, a device stage of its own
             g = device_stage("owner_merge")(
                 lambda part: lax.psum(part, "t"))(g)
-            return [g[l].reshape(rows.shape) for l in range(limbs)]
+            return [g[l] for l in range(limbs)]
 
         q_index = (lax.axis_index("q").astype(jnp.int32) * q_local
                    + jnp.arange(q_local, dtype=jnp.int32))

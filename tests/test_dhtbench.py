@@ -13,9 +13,18 @@ dhtbench/tests``).  A file the benchmark already has may be edited only by
 a ``benchmark`` PR, which mends that one line and then deletes the copy
 and the ``del`` below (PERF.md §7).  The copy asserts everything the
 original does.
+
+A SECOND CASE, the same way (PR 27):
+``test_each_stage_metric_names_a_stage_of_the_program`` pins the metric
+files of the ``stage`` source to four and their stages to ``fetch_ids``,
+``block_bounds``, ``merge``; ``metrics/sim_reply_rows_ms_per_wave.json``
+(the file PR 26 asked for and ISSUE 27 brought) makes them five, so the
+original fails where it stands.  The copy here holds every stage metric
+to a stage the program names, and names the four it expects.
 """
 
 import json
+import os
 
 import pytest
 
@@ -26,8 +35,10 @@ from dhtbench import run                            # noqa: E402
 from dhtbench.tests.test_dhtbench import *          # noqa: E402,F401,F403
 from dhtbench.tests.test_dhtbench import RESULT_KEYS, manifest  # noqa: E402,F401
 from dhtbench.tests.test_stage_source import *      # noqa: E402,F401,F403
+from dhtbench.tests.test_stage_source import STAGES  # noqa: E402
 
 del test_sim_cell_at_toy_size_prints_the_contract_line      # noqa: F821
+del test_each_stage_metric_names_a_stage_of_the_program     # noqa: F821
 
 # the per-layer metrics of the sim cell that read the program's registry,
 # and so read something on the CPU too; the trace ones read nothing there
@@ -53,3 +64,12 @@ def test_sim_cell_at_toy_size_reports_every_registry_metric(manifest, trace):  #
                                         "sim_wave_p90_ms", "setup_s"}
     for m in line["metrics"].values():
         assert set(m) == {"value", "unit"} and m["value"] > 0
+
+
+def test_each_stage_metric_file_names_a_stage_of_the_program():
+    mdir = os.path.join(run.HERE, "metrics")
+    specs = [run.load_json(mdir, f) for f in sorted(os.listdir(mdir))]
+    staged = [m["source"] for m in specs if m["source"]["kind"] == "stage"]
+    assert len(staged) == 5
+    assert {s["stage"] for s in staged if s["value"] == "stage_ms_per"} \
+        == {"fetch_ids", "reply_rows", "block_bounds", "merge"} <= set(STAGES)

@@ -293,3 +293,134 @@ def test_lut_block_bounds_exact_up_to_lut_width():
                     want_lo = min(want_lo, int(nv))
                 assert lo[i] == want_lo and ub[i] == want_ub, \
                     (cluster, L, i, lo[i], ub[i], want_lo, want_ub)
+
+
+def _np_mix32(x):
+    """``core.search._mix32`` on uint64 lanes masked to 32 bits."""
+    m = np.uint64(0xFFFFFFFF)
+    x = x & m
+    x ^= x >> np.uint64(16)
+    x = (x * np.uint64(0x7FEB352D)) & m
+    x ^= x >> np.uint64(15)
+    x = (x * np.uint64(0x846CA68B)) & m
+    x ^= x >> np.uint64(16)
+    return x
+
+
+@pytest.mark.parametrize("block_mode", ["lut", "exact"])
+@pytest.mark.parametrize("alpha", [3, 4])
+def test_reply_rows_equal_the_w_alpha_k_formula(alpha, block_mode):
+    """The slot-major reply rows of a round are, element for element,
+    the rows of the ``[W, α, k]`` formula the engine used to compute in
+    (PR 27): counter ``(((round·Q + q)·α + a)·k + j) ^ seed``, hash,
+    modulo into the peer's block when it holds ≥ k rows, else the slice
+    of the fallback window, −1 for a slot that sent no request — here in
+    plain numpy over python-int ids, block edges included.  The table is
+    small enough that all three cases occur."""
+    import bisect
+    from opendht_tpu.ops.ids import clz32
+    from opendht_tpu.ops.sorted_table import _lower_bound, build_prefix_lut
+    from opendht_tpu.core import search as S
+
+    N, W, k, bits, rnd, seed, q_total = 300, 48, 8, 6, 3, 0x9E3779B9, 59
+    R = alpha * k
+    rng = np.random.default_rng(270 + alpha)
+    sorted_ids, n = _network(N, 27)
+    n = int(n)
+    ids = np.asarray(sorted_ids)[:n]
+    tgt = rng.integers(0, 2**32, size=(W, 5), dtype=np.uint32)
+
+    def as_int(limbs):
+        return int.from_bytes(np.asarray(limbs, ">u4").tobytes(), "big")
+
+    ids_int = [as_int(r) for r in ids]
+    t_int = [as_int(t) for t in tgt]
+    pt = np.array([bisect.bisect_left(ids_int, t) for t in t_int], np.int32)
+    qidx = np.arange(W, dtype=np.int32) + 5          # global ids, not 0..W
+    # peers [W, alpha]: far (random rows), near (next to the target), unsent
+    x_rows = rng.integers(0, n, size=(W, alpha)).astype(np.int32)
+    near = rng.random((W, alpha)) < 0.4
+    x_rows = np.where(near, np.clip(pt[:, None] + rng.integers(
+        -2, 3, size=(W, alpha)), 0, n - 1), x_rows).astype(np.int32)
+    x_rows[rng.random((W, alpha)) < 0.25] = -1
+
+    # --- the old formula, numpy, logical shape [W, alpha, k] -------------
+    lo = np.zeros((W, alpha), np.int64)
+    ub = np.zeros((W, alpha), np.int64)
+    for q in range(W):
+        for a in range(alpha):
+            x = x_rows[q, a]
+            if block_mode == "exact":
+                d = ids_int[max(x, 0)] ^ t_int[q]
+                plen = min(160 - d.bit_length() + 1, 160)
+                mask = ((1 << plen) - 1) << (160 - plen)
+                p_lo = t_int[q] & mask
+                lo[q, a] = bisect.bisect_left(ids_int, p_lo)
+                ub[q, a] = bisect.bisect_left(
+                    ids_int, p_lo + (1 << (160 - plen)))
+            else:
+                # carried top limb, 0 for an unsent slot; clamped at `bits`
+                d0 = int(ids[x, 0] ^ tgt[q, 0]) if x >= 0 else 0
+                plen = min(32 - d0.bit_length() + 1, bits)
+                top = [int(r[0]) >> (32 - plen) for r in ids]
+                lo[q, a] = bisect.bisect_left(top, int(tgt[q, 0]) >> (32 - plen))
+                ub[q, a] = bisect.bisect_right(top, int(tgt[q, 0]) >> (32 - plen))
+    size = np.maximum(ub - lo, 0)
+    qi = qidx.astype(np.uint64)[:, None, None]
+    ai = np.arange(alpha, dtype=np.uint64)[None, :, None]
+    ji = np.arange(k, dtype=np.uint64)[None, None, :]
+    ctr = (((np.uint64(rnd) * np.uint64(q_total) + qi) * np.uint64(alpha)
+            + ai) * np.uint64(k) + ji) ^ np.uint64(seed)
+    h = _np_mix32(ctr).astype(np.int64)
+    blk = lo[..., None] + h % np.maximum(size, 1)[..., None]
+    base = np.clip(pt.astype(np.int64) - R // 2, 0, max(n - R, 0))
+    fb = np.clip(base[:, None, None] + (ai * np.uint64(k) + ji).astype(
+        np.int64), 0, max(n - 1, 0))
+    want = np.where(size[..., None] >= k, blk, fb)
+    want = np.where((x_rows >= 0)[..., None], want, -1).reshape(W, R)
+    sent = x_rows >= 0
+    assert (sent & (size >= k)).any() and (sent & (size < k)).any() \
+        and (~sent).any()
+
+    # --- the engine's: peer-major in, slot-major out ----------------------
+    tj, xj = jnp.asarray(tgt), jnp.asarray(x_rows.T)            # [a, W]
+    x_l = [jnp.asarray(ids[np.maximum(x_rows.T, 0), l]) for l in range(5)]
+    if block_mode == "exact":
+        b = S._common_bits_planar(x_l, [tj[:, l][None, :] for l in range(5)])
+        lo_j, ub_j = S._prefix_block_bounds(
+            lambda flat: _lower_bound(sorted_ids, flat, n), n,
+            jnp.broadcast_to(tj[None], (alpha, W, 5)),
+            jnp.clip(b + 1, 0, 160))
+    else:
+        x_d0 = jnp.where(xj >= 0, x_l[0] ^ tj[:, 0][None, :], 0)
+        lo_j, ub_j = S._lut_block_bounds(
+            build_prefix_lut(sorted_ids, n, bits=bits), tj[:, 0][None, :],
+            clz32(x_d0) + 1)
+    np.testing.assert_array_equal(np.asarray(lo_j).T, lo)
+    np.testing.assert_array_equal(np.asarray(ub_j).T, ub)
+    got = S._reply_rows(jnp.asarray(pt), jnp.asarray(qidx), xj,
+                        jnp.int32(rnd), lo_j, ub_j, n=jnp.int32(n), k=k,
+                        q_total=q_total, seed_u=jnp.uint32(seed))
+    assert got.shape == (R, W)                       # slot-major plane
+    np.testing.assert_array_equal(np.asarray(got).T, want)
+
+
+@pytest.mark.parametrize("state_limbs", [2, 5])
+def test_no_w_alpha_k_tensor_in_the_lowered_round(state_limbs):
+    """The reply path stays slot-major: the lowered module holds no
+    rank-3 ``[W, alpha, k]`` tensor.  On the TPU that shape pads its
+    minor dims (3, 8) to a (4, 128) tile, 21× the bytes, and cost the
+    round its second stage (PERF.md §6, PR 27) — an edit that brings it
+    back fails here, not only in the benchmark.  W, alpha, k chosen so
+    that no other tensor of the module has the shape."""
+    from opendht_tpu.core.search import _simulate_lookups_jit
+
+    W, alpha, k = 44, 3, 5
+    sorted_ids, n = _network(512, 3)
+    targets = jnp.zeros((W, 5), jnp.uint32)
+    text = _simulate_lookups_jit.lower(
+        sorted_ids, n, targets, seed=1, k=k, alpha=alpha,
+        state_limbs=state_limbs).as_text()
+    assert "stage_reply_rows" in text                # the round is in there
+    assert f"tensor<{alpha * k}x{W}x" in text        # ... slot-major
+    assert f"tensor<{W}x{alpha}x{k}x" not in text
