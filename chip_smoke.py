@@ -449,8 +449,9 @@ def phase_simulator(*, n_ids: int, n_targets: int, n_sample: int,
             "load_sort_lut_s": round(load_s, 2),
             "first_wave_s": round(first_s, 2), "warm_wave_s": round(warm_s, 3),
             "reference_s": round(time.perf_counter() - t0, 2),
-            # handed to the four-chip phase, dropped from the printed result
-            "_sorted_ids": sorted_ids, "_n_valid": n_valid,
+            # handed to the four-chip phase, dropped from the printed
+            # result: the key of the ids, not the one-chip table
+            "_ids_key": k1,
             "_targets": targets, "_nodes": nodes, "_hops": hops}
 
 
@@ -750,22 +751,36 @@ def _shard_report(arr, t: int) -> list:
 def phase_four_chips(*, sim: dict, served: dict, n_rows: int,
                      n_requests: int, seed: int,
                      compile_log: CompileLog) -> dict:
-    """The same simulator table row-sharded over ``make_mesh(4, q=1,
-    t=4)``: ``parallel.tp_simulate_lookups`` must be bit-identical in
-    ``nodes`` and ``hops`` to the one-chip wave ``sim`` holds (what
+    """The simulator's ids row-sharded over ``make_mesh(4, q=1, t=4)``:
+    made again from the same key, each shard's rows on its own chip,
+    and sorted ACROSS the mesh by ``parallel.sharded_global_sort`` — the
+    one-chip table is not held beside the shards.
+    ``parallel.tp_simulate_lookups`` on that state must be bit-identical
+    in ``nodes`` and ``hops`` to the one-chip wave ``sim`` holds (what
     ``__graft_entry__.dryrun_multichip`` pins on a CPU mesh).  Then the
     served node with ``Config(resolve_mesh_t=4)``: the same answers as
     ``served`` (t=1) gave on the same seed, resolved sharded on the
     clean snapshot, one N/4 shard per device."""
     import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec
     from opendht_tpu.core.search import SEARCH_NODES
-    from opendht_tpu.parallel import (make_mesh, shard_table_state,
+    from opendht_tpu.parallel import (make_mesh, sharded_global_sort,
                                       tp_simulate_lookups)
 
     t = 4
     mesh = make_mesh(t, q=1, t=t)
     t0 = time.perf_counter()
-    state = shard_table_state(mesh, sim["_sorted_ids"], sim["_n_valid"])
+    # the same bits as phase_simulator's table (threefry is partitionable:
+    # the values do not depend on the sharding), each row made in place
+    ids = jax.jit(
+        lambda key: jax.random.bits(key, (sim["n_ids"], 5), dtype=jnp.uint32),
+        out_shardings=NamedSharding(mesh, PartitionSpec("t", None)))(
+            sim["_ids_key"])
+    state = sharded_global_sort(mesh, ids, donate=True)
+    del ids
+    widths = np.asarray(state.arrays["shard_rows"])[:, 1].tolist()
+    assert sum(widths) == sim["n_ids"], (widths, sim["n_ids"])
     shard_bytes = _shard_report(state.arrays["sorted_ids"], t)
 
     def wave():
@@ -793,6 +808,7 @@ def phase_four_chips(*, sim: dict, served: dict, n_rows: int,
                                         "sharded": True, "resolve_mesh_t": t}
     return {"t": t, "sim_bit_identical": True,
             "sim_shard_rows": int(state.shard_n),
+            "sim_shard_widths": widths,
             "sim_shard_bytes_in_use": shard_bytes,
             "sim_first_wave_s": round(first_s, 2),
             "sim_warm_wave_s": round(warm_s, 3), "served": served4}

@@ -24,3 +24,4 @@ from .sharded import (  # noqa: F401
     tp_simulate_lookups,
     build_tp_lookup,
 )
+from .global_sort import sharded_global_sort  # noqa: F401

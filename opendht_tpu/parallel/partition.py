@@ -14,7 +14,8 @@ hand-rolled per-entry ``jnp.asarray`` + ``device_put`` placement that
 
 The named state it exists for is :func:`shard_table_state`'s pytree —
 the row-sharded sorted table that scales the iterative search engine
-past one chip's HBM (ROADMAP item 1):
+past one chip's HBM (ROADMAP item 1); ``global_sort.sharded_global_sort``
+builds the same pytree from unsorted row-sharded ids:
 
 ``sorted_ids``   uint32 [N, 5]        ``P('t', None)`` — each ``t``
                  shard owns one contiguous range of the global sorted
@@ -290,9 +291,15 @@ def shard_table_state(mesh: Mesh, sorted_ids, n_valid, *,
                       lut_bits: Optional[int] = None,
                       block_bits: Optional[int] = None,
                       boundaries=None) -> TableState:
-    """Split a GLOBALLY sorted id table over the mesh ``t`` axis and
-    derive its lookup state — built ONCE per table, reused across every
-    wave (``tp_simulate_lookups(..., state=)``).
+    """Split an ALREADY globally sorted id table over the mesh ``t``
+    axis and derive its lookup state — built ONCE per table, reused
+    across every wave (``tp_simulate_lookups(..., state=)``).  For a
+    table that has not been sorted, or that no single device or host
+    array should hold whole, use
+    :func:`~opendht_tpu.parallel.global_sort.sharded_global_sort`: it
+    sorts row-sharded ids across the mesh and returns the same
+    :class:`TableState` (in the weighted layout below), so nothing here
+    asks for a one-device ``sort_table`` or a host sort of the id set.
 
     Row count must divide ``mesh.shape['t']`` (pad with invalid rows
     via :func:`~opendht_tpu.parallel.sharded.pad_to_multiple`; pad rows

@@ -23,7 +23,9 @@ operands by regex partition rules over a named state pytree
 shard fns, the standard large-model JAX pattern), and the iterative
 engine's table state — sorted rows, per-shard positioning LUT, the
 replicated global block LUT, validity — is built ONCE by
-``partition.shard_table_state`` and reused across waves.  Each ``t``
+``partition.shard_table_state`` (from a table sorted elsewhere) or
+``global_sort.sharded_global_sort`` (sorted across the mesh, for a table
+no single memory should hold) and reused across waves.  Each ``t``
 shard holds ~N/t rows (plus the 4·2^bb-byte block LUT); nothing
 table-sized is replicated, so the servable id set scales linearly in
 mesh size.  The steady-state search round costs exactly ONE in-loop
@@ -457,11 +459,16 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
     searched iteratively, not just scanned (10M+ ids spread across the
     mesh, benchmarks/exp_shard_r13.py).
 
-    ``sorted_ids`` must be GLOBALLY sorted (one :func:`sort_table` /
-    host sort over the whole id set); each ``t``-shard then owns one
-    contiguous range of the global sorted order — the Kademlia analog
-    of a node owning the contiguous XOR neighborhood around its id
-    (PARITY.md "t-sharded table").  That contiguity is what makes the
+    The table must be GLOBALLY sorted, each ``t``-shard one contiguous
+    range of the global order — the Kademlia analog of a node owning
+    the contiguous XOR neighborhood around its id (PARITY.md "t-sharded
+    table").  For a table that is sharded because it outgrows one chip,
+    build ``state=`` with
+    :func:`~opendht_tpu.parallel.global_sort.sharded_global_sort`: it
+    sorts ids that already lie row-sharded ACROSS the mesh, and nothing
+    of table size passes through one device or the host.  A table that
+    fits one memory may still be sorted there (:func:`sort_table`) and
+    split by ``shard_table_state``.  That contiguity is what makes the
     distributed primitives cheap:
 
     - positioning (once per wave): global lower_bound = ONE psum of
@@ -480,10 +487,12 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
     identity) — asserted in tests/test_sharded.py.
 
     Callers serving a stable table should pass ``state=`` from
+    ``sharded_global_sort`` or
     :func:`~opendht_tpu.parallel.partition.shard_table_state` (built
     once, reused across waves — the sorted rows and positioning LUTs
     then never re-place or re-derive per call); the raw
-    ``sorted_ids``/``n_valid`` form builds a state pytree on the fly.
+    ``sorted_ids``/``n_valid`` form takes an already sorted table and
+    builds a state pytree on the fly.
 
     targets [Q, 5]: Q divisible by mesh.shape['q']; N divisible by
     mesh.shape['t'] (pad via :func:`pad_to_multiple` — pad rows land

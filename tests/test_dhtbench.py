@@ -20,18 +20,26 @@ files of the ``stage`` source to four and their stages to ``fetch_ids``,
 ``block_bounds``, ``merge``; ``metrics/sim_reply_rows_ms_per_wave.json``
 (the file PR 26 asked for and ISSUE 27 brought) makes them five, so the
 original fails where it stands.  The copy here holds every stage metric
-to a stage the program names, and names the four it expects.
+to a stage the program names, and names the four it expects.  Since
+PR 28 the files are nine: ``host4-100m.wave-65536`` reads the same three
+stages and ``owner_merge`` through files of its own.
+
+PR 28's cell ``host4-100m.wave-65536`` is rehearsed here too, on four of
+the virtual CPU devices, with the assertions of the sim case; and its
+blockwise reference (``dhtbench/reference_blocks.py``) is held to
+``reference.XorIndex`` over the whole id set.
 """
 
 import json
 import os
 
+import numpy as np
 import pytest
 
 pytest.register_assert_rewrite("dhtbench.tests.test_dhtbench",
                                "dhtbench.tests.test_stage_source")
 
-from dhtbench import run                            # noqa: E402
+from dhtbench import reference, reference_blocks, run    # noqa: E402
 from dhtbench.tests.test_dhtbench import *          # noqa: E402,F401,F403
 from dhtbench.tests.test_dhtbench import RESULT_KEYS, manifest  # noqa: E402,F401
 from dhtbench.tests.test_stage_source import *      # noqa: E402,F401,F403
@@ -66,10 +74,121 @@ def test_sim_cell_at_toy_size_reports_every_registry_metric(manifest, trace):  #
         assert set(m) == {"value", "unit"} and m["value"] > 0
 
 
+# the same for the four-chip cell (PR 28): its two envelope spans under
+# mode="tp", and the build span its driver reads in set-up
+HOST4_REGISTRY_METRICS = {"host4_record_ms_per_wave",
+                          "host4_dispatch_ms_per_wave", "host4_table_build_s"}
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_host4_cell_at_toy_size_prints_the_contract_line(manifest, trace):
+    line = run.run_cell("host4-100m.wave-65536", 2 ** 31 + 54321, 1.0, trace,
+                        rehearsal={"n_ids": 16384, "wave_targets": 256,
+                                   "target_sets": 4})
+    line = json.loads(json.dumps(line))
+    assert set(line) == RESULT_KEYS         # no breakdown: the CPU has no device plane
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0 and line["attempted"] % 256 == 0
+    assert set(line["device"]) == {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    assert line["device"]["count"] >= 4
+    if trace:
+        assert set(line["metrics"]) == HOST4_REGISTRY_METRICS
+    else:
+        assert set(line["metrics"]) == {"sim_lookups_per_s",
+                                        "sim_wave_p90_ms", "setup_s"}
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0
+
+
+def test_host4_check_fails_a_table_that_is_not_the_seeds(manifest):
+    """The ``sorted`` guarantee is checked, not assumed: a built table
+    with two rows swapped, one row doubled over another, or a width off
+    by one is not ``correct``."""
+    import jax.numpy as jnp
+    from dhtbench.drivers import sim_tp
+    cell, config, driver, _ = run.resolve("host4-100m.wave-65536")
+    assert driver is sim_tp
+    config = dict(config, sizes=dict(config["sizes"], n_ids=8192))
+    st = sim_tp.setup(config, dict(cell["traffic"], wave_targets=64,
+                                   target_sets=2), 5, lambda msg: None)
+    try:
+        assert sim_tp._built_right(st, 8192) is None
+        arrays = st.state.arrays
+        good = arrays["sorted_ids"]
+        swapped = good.at[jnp.array([3, 4])].set(good[jnp.array([4, 3])])
+        doubled = good.at[3].set(good[4])
+        for bad, why in ((swapped, "not ascending"), (doubled, "checksum")):
+            arrays["sorted_ids"] = bad
+            assert why in sim_tp._built_right(st, 8192)
+        arrays["sorted_ids"] = good
+        st.shard_rows = st.shard_rows + np.array([[0, 1], [0, 0], [0, 0],
+                                                  [0, 0]])
+        assert "expected from" in sim_tp._built_right(st, 8192)
+    finally:
+        sim_tp.close(st)
+
+
+@pytest.mark.parametrize("ascending", [False, True])
+@pytest.mark.parametrize("blocks", [1, 4, 7])
+def test_blockwise_reference_equals_the_whole_table_reference(blocks,
+                                                              ascending):
+    rng = np.random.default_rng(31)
+    ids = rng.integers(0, 2 ** 32, size=(6000, 5), dtype=np.uint32)
+    ids[100:140, :2] = ids[100, :2]            # a run that shares 64 bits
+    if ascending:           # as a built shard arrives: no argsort is run
+        ids = ids[np.lexsort(ids.T[::-1])]
+    targets = np.concatenate([
+        rng.integers(0, 2 ** 32, size=(12, 5), dtype=np.uint32), ids[100:104]])
+    whole = reference.XorIndex(ids)
+    edges = np.linspace(0, 6000, blocks + 1).astype(int)
+    edges[1:-1] += rng.integers(-50, 50, size=blocks - 1)
+    pieces = ((int(a), ids[a:b]) for a, b in zip(edges[:-1], edges[1:]))
+    got = reference_blocks.closest_over_blocks(pieces, targets, 8)
+    for target, rows in zip(targets, got):
+        want = whole.closest(target, 8)
+        # equal ids may be picked in either order: compare the distances
+        np.testing.assert_array_equal(ids[rows] ^ target, ids[want] ^ target)
+    if ascending and blocks > 1:
+        # blocks that are ranges of the key space, as the shards of a
+        # range-partitioned table are: most targets lie outside a block's
+        # range, and are still answered from a handful of candidates —
+        # not from the whole block, which at 25M rows a block took eight
+        # minutes a run (PERF.md section 6, PR 28)
+        block = reference_blocks.BlockIndex(ids[edges[1]:edges[2]])
+        outside = [t for t in targets
+                   if not (block.key[0] >> np.uint64(32)) <= t[0]
+                   <= (block.key[-1] >> np.uint64(32))]
+        assert outside
+        assert max(len(block.candidates(t, 8)) for t in targets) <= 48
+    # a block of fewer than k rows, and an empty one
+    tiny = reference_blocks.closest_over_blocks(
+        [(0, ids[:3]), (3, ids[3:3]), (3, ids[3:6000])], targets, 8)
+    for rows, full in zip(tiny, got):
+        np.testing.assert_array_equal(rows, full)
+
+
 def test_each_stage_metric_file_names_a_stage_of_the_program():
     mdir = os.path.join(run.HERE, "metrics")
-    specs = [run.load_json(mdir, f) for f in sorted(os.listdir(mdir))]
-    staged = [m["source"] for m in specs if m["source"]["kind"] == "stage"]
-    assert len(staged) == 5
-    assert {s["stage"] for s in staged if s["value"] == "stage_ms_per"} \
-        == {"fetch_ids", "reply_rows", "block_bounds", "merge"} <= set(STAGES)
+    specs = {f[:-len(".json")]: run.load_json(mdir, f)
+             for f in sorted(os.listdir(mdir))}
+    staged = {name: m["source"] for name, m in specs.items()
+              if m["source"]["kind"] == "stage"}
+    assert len(staged) == 9
+    assert {name: s["stage"] for name, s in staged.items()
+            if s["value"] == "stage_ms_per"} == {
+        "sim_fetch_ids_ms_per_wave": "fetch_ids",
+        "sim_reply_rows_ms_per_wave": "reply_rows",
+        "sim_block_bounds_ms_per_wave": "block_bounds",
+        "sim_merge_ms_per_wave": "merge",
+        "host4_owner_merge_ms_per_wave": "owner_merge",
+        "host4_fetch_ids_ms_per_wave": "fetch_ids",
+        "host4_block_bounds_ms_per_wave": "block_bounds",
+        "host4_merge_ms_per_wave": "merge"}
+    # every stage a metric names is one the program names: those of the
+    # round engine, and the tp twin's collective
+    from opendht_tpu.parallel import sharded
+    import inspect
+    assert 'device_stage("owner_merge")' in inspect.getsource(sharded)
+    assert {s["stage"] for s in staged.values() if "stage" in s} \
+        <= set(STAGES) | {"owner_merge"}
