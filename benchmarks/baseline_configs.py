@@ -191,7 +191,7 @@ def config3(Q: int = 0, N: int = 0, chunk: int = 0,
     N = N or (10_000_000 if on_accel else 100_000)
     Q = Q or (65_536 if on_accel else 1_024)
     # measured optimum wave width on v5e AFTER the round-5 LUT block
-    # bounds removed the per-round positioning search (exp_search_r5
+    # bounds removed the per-round positioning search (round-5
     # sweep, 10M table: 8K/16K/32K/64K/128K/256K waves = 282/270/401/
     # 442/421/350 K lookups/s) — with the serial search gone, wider
     # waves amortize the issue-bound gathers until HBM pressure turns

@@ -81,6 +81,18 @@ ALPHA = 4            # in-flight requests per search (dht.h:321)
 SEARCH_NODES = 14    # candidate set size (dht.h:308)
 TARGET_NODES = 8     # convergence set (routing_table.h:26)
 
+# SURVIVOR COMPACTION (_lookup_engine): a wave's loop hands over to one
+# an eighth as wide as soon as the live lookups fit it.  An eighth sits
+# between the shares the last rounds fall to (6.4% at 10M uniform ids,
+# 2.2% at 100M) and those before them (58%, 35%; PERF.md §5), so the cut
+# comes where the live count drops.
+NARROW_DIVISOR = 8
+# Waves under this width keep one loop: a round's fixed cost does not
+# shrink with its width.  Chosen from the chip (PERF.md §6, PR 29): at
+# 10M ids a cut of the last two rounds took 3.2 ms off a 4,096-lookup
+# wave of 28.2 and ADDED 3.0 to a 2,048-lookup wave of 15.2.
+NARROW_MIN_WAVE = 4096
+
 
 def _mix32(x):
     """Counter-based uint32 hash (splitmix-style) for reply sampling."""
@@ -316,8 +328,6 @@ def _reply_rows(pt, qidx, x_rows, round_no, lo, ub, *, n, k, q_total,
 def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                    seed_u, *, k, alpha, search_nodes, max_hops,
                    state_limbs: int = N_LIMBS,
-                   compact_after: "int | None" = None,
-                   compact_cap: int = 0,
                    block_bounds=None):
     """The iterative-lookup state machine, abstracted over table access.
 
@@ -376,8 +386,8 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
     DEVICE STAGES: each part of a round runs through
     ``telemetry.device_stage`` — ``select``, ``block_bounds``,
-    ``reply_rows``, ``fetch_ids``, ``merge``, ``converge`` — an inner
-    jit named ``stage_<name>``.  A part that is a function of its
+    ``reply_rows``, ``fetch_ids``, ``merge``, ``converge``, and once a
+    cut ``pack`` — an inner jit named ``stage_<name>``.  A part that is a function of its
     arguments alone is decorated where it is defined; one that closes
     over values of the trace it runs in (the table, the LUT, the seed)
     is wrapped where it is CALLED, a fresh jit per call.  XLA inlines
@@ -400,18 +410,32 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     returned ``dist`` carries all 5 limbs (reconstructed from the final
     node ids in one gather).
 
-    ``compact_after`` (static round count) enables SURVIVOR COMPACTION:
-    after that many rounds the (typically small) set of unconverged
-    searches is packed into a ``compact_cap``-wide sub-batch on device
-    (``jnp.nonzero(size=cap)`` — no host sync) and run to convergence
-    at the narrow width, then scattered back; a final full-width loop
-    resuming AT THE CUT ROUND is the safety net for cap overflow (it
-    runs ZERO iterations when the cap held).  Reply streams are keyed
-    by (global query id, round number), so results are bitwise
-    identical to the uncompacted run regardless of cap (overflow rows
-    replay exactly the rounds they were paused for); the sole
-    exception is a row still unconverged at ``max_hops``, which both
-    engines report converged=False.
+    SURVIVOR COMPACTION: a round's cost is set by the index elements it
+    issues — α·k·W for the reply gather, 2·α·W for the LUT reads —
+    whatever the number of lookups that still need the round, and the
+    last rounds of a wave run for a few per cent of it (10M uniform
+    ids: 6.4% are live after loop round 7 and 0.04% after round 8 of
+    9; PERF.md §5).  So a loop also stops once the live count
+    ``sum(~done)`` — which the engine observes, nobody configures — is
+    at or under ``C = W // NARROW_DIVISOR``.  The survivors are then
+    packed into a ``C``-wide sub-batch on the device
+    (``jnp.nonzero(size=C)``, no host sync; stage ``pack``) and run on
+    as a wave of ``C`` lookups through the same round body — to which
+    the same rule applies, so a 65,536-lookup wave steps down to 8,192
+    and then 1,024 lanes — and what the caller reads of them (the
+    first k candidates, ``hops``, ``converged``) is written back to
+    their rows.  A cut waits until the survivors fit, so the cap always
+    holds and no loop has to catch an overflow.  Reply streams are
+    keyed by (global query id, round number): a narrower loop carries
+    the round counter on from the cut and the survivors' own
+    ``q_index``, so every output equals the one-loop engine's bit for
+    bit, a lookup that runs into ``max_hops`` included (no loop runs
+    past it).  Waves under ``NARROW_MIN_WAVE`` keep one loop.  On a mesh
+    the search state is replicated over ``t``: every ``t``-rank sees the
+    same ``done``, cuts in the same round and packs the same survivors;
+    ``q``-ranks may cut in different rounds (the round's psum is over
+    ``t`` only).  The engine also returns ``narrow_rounds``, the number
+    of rounds it ran under the wave's full width (0 = never cut).
     """
     Q = targets.shape[0]
     S = search_nodes
@@ -608,73 +632,67 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             return cand_node, cand_l, queried, hops, done, round_no + 1
         return body
 
-    def cond(state):
-        done, round_no = state[4], state[5]
-        return (~jnp.all(done)) & (round_no < max_hops)
+    def live_over(cap):
+        """Loop condition: more than ``cap`` lookups live, rounds left."""
+        def cond(state):
+            done, round_no = state[4], state[5]
+            return (jnp.sum(~done) > cap) & (round_no < max_hops)
+        return cond
 
-    body_full = make_body(targets, pos_t_full, q_index)
-    state = (cand_node, cand_l, queried,
-             jnp.zeros((Q,), jnp.int32),
-             synced(cand_node, queried) | empty,
-             jnp.int32(0))
+    def head(cand_node, cand_l, queried):
+        """What the outputs read of a search state: the first k
+        candidates, their carried distance planes (exact mode only; the
+        2-limb mode fetches ids instead) and the converged flags."""
+        return (cand_node[:, :k],
+                [cl[:, :k] for cl in cand_l] if NL == N_LIMBS else [],
+                synced(cand_node, queried))
 
-    if compact_after is None:
-        cand_node, cand_l, queried, hops, done, _ = \
-            lax.while_loop(cond, body_full, state)
-    else:
-        cut = min(compact_after, max_hops)
+    def run(width, tgt, pt, qidx, state):
+        """Run the ``width`` lookups of ``state`` to the end (SURVIVOR
+        COMPACTION): the loop at this width until the live ones fit
+        ``C`` lanes, then — packed — as a wave of ``C`` lookups, to
+        which the same rule applies.  ``C`` = 0 (a wave under the
+        threshold): the loop runs until nobody is live and is the last.
+        Returns what the outputs read (:func:`head` and ``hops``), the
+        round this width's loop ended in and the round the last one
+        did."""
+        C = width // NARROW_DIVISOR if width >= NARROW_MIN_WAVE else 0
+        cand_node, cand_l, queried, hops, done, round_no = lax.while_loop(
+            live_over(C), make_body(tgt, pt, qidx), state)
+        outs = (*head(cand_node, cand_l, queried), hops)
+        if not C:
+            return outs, round_no, round_no
 
-        def cond1(st):
-            return (~jnp.all(st[4])) & (st[5] < cut)
+        @device_stage("pack")
+        def pack(done, wide):
+            """The live lookups' rows (fill lanes: ``width``, past the
+            end) and their slice of every array of ``wide``; a fill
+            lane reads the last row and is marked done, so it sends
+            nothing."""
+            rows = jnp.nonzero(~done, size=C, fill_value=width)[0]
+            return rows, rows >= width, jax.tree.map(
+                lambda a: jnp.take(a, rows, axis=0, mode="clip"), wide)
 
-        cand_node, cand_l, queried, hops, done, rnd = \
-            lax.while_loop(cond1, body_full, state)
+        @device_stage("pack")
+        def unpack(rows, wide, narrow):
+            """Write the survivors' outputs back to their rows (a fill
+            lane's row is out of range and dropped)."""
+            return jax.tree.map(
+                lambda w, n: w.at[rows].set(n, mode="drop"), wide, narrow)
 
-        # pack survivors into a cap-wide sub-batch (fill duplicates of
-        # row 0 recompute identical values — harmless); run them to
-        # convergence at the narrow width, scatter back
-        C = compact_cap or max(1, Q // 2)
-        sel_rows = jnp.nonzero(~done, size=C, fill_value=0)[0]
-        live = jnp.take(~done, sel_rows)
+        rows, filled, (tgt, pt, qidx, *sub) = pack(
+            done, (tgt, pt, qidx, cand_node, cand_l, queried, hops))
+        sub_outs, _, last_round = run(C, tgt, pt, qidx,
+                                      (*sub, filled, round_no))
+        return unpack(rows, outs, sub_outs), round_no, last_round
 
-        def sub(a):
-            return jnp.take(a, sel_rows, axis=0)
+    (nodes_k, dist_k, converged, hops), cut_round, last_round = run(
+        Q, targets, pos_t_full, q_index,
+        (cand_node, cand_l, queried, jnp.zeros((Q,), jnp.int32),
+         synced(cand_node, queried) | empty, jnp.int32(0)))
 
-        sub_state = (sub(cand_node), [sub(cl) for cl in cand_l],
-                     sub(queried), sub(hops), ~live, rnd)
-        body_sub = make_body(sub(targets), sub(pos_t_full), sub(q_index))
-        cn2, cl2, qd2, hp2, dn2, rnd2 = \
-            lax.while_loop(cond, body_sub, sub_state)
-
-        lv = live[:, None]
-        cand_node = cand_node.at[sel_rows].set(
-            jnp.where(lv, cn2, sub(cand_node)))
-        cand_l = [cl.at[sel_rows].set(jnp.where(lv, c2, sub(cl)))
-                  for cl, c2 in zip(cand_l, cl2)]
-        queried = queried.at[sel_rows].set(jnp.where(lv, qd2, sub(queried)))
-        hops = hops.at[sel_rows].set(jnp.where(live, hp2, sub(hops)))
-        done = done.at[sel_rows].set(jnp.where(live, dn2, sub(done)))
-
-        # safety net: if more than C searches survived the cut, finish
-        # them at full width (ZERO iterations when the cap held).  The
-        # round counter RESTARTS AT THE CUT, not at the sub-loop's end:
-        # overflow rows were paused at round `rnd`, so resuming there
-        # replays exactly the reply streams the uncompacted engine
-        # would have given them (streams key on global query id +
-        # round) — bitwise identity holds even on overflow, and the
-        # sub-loop cannot starve overflow rows' round budget.  Rows
-        # the sub-loop already finished are done and untouched.  (The
-        # one residual divergence: a row still unconverged at max_hops
-        # after the sub-loop re-enters here and sees its last rounds'
-        # streams again — it can only stall/dedup on them, and such
-        # rows are reported converged=False either way.)
-        cand_node, cand_l, queried, hops, done, _ = lax.while_loop(
-            cond, body_full,
-            (cand_node, cand_l, queried, hops, done, rnd))
-
-    nodes_k = cand_node[:, :k]
     if NL == N_LIMBS:
-        dist = jnp.stack([cl[:, :k] for cl in cand_l], axis=-1)
+        dist = jnp.stack(dist_k, axis=-1)
     else:
         # reconstruct the full 160-bit distances from the final node ids
         # in ONE gather — the merge loop never carried limbs 2-4.
@@ -691,22 +709,21 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         "nodes": nodes_k,
         "dist": dist,
         "hops": hops,
-        "converged": synced(cand_node, queried) & ~empty,
+        "converged": converged & ~empty,
+        "narrow_rounds": last_round - cut_round,
     }
 
 
 @functools.partial(
     jax.jit,
     static_argnames=("k", "alpha", "search_nodes", "max_hops",
-                     "state_limbs", "compact_after", "compact_cap",
-                     "block_mode"),
+                     "state_limbs", "block_mode"),
 )
 def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
                           k: int = TARGET_NODES, alpha: int = ALPHA,
                           search_nodes: int = SEARCH_NODES, max_hops: int = 48,
                           lut=None, state_limbs: int = N_LIMBS,
-                          compact_after: "int | None" = None,
-                          compact_cap: int = 0, block_mode: str = "lut"):
+                          block_mode: str = "lut"):
     """Compiled core of :func:`simulate_lookups` (same contract; the
     public wrapper adds the host-side telemetry envelope).
 
@@ -721,6 +738,9 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
       dist      [Q, k, 5]     — their XOR distances
       hops      [Q] int32     — rounds until the first-k set had replied
       converged [Q] bool
+      narrow_rounds int32     — rounds the wave ran under its full width
+                                (:func:`_lookup_engine`, SURVIVOR
+                                COMPACTION; 0 = one loop did it all)
 
     Single-device instantiation of :func:`_lookup_engine`.  The
     table-sharded multi-chip form (table rows partitioned over a mesh
@@ -795,8 +815,6 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
                           jnp.arange(Q, dtype=jnp.int32), Q, seed_u,
                           k=k, alpha=alpha, search_nodes=search_nodes,
                           max_hops=max_hops, state_limbs=state_limbs,
-                          compact_after=compact_after,
-                          compact_cap=compact_cap,
                           block_bounds=(
                               (lambda t0, L: _lut_block_bounds(lut, t0, L))
                               if block_mode == "lut" else None))
@@ -818,10 +836,12 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     deepest lookup's rounds — the rounds run in lockstep inside the
     compiled while_loop and no host probe sees one; where a round's
     time goes is read off a device trace by the stages
-    ``_lookup_engine`` names), and the wave-width / hops
-    distributions.  Shared by the single-device engine and the
-    tp-sharded twin (``mode="tp"``, parallel/sharded.py); both time the
-    whole of this call as ``dht_search_record_seconds``.
+    ``_lookup_engine`` names), the wave-width / hops distributions,
+    and ``dht_search_narrow_rounds``: how many of the wave's rounds ran
+    under its full width (the engine's own count, 0 = it never cut).
+    Shared by the single-device engine and the tp-sharded twin
+    (``mode="tp"``, parallel/sharded.py); both time the whole of this
+    call as ``dht_search_record_seconds``.
 
     ISSUE-4: when an ambient trace context is active the same envelope
     records the wave into the distributed tracer as ONE
@@ -839,8 +859,13 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     reg = telemetry.get_registry()
     reg.histogram("dht_search_wave_seconds", mode=mode).observe(elapsed_s)
     reg.histogram("dht_search_wave_width", mode=mode).observe(wave_width)
-    hops = np.asarray(out["hops"])
+    # ONE fetch for both: the count rides the copy of ``hops``
+    hops, narrow = jax.device_get((out["hops"], out["narrow_rounds"]))
     reg.histogram("dht_search_hops", mode=mode).observe_many(hops)
+    # one value a wave: on a mesh the slowest q-rank's (each cuts when
+    # its own survivors fit)
+    reg.histogram("dht_search_narrow_rounds", mode=mode).observe(
+        int(np.max(narrow)))
     rounds = int(hops.max()) if hops.size else 0
     if rounds > 0:
         reg.histogram("dht_search_round_seconds", mode=mode).observe(
@@ -878,6 +903,14 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
 def simulate_lookups(sorted_ids, n_valid, targets, **kw):
     """Run Q iterative lookups to convergence — the public entry point;
     see :func:`_simulate_lookups_jit` for the full argument contract.
+
+    A wave of ``NARROW_MIN_WAVE`` lookups or more runs its straggler
+    rounds at the width of the lookups still alive: the engine watches
+    the live count and, once the survivors fit an eighth of the wave,
+    packs them and runs them on as a wave of that width
+    (:func:`_lookup_engine`, SURVIVOR COMPACTION).  Nothing to
+    configure, and results are bit-identical to one full-width loop;
+    ``narrow_rounds`` in the result says how many rounds ran narrow.
 
     Telemetry envelope over the compiled engine: three host-side spans
     (``perf_counter`` plus the matching ``jax.profiler.TraceAnnotation``,

@@ -812,8 +812,9 @@ def cascade_topk(sorted_ids, exp_fast, exp_wide, n_valid, queries, lut, *,
     # was_bad=False, so the write is the row's own current value (and
     # the cert update ORs a True with anything).  If a future edit makes
     # per-row scatter values diverge (e.g. mixes in per-slot data), the
-    # duplicates become racy — use a unique fill row or mask first.
-    # (Same invariant as _lookup_engine's compaction in core/search.py.)
+    # duplicates become racy — use a unique fill row or mask first
+    # (as _lookup_engine's pack in core/search.py does: its fill lanes
+    # point past the end and the write-back drops them).
     bad = jnp.nonzero(~cert, size=cap, fill_value=0)[0]
     qb = jnp.take(queries, bad, axis=0)
     # LUT-started bounded positioning for the rescue rows too: the

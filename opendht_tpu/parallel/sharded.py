@@ -430,11 +430,14 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
 
         q_index = (lax.axis_index("q").astype(jnp.int32) * q_local
                    + jnp.arange(q_local, dtype=jnp.int32))
-        return _lookup_engine(gather_planar, lower, n, targets_local,
-                              q_index, q_total, seed.astype(_U32),
-                              k=k, alpha=alpha, search_nodes=search_nodes,
-                              max_hops=max_hops, state_limbs=state_limbs,
-                              block_bounds=block_bounds)
+        out = _lookup_engine(gather_planar, lower, n, targets_local,
+                             q_index, q_total, seed.astype(_U32),
+                             k=k, alpha=alpha, search_nodes=search_nodes,
+                             max_hops=max_hops, state_limbs=state_limbs,
+                             block_bounds=block_bounds)
+        # one count a q-rank (t-ranks hold the same search state and
+        # cut in the same round)
+        return dict(out, narrow_rounds=out["narrow_rounds"][None])
 
     in_specs = ((P("t", None), P("t", None), P(), P(), P("t", None),
                  P("q", None), P()) if weighted else
@@ -443,7 +446,8 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
         local, mesh=mesh,
         in_specs=in_specs,
         out_specs={"nodes": P("q", None), "dist": P("q", None, None),
-                   "hops": P("q"), "converged": P("q")},
+                   "hops": P("q"), "converged": P("q"),
+                   "narrow_rounds": P("q")},
         check_vma=False,
     )
     return jax.jit(fn)

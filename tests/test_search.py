@@ -175,44 +175,112 @@ def test_guarded_lower_bound_exact_incl_tie64_tables():
     check(clus, p2, "clustered")
 
 
-@pytest.mark.slow
-def test_survivor_compaction_bitwise_identical():
-    """compact_after packs post-cut stragglers into a narrow sub-batch;
-    whenever the cap holds, results must be BITWISE identical to the
-    plain engine (reply streams key on global query id + round).  Also
-    exercises the cap-overflow safety net (tiny cap → full-width finish
-    still converges everything)."""
-    import jax
-    import jax.numpy as jnp
-    from opendht_tpu.ops.sorted_table import sort_table
-    from opendht_tpu.core.search import simulate_lookups
+# -- SURVIVOR COMPACTION (core/search.py _lookup_engine): a wave of
+# NARROW_MIN_WAVE lookups or more packs its survivors and runs its last
+# rounds narrow.  The reference is the same wave cut into sub-waves, each
+# with its lookups' global q_index / q_total — ``tp_simulate_lookups`` on
+# a q=4 or q=8, t=1 mesh: a lookup's trajectory does not depend on the
+# wave it rides in.  Four sub-waves of a NARROW_MIN_WAVE-wide wave are
+# UNDER the threshold and run one loop each (narrow_rounds 0).
 
-    k1, k2 = jax.random.split(jax.random.PRNGKey(23))
-    table = jax.random.bits(k1, (8192, 5), dtype=jnp.uint32)
-    targets = jax.random.bits(k2, (256, 5), dtype=jnp.uint32)
-    sorted_ids, _, n = sort_table(table)
-    ref = simulate_lookups(sorted_ids, n, targets, seed=11, state_limbs=2)
-    out = simulate_lookups(sorted_ids, n, targets, seed=11, state_limbs=2,
-                           compact_after=4, compact_cap=256)  # cap == Q
-    for key in ("nodes", "hops", "converged", "dist"):
+OUTPUTS = ("nodes", "dist", "hops", "converged")
+
+
+@pytest.fixture(scope="module")
+def cut_network():
+    """3,000 ids and 8 × NARROW_MIN_WAVE targets: with α=2, k=8 the
+    live share falls 39% → 0.7% → 0.02% → 0.006% → 0 over loop rounds
+    5–9, so a wave cuts after round 6."""
+    import jax
+    from opendht_tpu.core.search import NARROW_MIN_WAVE
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3000))
+    sorted_ids, _, n = sort_table(jax.random.bits(k1, (3000, 5),
+                                                  dtype=jnp.uint32))
+    return sorted_ids, n, jax.random.bits(k2, (8 * NARROW_MIN_WAVE, 5),
+                                          dtype=jnp.uint32)
+
+
+def _in_sub_waves(sorted_ids, n, targets, q=4, **kw):
+    from opendht_tpu.parallel import make_mesh, tp_simulate_lookups
+    return tp_simulate_lookups(make_mesh(q, q=q, t=1), np.asarray(sorted_ids),
+                               n, np.asarray(targets), **kw)
+
+
+def _assert_same_outputs(out, ref):
+    for key in OUTPUTS:
         np.testing.assert_array_equal(np.asarray(out[key]),
-                                      np.asarray(ref[key]))
-    # generous-but-partial cap: by round 4 fewer than half survive
-    out2 = simulate_lookups(sorted_ids, n, targets, seed=11, state_limbs=2,
-                            compact_after=4, compact_cap=192)
-    if bool((np.asarray(ref["hops"]) <= 4).sum() >= 64):
-        for key in ("nodes", "hops", "converged"):
-            np.testing.assert_array_equal(np.asarray(out2[key]),
-                                          np.asarray(ref[key]))
-    # overflow: cap 8 cannot hold the survivors — the full-width safety
-    # net resumes them AT THE CUT ROUND, replaying exactly the streams
-    # the plain engine would have given them, so even overflow is
-    # bitwise identical (and nobody's round budget is starved)
-    out3 = simulate_lookups(sorted_ids, n, targets, seed=11, state_limbs=2,
-                            compact_after=2, compact_cap=8)
-    for key in ("nodes", "hops", "converged", "dist"):
-        np.testing.assert_array_equal(np.asarray(out3[key]),
-                                      np.asarray(ref[key]))
+                                      np.asarray(ref[key]), err_msg=key)
+
+
+@pytest.mark.parametrize("state_limbs", [2, 5])
+def test_wide_wave_cuts_and_equals_its_sub_waves(cut_network, state_limbs):
+    """A wave wide enough to cut equals, bit for bit, the same targets
+    run as sub-waves under the width threshold — in the exact mode too,
+    whose ``dist`` is the carried planes and is written back with the
+    nodes — and says how many rounds it ran narrow."""
+    from opendht_tpu.core.search import NARROW_MIN_WAVE
+    sorted_ids, n, targets = cut_network
+    targets = targets[:NARROW_MIN_WAVE]
+    kw = dict(seed=11, alpha=2, state_limbs=state_limbs)
+    out = simulate_lookups(sorted_ids, n, targets, **kw)
+    assert int(out["narrow_rounds"]) == 1
+    assert np.asarray(out["converged"]).all()
+    assert np.asarray(out["hops"]).max() == 7
+    ref = _in_sub_waves(sorted_ids, n, targets, **kw)
+    assert not np.asarray(ref["narrow_rounds"]).any()   # one loop each
+    _assert_same_outputs(out, ref)
+
+
+@pytest.mark.parametrize("width, max_hops, narrow, unconverged", [
+    # the budget ends AT the cut: survivors are packed, no round is left
+    pytest.param(4096, 6, 0, 28, id="max_hops_at_the_cut"),
+    # ... and before the survivors fit: every lookup is live, an eighth
+    # of them is packed, nothing runs
+    pytest.param(4096, 2, 0, 4096, id="max_hops_before_anyone_fits"),
+    # a wave under the threshold keeps one loop
+    pytest.param(2048, 48, 0, 0, id="under_the_threshold"),
+    # a wave that steps down twice: its 229 survivors fit the second
+    # step (512 lanes) as soon as they are packed into the first (4,096),
+    # which runs no round
+    pytest.param(32768, 48, 3, 0, id="two_steps_down"),
+    # ... and the budget ends inside the narrowest loop
+    pytest.param(32768, 8, 2, 2, id="max_hops_in_a_narrow_loop"),
+])
+def test_cut_edge_cases(cut_network, width, max_hops, narrow, unconverged):
+    sorted_ids, n, targets = cut_network
+    targets = targets[:width]
+    kw = dict(seed=11, alpha=2, state_limbs=2, max_hops=max_hops)
+    out = simulate_lookups(sorted_ids, n, targets, **kw)
+    assert int(out["narrow_rounds"]) == narrow
+    assert int((~np.asarray(out["converged"])).sum()) == unconverged
+    assert np.asarray(out["hops"]).max() <= max_hops
+    # sub-waves of 1,024 or less run one loop; those of the 32,768-wide
+    # wave are 4,096 wide and cut once, as the case above proves they may
+    _assert_same_outputs(out, _in_sub_waves(
+        sorted_ids, n, targets, q=8 if width == 32768 else 4, **kw))
+
+
+@pytest.mark.parametrize("n_ids", [0, 5])
+def test_cut_with_nobody_live(n_ids):
+    """Nobody live at the cut: an empty table (every lookup done before
+    the first round) and a five-id one (every lookup finishes in the
+    same round, so the live count falls from all to none).  The pack
+    finds no survivor, the narrow loop runs no round, the write-back
+    drops every lane."""
+    import jax
+    from opendht_tpu.core.search import NARROW_MIN_WAVE
+    k1, k2 = jax.random.split(jax.random.PRNGKey(5))
+    sorted_ids, _, _ = sort_table(jax.random.bits(k1, (8, 5),
+                                                  dtype=jnp.uint32))
+    targets = jax.random.bits(k2, (NARROW_MIN_WAVE, 5), dtype=jnp.uint32)
+    out = simulate_lookups(sorted_ids, n_ids, targets, seed=3,
+                           state_limbs=2)
+    assert int(out["narrow_rounds"]) == 0
+    hops = np.asarray(out["hops"])
+    assert (hops == hops[0]).all()
+    assert np.asarray(out["converged"]).all() == (n_ids > 0)
+    _assert_same_outputs(out, _in_sub_waves(sorted_ids, n_ids, targets,
+                                            seed=3, state_limbs=2))
 
 
 def test_engine_reply_stream_goldens():

@@ -224,6 +224,54 @@ def test_tp_simulate_mesh_geometries(q, t):
                                       np.asarray(ref[key]))
 
 
+@pytest.mark.parametrize("q,t", [(1, 4), (2, 2)])
+def test_tp_simulate_equals_one_chip_with_the_cut_engaged(q, t):
+    """SURVIVOR COMPACTION on a mesh (core/search.py _lookup_engine):
+    each q-rank's wave is wide enough to cut, so it packs its survivors
+    and runs its last rounds narrow — with the round's one psum at the
+    narrow width — and the results equal one chip's, which cuts too.
+    Every t-rank holds the same search state, so they cut together; a
+    q-rank cuts when ITS survivors fit (one count a rank)."""
+    from opendht_tpu.core.search import NARROW_MIN_WAVE
+    m = make_mesh(4, q=q, t=t)
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3000))
+    sorted_ids, _, n_valid = sort_table(jax.random.bits(
+        k1, (3000, 5), dtype=jnp.uint32))
+    targets = jax.random.bits(k2, (q * NARROW_MIN_WAVE, 5), dtype=jnp.uint32)
+    kw = dict(seed=11, alpha=2, state_limbs=2)
+    ref = simulate_lookups(sorted_ids, n_valid, targets, **kw)
+    out = tp_simulate_lookups(m, np.asarray(sorted_ids), n_valid,
+                              np.asarray(targets), **kw)
+    narrow = np.asarray(out["narrow_rounds"])
+    assert narrow.shape == (q,) and (narrow >= 1).all()
+    assert int(ref["narrow_rounds"]) >= 1
+    if q == 1:
+        assert narrow[0] == int(ref["narrow_rounds"])
+    for key in ("nodes", "dist", "hops", "converged"):
+        np.testing.assert_array_equal(np.asarray(out[key]),
+                                      np.asarray(ref[key]), err_msg=key)
+
+
+def test_dp_simulate_equals_one_chip_with_the_cut_engaged(mesh):
+    """The data-parallel entry runs the same engine under XLA's own
+    partitioning: the live count is global there, so the wave cuts as
+    one — the pack gathers its survivors across the shards — and the
+    results equal one chip's."""
+    from opendht_tpu.core.search import NARROW_MIN_WAVE
+    k1, k2 = jax.random.split(jax.random.PRNGKey(3000))
+    sorted_ids, _, n_valid = sort_table(jax.random.bits(
+        k1, (3000, 5), dtype=jnp.uint32))
+    targets = jax.random.bits(k2, (NARROW_MIN_WAVE, 5), dtype=jnp.uint32)
+    kw = dict(seed=11, alpha=2, state_limbs=2)
+    ref = simulate_lookups(sorted_ids, n_valid, targets, **kw)
+    out = dp_simulate_lookups(mesh, np.asarray(sorted_ids), n_valid,
+                              np.asarray(targets), **kw)
+    assert int(out["narrow_rounds"]) == int(ref["narrow_rounds"]) >= 1
+    for key in ("nodes", "dist", "hops", "converged"):
+        np.testing.assert_array_equal(np.asarray(out[key]),
+                                      np.asarray(ref[key]), err_msg=key)
+
+
 def test_sharded_maintenance_sweep_matches_single_device(mesh):
     """The round-10 maintenance sweep over a row-sharded table must be
     BIT-IDENTICAL to the single-device radix kernel: occupancy psum and
