@@ -182,7 +182,7 @@ def chain_slope(body, example, *consts, r1: int = 2, r2: int = 8,
 #              removes ~2.5 ms of serialized element-gather steps.
 # Round 5 (2-plane expansions — expand_table limbs=2 — cut the row
 # gather 60% and moved the headline 17.86M → 21.6M) re-swept the
-# strides hunting the verdict's ≥25M (benchmarks/exp_headline_r5.py):
+# strides hunting the verdict's ≥25M:
 #   stride 16 (48-window, 64-lane sorts): stage-1 alone 2.9 ms BUT
 #              cert 0.798 at k=16 — 26K misses/batch flood the repair
 #              stage, cascade 32.7 ms.  NEGATIVE.

@@ -21,8 +21,8 @@ CHECKOUT_CACHE_DIR = os.path.join(
 
 def ensure_compile_cache() -> str:
     """Place the compile cache and return the directory in use.  Call
-    before the first compile (``chip_smoke.py``, ``bench.py`` and
-    ``benchmarks/driver_common.py`` do).  A set ``ENV_VAR`` is left
+    before the first compile (``chip_smoke.py`` and ``bench.py`` do).
+    A set ``ENV_VAR`` is left
     alone — jax already honours it; otherwise
     ``jax_compilation_cache_dir`` is pointed at
     :data:`CHECKOUT_CACHE_DIR`."""

@@ -158,10 +158,10 @@ def _lut_block_bounds(lut, t0, prefix_len):
     the near-target fallback window and the trajectory is unchanged
     (measured: hop distribution and convergence identical at 10M).
 
-    This removes the per-round batched binary search that the round-body
-    attribution (benchmarks/exp_round_r5.py) measured at 8.6 of the
-    10.1 ms round — the round-5 engine win.  The sharded twin computes
-    the same values as a psum of per-shard LUT reads (global lower
+    This removes the per-round batched binary search, which was most
+    of a round before it (the LUT reads that replaced it are stage
+    ``block_bounds``, 19% of a wave: PERF.md §5).  The sharded twin
+    computes the same values as a psum of per-shard LUT reads (global lower
     bound = Σ shard-local counts), so tp/single-device bit-identity is
     preserved (tests/test_sharded.py).
     """
@@ -756,9 +756,8 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
     edge (:func:`_lut_block_bounds`) — exact for prefixes up to the LUT
     width, clamped to the containing bucket beyond it; ``"exact"`` =
     the per-round batched binary search (the pre-round-5 model, exact
-    at any depth, measured 85% of the round's wall-clock at 10M —
-    benchmarks/exp_round_r5.py).  On uniform tables at
-    ``default_lut_bits`` the two are statistically indistinguishable
+    at any depth and most of a round's time at 10M).  On uniform
+    tables at ``default_lut_bits`` the two are statistically indistinguishable
     (a clamped bucket with ≥ k rows exists for ~4 of 16.7M buckets at
     N=10M and affects a reply only when a target lands in it past the
     LUT depth); on heavily CLUSTERED tables the clamp widens deep
@@ -828,10 +827,9 @@ def _is_tracer(x) -> bool:
 
 
 def record_wave(out, elapsed_s: float, wave_width: int, *,
-                mode: str = "single", mesh_t: int = 1) -> None:
+                mode: str = "single") -> None:
     """Feed one completed search wave into the telemetry spine
-    (ISSUE-3): ``dht_search_wave_seconds`` (the OPEN ≤8 ms 1024-wave
-    p50 bound is exactly this histogram's p50 at width 1024, PARITY.md),
+    (ISSUE-3): ``dht_search_wave_seconds``,
     ``dht_search_round_seconds`` (a QUOTIENT, not a timing: wave wall /
     deepest lookup's rounds — the rounds run in lockstep inside the
     compiled while_loop and no host probe sees one; where a round's
@@ -850,9 +848,8 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     would otherwise mint a root span per wave into the shared ring and
     evict the flight-recorder events it exists to retain (found by
     review) — to trace a wave, activate a root first (``with
-    tracing.activate(TraceContext.new_root()): simulate_lookups(...)``,
-    the exact recipe PARITY gives for settling the OPEN p95-wave bound
-    on chip).  Host-side only: the traced computation ran BEFORE this
+    tracing.activate(TraceContext.new_root()): simulate_lookups(...)``).
+    Host-side only: the traced computation ran BEFORE this
     call — tracing cannot perturb the kernels (pinned in
     tests/test_tracing.py)."""
     from .. import telemetry, tracing
@@ -887,17 +884,29 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     if tr.enabled and ctx is not None:
         end = time.time()
         start = end - elapsed_s
-        # ISSUE-6: device-cost attribution from the kernel ledger — the
-        # scaled cost-model estimate (bytes/flops) and the achieved HBM
-        # fraction ride the wave span, so a Perfetto load shows which
-        # waves ran memory-bound and how far from peak.  Empty dict (one
-        # cached-flag check) until someone computes the ledger; cost
-        # quantified by captures/ledger_overhead.json.
-        from .. import profiling
-        cost = profiling.wave_attrs(int(wave_width), rounds, elapsed_s,
-                                    mode=mode, mesh_t=mesh_t)
         tr.record("dht.search.wave", start, elapsed_s, parent=ctx,
-                  mode=mode, width=int(wave_width), rounds=rounds, **cost)
+                  mode=mode, width=int(wave_width), rounds=rounds)
+
+
+def _run_wave(launch, wave_width: int, mode: str):
+    """Run one compiled wave (``launch()`` returns its result pytree)
+    under the simulator's host envelope and feed it to
+    :func:`record_wave` — the ONE spelling of the three spans, shared
+    by :func:`simulate_lookups` (``mode="single"``) and
+    ``parallel.sharded.tp_simulate_lookups`` (``mode="tp"``):
+    ``dht_search_wave_seconds`` around dispatch and
+    ``block_until_ready``, inside it ``dht_search_dispatch_seconds``
+    around the jit call until it returns, and after it
+    ``dht_search_record_seconds`` around all of ``record_wave``."""
+    from .. import telemetry
+    reg = telemetry.get_registry()
+    with reg.span("dht_search_wave_seconds", record=False) as sp:
+        with reg.span("dht_search_dispatch_seconds", mode=mode):
+            out = launch()
+        jax.block_until_ready(out)
+    with reg.span("dht_search_record_seconds", mode=mode):
+        record_wave(out, sp.elapsed, wave_width, mode=mode)
+    return out
 
 
 def simulate_lookups(sorted_ids, n_valid, targets, **kw):
@@ -912,32 +921,24 @@ def simulate_lookups(sorted_ids, n_valid, targets, **kw):
     configure, and results are bit-identical to one full-width loop;
     ``narrow_rounds`` in the result says how many rounds ran narrow.
 
-    Telemetry envelope over the compiled engine: three host-side spans
-    (``perf_counter`` plus the matching ``jax.profiler.TraceAnnotation``,
-    so all three lie on a device trace's clock) —
-    ``dht_search_wave_seconds`` around dispatch and ``block_until_ready``,
-    inside it ``dht_search_dispatch_seconds`` around the jit call until
-    it returns, and after it ``dht_search_record_seconds`` around
-    :func:`record_wave` (the ``hops`` fetch and the wave/hops
-    histograms).  With one wave in flight the device idles exactly
-    during the second and the third.  Host-side ONLY — the traced computation is
-    byte-for-byte :func:`_simulate_lookups_jit`, so results are
-    bit-identical with telemetry on or off (pinned in
-    tests/test_telemetry.py).  Under an outer trace (e.g. the bench
-    drivers jit a body that calls this) or with the registry disabled,
-    the envelope vanishes and the call degrades to the bare jit —
-    no blocking, no transfers."""
+    Telemetry envelope over the compiled engine: :func:`_run_wave`'s
+    three host-side spans (``perf_counter`` plus the matching
+    ``jax.profiler.TraceAnnotation``, so all three lie on a device
+    trace's clock).  With one wave in flight the device idles exactly
+    during the dispatch and the record span.  Host-side ONLY — the
+    traced computation is byte-for-byte :func:`_simulate_lookups_jit`,
+    so results are bit-identical with telemetry on or off (pinned in
+    tests/test_telemetry.py).  Under an outer trace (a caller that jits
+    a body which calls this) or with the registry disabled, the
+    envelope vanishes and the call degrades to the bare jit — no
+    blocking, no transfers."""
     from .. import telemetry
     reg = telemetry.get_registry()
     if not reg.enabled or _is_tracer(targets) or _is_tracer(sorted_ids):
         return _simulate_lookups_jit(sorted_ids, n_valid, targets, **kw)
-    with reg.span("dht_search_wave_seconds", record=False) as sp:
-        with reg.span("dht_search_dispatch_seconds", mode="single"):
-            out = _simulate_lookups_jit(sorted_ids, n_valid, targets, **kw)
-        jax.block_until_ready(out)
-    with reg.span("dht_search_record_seconds", mode="single"):
-        record_wave(out, sp.elapsed, targets.shape[0], mode="single")
-    return out
+    return _run_wave(
+        lambda: _simulate_lookups_jit(sorted_ids, n_valid, targets, **kw),
+        targets.shape[0], "single")
 
 
 # ---------------------------------------------------------------------------

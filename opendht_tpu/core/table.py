@@ -459,10 +459,8 @@ class ChurnView:
         contract as :meth:`Snapshot.lookup` (``window`` ignored).
 
         Host-side telemetry (ISSUE-3; the kernel itself is untouched):
-        ``dht_churn_lookup_seconds`` spans the whole device call — the
-        OPEN churny/static ≥0.6× bound (PARITY.md) is this histogram's
-        p50 at an 8192 wave vs ``dht_search_wave_seconds`` on a static
-        table; ``dht_churn_lookups_total{pack=}`` records which merge
+        ``dht_churn_lookup_seconds`` spans the whole device call;
+        ``dht_churn_lookups_total{pack=}`` records which merge
         pack path the backend resolves ("auto" → 128//k on TPU, 1
         elsewhere); tombstone/delta gauges expose the view's churn
         debt."""
@@ -846,7 +844,7 @@ class NodeTable:
         path: no per-row dict bookkeeping, buckets computed on device).
         ``addrs``: optional per-row address (sequence aligned to rows, or
         one address shared by all) so loaded rows are servable in
-        closest-node replies (benchmarks/live_node_scale.py).
+        closest-node replies (tests/test_live_node_scale.py).
         ``buckets``: optional precomputed ``common_bits(self, id)`` per
         row — callers loading many small tables (the converged-cluster
         seeder, testing/virtual_net.py) pass it to skip the per-call
@@ -960,10 +958,9 @@ class NodeTable:
 
     def ids_of_rows(self, rows: np.ndarray) -> list:
         """Vectorized :meth:`id_of` over an int array (-1 → None): ONE
-        ids_to_bytes pass instead of a numpy round-trip per row — the
-        per-row form measured ~2 ms each on a 1-core host, which made
-        materializing a 4096×8 batched-resolve result 66 s
-        (benchmarks/live_node_scale.py)."""
+        ids_to_bytes pass instead of a numpy round-trip per row, which
+        made materializing a 4096×8 batched-resolve result take a
+        minute of host time."""
         rows = np.asarray(rows).reshape(-1)
         raw = IK.ids_to_bytes(self._ids[np.clip(rows, 0, None)])
         return [InfoHash(raw[i].tobytes()) if r >= 0 else None

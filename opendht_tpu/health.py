@@ -1,7 +1,7 @@
 """Cluster health observatory: the declarative SLO engine + node verdict.
 
-Five rounds of observability (round 8 telemetry, round 9 tracing/flight
-recorder, round 11 kernel ledger) export raw signals; nothing
+The rounds of observability before it (round 8 telemetry, round 9
+tracing/flight recorder) export raw signals; nothing
 *interpreted* them — no health model, no SLO evaluation, no readiness
 surface.  This module is the interpretation layer (the measurement half
 of ROADMAP item 4's invariants, standing infrastructure the swarm

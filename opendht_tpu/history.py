@@ -1,7 +1,7 @@
 """Time-series flight data recorder: retained metrics history (round 17).
 
-Six observability surfaces (``/stats``, ``/trace``, ``/healthz``,
-``/keyspace``, ``/cache``, the kernel ledger) are all point-in-time:
+Five observability surfaces (``/stats``, ``/trace``, ``/healthz``,
+``/keyspace``, ``/cache``) are all point-in-time:
 ``dhtmon --window`` fakes a window by scraping twice and waiting, the
 round-14 SLO engine re-derives every burn rate from private
 prior-snapshot state, and when a node goes unhealthy the evidence is
@@ -15,8 +15,7 @@ swarm soaks need.  This module is that retention layer:
   (``deque(maxlen=capacity)``, oldest-evicted) of periodic,
   **delta-encoded** registry frames, ticking on the node scheduler
   exactly like the round-14 health tick (host-side snapshot
-  subtraction only — no device work, kernels bit-identical with the
-  tick on, pinned by benchmarks/exp_history_r17.py).  Per frame:
+  subtraction only — no device work).  Per frame:
   counters as deltas vs the previous tick, histograms as bucket deltas
   (via the round-8 :meth:`telemetry.Histogram.raw` contract), gauges
   as last-value recorded only when they changed.  Series keys use the
@@ -37,7 +36,7 @@ swarm soaks need.  This module is that retention layer:
   (testing/history_smoke.py soak-checks a 10x flood).
 - **Post-mortem black-box bundles**: :func:`build_bundle` assembles
   the last N frames + the round-9 flight-recorder ring (spans AND
-  events) + kernel ledger + keyspace/cache/ingest snapshots + the
+  events) + keyspace/cache/ingest snapshots + the
   health report into ONE JSON artifact.  ``runtime/runner.py`` captures
   one automatically on every ``health_transition`` to unhealthy (the
   evidence survives the incident) and serves fresh ones via
@@ -567,7 +566,6 @@ def build_bundle(*, reason: str = "on_demand", node_id: str = "",
         "listeners": listeners or {},
         "history": {"enabled": False, "frames": []},
         "flight_recorder": {"spans": [], "events": []},
-        "kernels": {},
         "auto_captures": [],
     }
     if history is not None:
@@ -587,12 +585,6 @@ def build_bundle(*, reason: str = "on_demand", node_id: str = "",
             "spans": d.get("spans", [])[-flight_limit:],
             "events": d.get("events", [])[-flight_limit:],
         }
-    except Exception:
-        pass
-    try:
-        from . import profiling
-        if profiling.ledger_computed():
-            bundle["kernels"] = profiling.get_ledger().snapshot()
     except Exception:
         pass
     return bundle

@@ -1,7 +1,7 @@
 """Keyspace traffic observatory: where in the 160-bit ring traffic lands.
 
-Four observability layers (round-8 telemetry, round-9 tracing, round-11
-kernel ledger, round-14 health) say how fast and how healthy the node
+Three observability layers (round-8 telemetry, round-9 tracing,
+round-14 health) say how fast and how healthy the node
 is; nothing said WHERE traffic lands — yet the whole architecture (the
 row-sharded sorted table, the continuous-batching ingest waves) lives
 or dies on keyspace load balance, and Kademlia's original design calls
@@ -45,11 +45,9 @@ REPL command in tools/dhtnode.py, and the ``keyspace`` section of
 The sketch changes NO results anywhere: kernels are bit-identical with
 the observatory on (pinned in tests/test_keyspace.py), accuracy is
 pinned against an exact host-side ``Counter`` oracle (CMS overestimate
-bound + top-K recall >= 0.9 on Zipf(1.1) traffic), the update launch
-is cost-gated in perf_budgets.json (``sketch_update``), and the
-measured on-cost on the 8192-wave round is committed in
-captures/keyspace_overhead.json (<1% acceptance,
-benchmarks/exp_keyspace_r15.py).
+bound + top-K recall >= 0.9 on Zipf(1.1) traffic).  What the update
+launch costs a served window on the chip is not measured yet
+(PERF.md §7).
 
 Import-light by design: this module imports only stdlib + the
 telemetry/tracing spine at module scope; the device side (ops.sketch,

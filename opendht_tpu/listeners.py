@@ -43,9 +43,8 @@ This module is the device half of the fix:
 Surfaces: ``dht_listener_*`` occupancy/match/delivery-latency series
 on ``get_metrics()``/proxy ``GET /stats``/the history ring, a
 ``GET /listeners`` proxy route, the ``listeners`` REPL cmd, the
-scanner section, ``dhtmon --max-listener-lag`` off the windowed
-``dht_listener_lag_p95`` gauge, and the ``listener_match`` cost gate +
-``listener_wave_1m`` OPEN bound in perf_budgets.json.
+scanner section and ``dhtmon --max-listener-lag`` off the windowed
+``dht_listener_lag_p95`` gauge.
 
 Import-light by design (the keyspace.py rule): stdlib + the telemetry
 spine at module scope; the device side (ops.listener_match, and

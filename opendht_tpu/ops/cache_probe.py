@@ -24,8 +24,8 @@ Design mirrors :mod:`opendht_tpu.ops.sketch`:
 The kernel never carries payloads: values live host-side on the
 :class:`~opendht_tpu.hotcache.HotValueCache` keyed by the same canonical
 bytes, so the device answers membership + slot and the host serves the
-payload.  Cost-gated in perf_budgets.json (``cache_probe``) from day
-one; tp twin ``sharded_cache_probe`` in ``parallel/sharded.py``.
+payload.  The tp twin is ``sharded_cache_probe`` in
+``parallel/sharded.py``.
 """
 
 from __future__ import annotations

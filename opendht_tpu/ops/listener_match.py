@@ -30,8 +30,7 @@ The kernel never carries listener records or payloads: per-key listener
 sets (local callbacks, remote ``(node, sid)`` sockets, proxy push
 subscriptions) live host-side on the :class:`~opendht_tpu.runtime.dht.Dht`
 storage, so the device answers membership + slot and the host performs
-one coalesced delivery dispatch per wave per listener.  Cost-gated in
-perf_budgets.json (``listener_match``) from day one; tp twin
+one coalesced delivery dispatch per wave per listener.  The tp twin is
 ``sharded_listener_match`` in ``parallel/sharded.py``.
 """
 
@@ -46,7 +45,7 @@ from .ids import N_LIMBS
 #: default bounded listener table capacity (slots of 20-byte key ids);
 #: the [S, L] compare is one fused reduce — at the canonical wave
 #: S=64 even L=1e6 is a single ~300M-lane elementwise pass, which is
-#: the whole point (the OPEN million-listener bound, perf_budgets.json)
+#: the whole point (no chip has timed it yet: PERF.md §7)
 LISTENER_CAPACITY = 1024
 
 

@@ -717,9 +717,9 @@ def expanded_topk(sorted_ids, expanded, n_valid, queries, *, k: int = 8,
         # caller as a TUPLE of 2-D [Q, k] planes (churn_lookup_topk
         # merges on them without re-gathering ids).  Planes, not a
         # [Q, k, 2] stack: a minor dim of 2 pads to 128 lanes in TPU
-        # tiled layout — the stacked form materialized 64× the bytes
-        # and showed up as ~5 ms of unattributed churn-round cost
-        # (benchmarks/exp_churn2_r5.py).
+        # tiled layout — the stacked form materializes 64× the bytes
+        # (the same pad tax PERF.md §6, PR 27, measured on the
+        # simulator's reply path).
         top_dist = (tuple(top_limbs) if fast2_limbs else None)
         # tie-check operands (same layout as the keyed form below)
         tie_a0, tie_a1 = out[0][:, :k + 1], out[1][:, :k + 1]
@@ -1023,11 +1023,10 @@ def _fallback_tile(n_rows: int, q: int) -> int:
 def _resolve_merge_pack(pack, k: int) -> int:
     """``merge_pack="auto"`` → as many queries per 128-lane physical row
     as k allows (P·k ≤ 128; 16 at the protocol k=8) on TPU, where the
-    minor-dim pad tax the packing amortizes exists — and 1 elsewhere:
-    on cpu the packed merge STAGE measured ~10× the unpacked stage
-    (16.6 ms vs ~1.6 ms over the no-merge variant; −15 ms ≈ −3.5% at
-    the whole-round level — captures/churn_packed.json), the same
-    backend split window_topk's ``select="auto"`` makes.  Any int ≥ 1
+    minor-dim pad tax the packing amortizes exists — and 1 elsewhere
+    (no pad to amortize, only the packing's own reshapes to pay), the
+    same backend split window_topk's ``select="auto"`` makes; packed
+    against unpacked has no time on the chip yet (PERF.md §7).  Any int ≥ 1
     is valid — P=1 is the unpacked merge.  Pure resolution — the
     telemetry lives at the jit boundary (``churn_lookup_topk`` counts
     ``dht_churn_merge_pack_resolved_total{pack=}`` once per trace, so
@@ -1050,10 +1049,7 @@ def packed_churn_merge(m_dist, m_idx, d_dist, d_idx, n_base, *, k: int,
     The merge operands are intrinsically k lanes wide ([Q, k] carried
     distance planes + index planes), and TPU tiled layout pads every
     minor dim to 128 lanes: at the protocol k=8 each elementwise mask /
-    sentinel / sort step moves 16× the useful bytes — measured ~8 ms of
-    the 13.6 ms churny-vs-static gap at 131K queries
-    (benchmarks/exp_churn2_r5.py, the round-5 review's weak point 1).
-    The standard
+    sentinel / sort step moves 16× the useful bytes.  The standard
     lane-occupancy trick from batched serving kernels applies because
     the per-query merges are independent: pack P queries' k-lane planes
     into one [Q/P, P·k] physical row (P·k = 128 exactly at k=8), pay

@@ -220,9 +220,7 @@ class TableState(NamedTuple):
 
     def table_bytes_per_shard(self) -> int:
         """Resident sorted-table bytes on ONE device — the N/t·5·4 B
-        figure the per-shard HBM budget bounds (benchmarks/
-        exp_shard_r13.py; ci/run_ci.sh asserts it on the 8-device
-        mesh)."""
+        figure the per-shard HBM budget bounds."""
         return self.shard_n * self.sorted_ids.shape[1] * 4
 
 

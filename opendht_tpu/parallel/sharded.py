@@ -31,7 +31,8 @@ table-sized is replicated, so the servable id set scales linearly in
 mesh size.  The steady-state search round costs exactly ONE in-loop
 collective — the reply-row merge psum, O(queries·k) bytes — because
 reply-block edges read the replicated global LUT locally instead of
-psumming per-shard edge counts every hop (TP_SCALING.json).
+psumming per-shard edge counts every hop (stage ``owner_merge``, 8% of
+a wave on four chips: PERF.md §5).
 
 Compiled programs are cached per (mesh, k, tile/window, shard size) —
 repeated calls with the same geometry reuse one XLA executable.
@@ -61,7 +62,7 @@ from ..ops.xor_topk import xor_topk, select_topk, mask_invalid
 from ..ops.sorted_table import (sort_table, window_topk, build_prefix_lut,
                                 default_lut_bits, expand_table, expanded_topk,
                                 fused_gather_planar, _EROW)
-from ..core.search import (simulate_lookups, _lookup_engine,
+from ..core.search import (simulate_lookups, _lookup_engine, _run_wave,
                            _guarded_lower_bound, _lut_block_bounds,
                            TARGET_NODES, ALPHA, SEARCH_NODES)
 from ..telemetry import device_stage
@@ -460,8 +461,8 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
                         state: "TableState | None" = None):
     """Iterative lookups with the sorted table ROW-SHARDED over ``t`` —
     the multi-chip north star: tables larger than one chip's HBM are
-    searched iteratively, not just scanned (10M+ ids spread across the
-    mesh, benchmarks/exp_shard_r13.py).
+    searched iteratively, not just scanned (100M ids over a four-chip
+    host is the benchmark cell ``host4-100m.wave-65536``, PERF.md §4).
 
     The table must be GLOBALLY sorted, each ``t``-shard one contiguous
     range of the global order — the Kademlia analog of a node owning
@@ -527,24 +528,12 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
         args = (a["sorted_ids"], a["local_lut"], a["block_lut"],
                 a["n_valid"], targets, jnp.asarray(seed, jnp.int32))
     from .. import telemetry
-    reg = telemetry.get_registry()
-    if not reg.enabled:
+    if not telemetry.get_registry().enabled:
         return fn(*args)
-    # same host-side envelope as the single-device entry (core/search.py
-    # simulate_lookups): the traced computation is untouched, the span
-    # blocks and the wave/hops series land under mode="tp" — and via
-    # record_wave the distributed tracer gets the mode="tp" wave span
-    # too (ISSUE-4), so a sharded lookup shows up in the same
-    # Chrome/Perfetto timeline as the single-device one
-    with reg.span("dht_search_wave_seconds", record=False) as sp:
-        with reg.span("dht_search_dispatch_seconds", mode="tp"):
-            out = fn(*args)
-        jax.block_until_ready(out)
-    from ..core.search import record_wave
-    with reg.span("dht_search_record_seconds", mode="tp"):
-        record_wave(out, sp.elapsed, Q, mode="tp",
-                    mesh_t=mesh.shape["t"])
-    return out
+    # the single-device entry's host-side envelope (core/search.py
+    # _run_wave): the traced computation is untouched, the wave/hops
+    # series and the tracer's wave span land under mode="tp"
+    return _run_wave(lambda: fn(*args), Q, "tp")
 
 
 @functools.lru_cache(maxsize=8)

@@ -47,9 +47,9 @@ sockaddr), fed from the request lifecycle seams in
   reference's ``Node`` liveness rules.
 
 The ledger is pure observation on the send/receive path: it never
-composes packets, so wire bytes stay bit-identical with it enabled
-(pinned by benchmarks/exp_peers_r23.py, which also commits the <1%
-host-overhead paired delta as ``captures/peers_overhead.json``).
+composes packets, so wire bytes stay bit-identical with it enabled,
+and with zero samples the engine's retry schedule is the no-ledger
+one step for step (tests/test_peers.py).
 
 Exports: per-peer gauges ``dht_peer_srtt_seconds{peer=}`` /
 ``dht_peer_rto_seconds{peer=}`` / ``dht_peer_fail_ratio{peer=}``, a

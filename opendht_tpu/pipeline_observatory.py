@@ -28,12 +28,11 @@ Design (Dapper-style causality applied to the Orca-style pipeline):
   closed, and tests pin it against a host-side scalar oracle.
 - **Overlap efficiency**: Σ(per-wave serial spans) over the union wall
   span of the retained timeline.  1.0 means depth-1 serial behaviour;
-  >1.0 is measured fill∥device overlap — the always-on successor to
-  ``captures/pipeline_overlap.json``'s one-shot evidence.
+  >1.0 is measured fill∥device overlap.
 
 Everything here is host-side bookkeeping around the launch/consume
 edges; device kernels are untouched and remain bit-identical with the
-observatory on (pinned by tests and the r21 overhead driver).
+observatory on.
 """
 
 from __future__ import annotations
@@ -77,8 +76,7 @@ class PipelineObservatoryConfig:
     """Tuning for the pipeline utilization observatory.
 
     Defaults keep the plane always-on: the per-edge cost is a few dict
-    ops under a lock (no syscalls, no allocation beyond the ring slot),
-    bounded <1% on the 8192-wave round (``captures/pipeutil_overhead.json``).
+    ops under a lock (no syscalls, no allocation beyond the ring slot).
     """
 
     # Master switch.  Off => every hook is a cheap early return and the

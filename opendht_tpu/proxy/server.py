@@ -464,7 +464,7 @@ def _make_handler(server: DhtProxyServer):
             if parts == ["debug", "bundle"]:
                 # GET /debug/bundle → a fresh post-mortem black-box
                 # bundle (round 17): last-N history frames + flight
-                # ring + kernel ledger + keyspace/cache snapshots in
+                # ring + keyspace/cache snapshots in
                 # one artifact (summaries of the auto-captured bundles
                 # ride along under "auto_captures").  "debug" is not a
                 # valid hash, so the path was previously a 400 and
@@ -475,8 +475,8 @@ def _make_handler(server: DhtProxyServer):
                 # GET /profile → the per-op latency waterfall (round
                 # 19, ISSUE-15): per-stage dht_stage_seconds histograms
                 # with p50/p95/p99 + bucket exemplars, the stage
-                # budgets, the per-op decomposition ring and the live
-                # OPEN-bound comparison; ?fmt=folded serves
+                # budgets and the per-op decomposition ring;
+                # ?fmt=folded serves
                 # flamegraph-shaped folded stacks as text/plain
                 # ("stack weight" lines for flamegraph.pl/speedscope).
                 # "profile" is not a valid hash, so — like /stats —

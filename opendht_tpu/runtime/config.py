@@ -179,10 +179,8 @@ class Config:
     #: ``dht_stage_seconds{stage=}`` histograms (queue_wait /
     #: cache_probe / device_compile / device_launch / scatter_back /
     #: rpc_wait) with exemplar trace ids on the hot buckets, a bounded
-    #: per-op decomposition ring, the degrade-only ``stage_budget``
-    #: health signal, and the live OPEN-bound tracker
-    #: (``dht_open_bound{key=,status=}`` gauges + settling records into
-    #: ``$OPENDHT_TPU_SMOKE_RECORD_DIR``).  Surfaces: ``GET /profile``
+    #: per-op decomposition ring and the degrade-only ``stage_budget``
+    #: health signal.  Surfaces: ``GET /profile``
     #: (+ ``?fmt=folded``), the ``profile`` REPL cmd, the scanner's
     #: ``waterfall`` section and ``dhtmon --max-stage``.
     #: ``waterfall.enabled = False`` stops observation entirely —
@@ -239,8 +237,7 @@ class Config:
     #: and the testing/wiremap_assembler.py cluster wire map.
     #: ``peers.enabled = False`` removes every hook — the request
     #: lifecycle is then byte- and timing-identical to pre-round-23
-    #: builds (the ledger only observes; wire bytes are pinned
-    #: bit-identical either way in benchmarks/exp_peers_r23.py).
+    #: builds (the ledger only observes and never composes a packet).
     peers: PeersConfig = field(default_factory=PeersConfig)
 
     # --- wave-scale listen/push (round 24, opendht_tpu/listeners.py) --

@@ -269,18 +269,6 @@ class DhtRunner:
                     self._on_health_transition
             self._health.attach(dht.scheduler)
 
-        # OPEN-bound tracker (round 19): periodic live comparison of
-        # achieved wave p50 / occupancy / churny-static ratio against
-        # the six open perf_budgets.json bounds, on the same scheduler
-        # (registry reads only — no device work); re-drops the settling
-        # record each tick so a smoke harvest collects fresh evidence
-        self._open_bounds = None
-        wcfg = getattr(dht_config, "waterfall", None)
-        period = getattr(wcfg, "open_bound_period", 0.0) if wcfg else 0.0
-        if period > 0:
-            self._open_bounds = _waterfall.OpenBoundTracker()
-            self._open_bounds.attach(dht.scheduler, period=period)
-
         self.running = True
         if config.threaded:
             self._dht_thread = threading.Thread(
@@ -875,15 +863,6 @@ class DhtRunner:
                     continue
                 for field, v in st.to_dict().items():
                     reg.gauge("dht_routing_" + field, family=fam).set(v)
-        # kernel cost ledger (ISSUE-6): publish dht_kernel_* gauges when
-        # the ledger has been computed (REPL `kernels`, scanner, CI) or
-        # OPENDHT_TPU_LEDGER=1 arms eager compute — a no-op dict check
-        # otherwise, so a bare scrape stays cheap
-        try:
-            from .. import profiling
-            profiling.maybe_export(reg)
-        except Exception:
-            pass
         return reg.snapshot()
 
     def get_health(self) -> dict:
@@ -924,7 +903,7 @@ class DhtRunner:
                     refresh: bool = True) -> dict:
         """Assemble one post-mortem black-box bundle (round 17): the
         last N history frames + the flight-recorder ring (spans AND
-        events) + kernel ledger + keyspace/cache/ingest snapshots +
+        events) + keyspace/cache/ingest snapshots +
         the health report in ONE JSON artifact — the reference's
         ``dumpTables`` instant, retained and machine-readable.  Served
         by proxy ``GET /debug/bundle``, the ``bundle`` REPL cmd and
@@ -1032,16 +1011,13 @@ class DhtRunner:
     def get_profile(self) -> dict:
         """The per-op latency waterfall snapshot (ISSUE-15): per-stage
         ``dht_stage_seconds`` histograms with p50/p95/p99 and bucket
-        exemplars, the stage budgets, the recent per-op decomposition
-        records and the live OPEN-bound comparison — the JSON the
+        exemplars, the stage budgets and the recent per-op
+        decomposition records — the JSON the
         proxy's ``GET /profile`` route serves, the ``profile`` REPL
         command prints, and the scanner's ``waterfall`` section
         embeds."""
         try:
-            doc = _waterfall.get_profiler().snapshot()
-            if self._open_bounds is not None:
-                doc["open_bounds"] = self._open_bounds.snapshot()
-            return doc
+            return _waterfall.get_profiler().snapshot()
         except Exception:
             return {"enabled": False}
 

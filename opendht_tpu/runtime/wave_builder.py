@@ -57,9 +57,7 @@ member):
   gauge, ``dht_ingest_wave_occupancy`` / ``dht_ingest_queue_seconds`` /
   ``dht_ingest_wave_seconds`` histograms, shed/wave/op counters, a
   ``dht.search.wave`` (mode="ingest") trace span per launch with each
-  carried op's ``dht.ingest.op`` span linked to it, and the canonical
-  launch shape cost-gated from day one (profiling.py
-  ``wave_builder_lookup`` ↔ perf_budgets.json).
+  carried op's ``dht.ingest.op`` span linked to it.
 
 Threading: the builder lives on the DHT thread like everything else in
 ``runtime/dht.py`` — submissions come from posted closures, packet
@@ -677,13 +675,6 @@ class WaveBuilder:
         wave_ctx = None
         wave_end = t_avail
         if tr.enabled and any(e.ctx is not None for e in entries):
-            # round 13: device-cost attrs from the ledger's canonical
-            # coalesced-launch entry, with per-device table traffic
-            # scaled by 1/t when the resolve ran row-sharded (empty
-            # dict until the ledger is computed — a dict lookup on the
-            # hot path, same discipline as record_wave's wave_attrs)
-            from .. import profiling
-            cost = profiling.ingest_wave_attrs(len(entries), shard_t)
             # the span covers dispatch → results materialized (for a
             # pipelined wave that includes the in-flight overlap window
             # — the wall truth); pipeline_slot = waves already in
@@ -698,7 +689,7 @@ class WaveBuilder:
                 mode="ingest", occupancy=len(entries), af=af, k=k,
                 table_shard_t=shard_t, pipeline_slot=slot,
                 reshard_gen=(rs.layout.gen if rs is not None
-                             and rs.layout is not None else 0), **cost)
+                             and rs.layout is not None else 0))
         for e, nodes in zip(entries, results):
             if wave_ctx is not None and e.ctx is not None:
                 # span covers submit → scatter, anchored on the entry's

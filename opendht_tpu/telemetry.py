@@ -2,8 +2,8 @@
 
 The repo's perf story used to live in three disconnected islands —
 ``net/engine.py MessageStats``, ``proxy/server.py ServerStats`` and
-``runtime/dht.py get_nodes_stats`` — plus one-off ``benchmarks/exp_*``
-drivers for anything kernel-side.  This module is the shared spine they
+``runtime/dht.py get_nodes_stats`` — plus one-off drivers for anything
+kernel-side.  This module is the shared spine they
 all feed (↔ the reference exposing ``Dht::getNodesStats`` and the proxy
 ``STATS /`` route as a product surface, dht_proxy_server.cpp:206-232):
 
@@ -31,7 +31,8 @@ Everything is cheap enough to leave on by default (one dict lookup +
 a few float ops per event; hot callers cache the metric handles).  Flip
 ``get_registry().enabled = False`` to skip span timing/blocking in
 latency-critical embeddings; recorded kernels and results are identical
-either way (captures/telemetry_overhead.json quantifies the on-cost).
+either way (on the chip the simulator's instrumentation costs 1.2% of
+its rate, all of it ``record_wave``'s own work: PERF.md §6, PR 26).
 
 Import-light by design: stdlib only at module import (the jax profiler
 is looked up lazily inside :meth:`span`), so the scheduler/net layers

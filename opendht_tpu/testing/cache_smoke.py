@@ -25,7 +25,7 @@ asserts the five things the unit tier cannot:
 5. **Listeners are untouched**: a listener on the hot key still
    delivers a post-warm put (listens are never cache-served).
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.cache_smoke
 """

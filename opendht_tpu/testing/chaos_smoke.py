@@ -23,7 +23,7 @@ observability stack was built for:
    lookup-success and replica-coverage invariants degrade during the
    cut and are restored after healing, deterministic under the seed.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.chaos_smoke
 """

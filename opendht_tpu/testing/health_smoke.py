@@ -19,7 +19,7 @@ things the unit tier cannot:
 4. **dhtmon exits non-zero on the violated cluster invariant** (global
    lookup success below threshold).
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.health_smoke
 """

@@ -22,7 +22,7 @@ tier cannot:
 5. **Ring and spill stay bounded under a 10x flood** (RSS- and
    disk-stable; oldest evicted on both).
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.history_smoke
 """

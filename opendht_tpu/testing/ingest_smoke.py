@@ -17,7 +17,7 @@ builder exists for — and asserts the three things the unit tier cannot:
 3. **Backpressure discipline**: nothing was dropped mid-search — the
    shed counter stayed zero for the whole admitted workload.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.ingest_smoke
 """

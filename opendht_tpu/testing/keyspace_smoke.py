@@ -24,7 +24,7 @@ things the unit tier cannot:
    ticks wash out the mixed phase, the same ``dhtmon
    --max-imbalance`` invocation exits 1.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.keyspace_smoke
 """

@@ -21,7 +21,7 @@ cannot:
    path wedged while puts buffer, then released — the delivery arrives
    LATE and the windowed lag p95 crosses the gate).
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.listener_smoke
 """

@@ -30,7 +30,7 @@ per-peer ledger (opendht_tpu/peers.py):
    counter ``dht_net_attempt_timeouts_total{type=}`` ticked at the
    EXPIRED transitions the loss caused.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.peer_smoke
 """

@@ -22,7 +22,7 @@ pipeline and asserts the three things only a live cluster can:
    same values to every listener, and leaves the same per-node
    storage state.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.pipeline_smoke
 """

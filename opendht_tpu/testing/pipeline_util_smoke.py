@@ -25,7 +25,7 @@ cluster can about the utilization observatory:
    impossible floor (0.999) — the same per-node worst / unknown-never-
    violates contract as the other gauge gates.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.pipeline_util_smoke
 """

@@ -22,7 +22,7 @@ threshold, and asserts the closed loop the unit tier cannot:
    is reproduced post-swap, a fresh put lands, and a listener
    registered BEFORE the swap still delivers a post-swap put.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.reshard_smoke
 """

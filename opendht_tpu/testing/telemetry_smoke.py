@@ -7,7 +7,7 @@ proxy's ``GET /stats`` (Prometheus text exposition) — and asserts that
 (2) the counters the exercised paths must advance actually advanced, and
 (3) the two exports describe the same registry.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.telemetry_smoke
 """

@@ -9,8 +9,8 @@ spans.  Spans are deduped by span id, so in-process clusters sharing
 one tracer ring assemble identically to one-ring-per-process
 deployments.
 
-The smoke (``python -m opendht_tpu.testing.trace_assembler``, wired
-into ci/run_ci.sh) boots a real-UDP cluster, runs one traced put+get,
+The smoke (``python -m opendht_tpu.testing.trace_assembler``) boots a
+real-UDP cluster, runs one traced put+get,
 asserts the assembled tree has ≥ 3 contributing nodes with correct
 parentage and monotone timestamps, round-trips the Chrome trace dump
 through ``json.loads`` with the exact ``ph``/``pid``/``tid``/``ts``/

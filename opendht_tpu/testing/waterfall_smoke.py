@@ -9,9 +9,8 @@ traffic, and asserts what the unit tier cannot:
    the scraped ``GET /stats`` exposition, and the network hop stage
    (``rpc_wait``) moves off the real UDP RTTs.
 2. **``GET /profile`` serves the waterfall over the proxy**: the JSON
-   snapshot (stages + budgets + per-op records + live OPEN-bound
-   comparison), the ``?fmt=folded`` flamegraph stacks as text, and a
-   400 on an unknown ``fmt``.
+   snapshot (stages + budgets + per-op records), the ``?fmt=folded``
+   flamegraph stacks as text, and a 400 on an unknown ``fmt``.
 3. **A hot-bucket exemplar resolves through the trace assembler**: a
    trace id stamped on a stage bucket by serving traffic reassembles
    into a span tree via :func:`trace_assembler.assemble_trace` — the
@@ -21,12 +20,8 @@ traffic, and asserts what the unit tier cannot:
    exits 0; after an injected scatter-path stall (sleeping wave
    callbacks inflate the real per-wave scatter-back span — no clock
    mocking), the SAME threshold exits 1.
-5. **The OPEN-bound tracker drops a well-formed settling record**:
-   ``refresh()`` measures live series, every bound reports
-   ``status="unsettled"`` on CPU, and ``write_record`` round-trips
-   through JSON with metric + settle fields per bound.
 
-Run directly (CI does)::
+Run directly::
 
     python -m opendht_tpu.testing.waterfall_smoke
 """
@@ -36,7 +31,6 @@ from __future__ import annotations
 import json
 import socket
 import sys
-import tempfile
 import time
 import urllib.error
 import urllib.request
@@ -46,7 +40,7 @@ from ..infohash import InfoHash
 from ..runtime.config import Config, NodeStatus
 from ..runtime.runner import DhtRunner, RunnerConfig
 from ..tools import dhtmon
-from ..waterfall import OPEN_BOUND_KEYS, STAGES
+from ..waterfall import STAGES
 from . import health_monitor as hm
 from . import trace_assembler as tra
 
@@ -80,7 +74,6 @@ def main(argv=None) -> int:
         for i in range(N_NODES):
             cfg = Config(node_id=InfoHash.get("waterfall-smoke-node-%d" % i))
             cfg.health.period = TICK
-            cfg.waterfall.open_bound_period = TICK
             r = DhtRunner()
             r.run(0, RunnerConfig(dht_config=cfg))
             runners.append(r)
@@ -127,8 +120,6 @@ def main(argv=None) -> int:
         for op in prof["ops"]:
             s = sum(op["stages"].values())
             assert s <= op["end_to_end"] + 1e-6, op
-        ob = prof.get("open_bounds")
-        assert ob and set(ob["bounds"]) == set(OPEN_BOUND_KEYS), ob
         with urllib.request.urlopen(
                 "http://%s/profile?fmt=folded" % ep, timeout=10) as r:
             assert r.headers.get_content_type() == "text/plain"
@@ -193,44 +184,12 @@ def main(argv=None) -> int:
                           "--max-stage", "scatter_back=%g" % gate])
         assert rc == 1, "dhtmon missed the scatter stall (rc=%d)" % rc
 
-        # --- 5: OPEN-bound settling record, live off this traffic
-        tracker = runners[0]._open_bounds
-        assert tracker is not None
-        measured = tracker.refresh()
-        # live serving traffic lights up the op-latency and ingest
-        # bounds; the mode="single"/"tp" wave bounds only measure under
-        # the benchmark drivers and stay at the -1 "no data" sentinel
-        assert measured["cache_flood_p50"]["value"] is not None, measured
-        assert measured["ingest_wave_occupancy"]["value"] is not None, \
-            measured
-        n_live = sum(1 for b in measured.values()
-                     if b["value"] is not None)
-        assert n_live >= 2, measured
-        with tempfile.TemporaryDirectory(prefix="odt-wf-smoke-") as d:
-            path = tracker.write_record(d)
-            assert path, "settling record not written"
-            with open(path) as f:
-                doc = json.load(f)
-        assert doc["name"] == "open_bounds"
-        assert doc["status"] == "unsettled", doc["status"]  # CPU run
-        assert doc["bounds"], doc
-        for k, b in doc["bounds"].items():
-            assert k in OPEN_BOUND_KEYS, k
-            assert b["metric"] and b["settle"], b
-            assert b["status"] == "unsettled", b
-        n_gauges = sum(1 for name in series
-                       if name.startswith("dht_open_bound{"))
-        assert n_gauges == len(OPEN_BOUND_KEYS), \
-            "expected %d open-bound gauges, scraped %d" % (
-                len(OPEN_BOUND_KEYS), n_gauges)
-
         print("waterfall_smoke: OK — stages advanced (device +%d), "
               "/profile json+folded+400, exemplar %s -> %d spans, "
               "dhtmon --max-stage 0 then 1 (gate %.3fs, stalled p95 "
-              "%.3fs), %d/%d bounds measured unsettled"
+              "%.3fs)"
               % (int(dev), tid[:8], trace["spans"], gate,
-                 _scatter_p95(), len(doc["bounds"]),
-                 len(OPEN_BOUND_KEYS)))
+                 _scatter_p95()))
         return 0
     finally:
         if proxy is not None:

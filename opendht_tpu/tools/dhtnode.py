@@ -22,13 +22,6 @@ REPL ops (cmd_loop, dhtnode.cpp:104-460):
     ii <name> <key>        index: lookup
     stats [prom]           unified telemetry (JSON snapshot; 'prom' =
                            Prometheus text, same registry as GET /stats)
-    kernels [measure]      kernel cost ledger: per-kernel XLA cost model
-                           (flops / bytes accessed / HBM footprint) at
-                           the canonical shapes ci/perf_gate.py budgets;
-                           'measure' adds one timed canonical launch per
-                           kernel + roofline attribution vs the platform
-                           peaks.  Exports dht_kernel_* gauges to the
-                           same registry GET /stats serves
     ingest                 continuous-batching ingest state (round 12):
                            queue depth, wave occupancy p50/p95 + mean,
                            time-in-queue p50/p95, waves fired, sheds —
@@ -59,11 +52,11 @@ REPL ops (cmd_loop, dhtnode.cpp:104-460):
     profile [json|folded]  per-op latency waterfall (round 19): per-
                            stage p50/p95/p99 (queue_wait, cache_probe,
                            device_compile/launch, scatter_back,
-                           rpc_wait), the stage budgets and the live
-                           OPEN-bound comparison — the same data the
-                           proxy serves on GET /profile; 'json' dumps
-                           the full snapshot (incl. per-op records +
-                           bucket exemplars), 'folded' prints
+                           rpc_wait) and the stage budgets — the same
+                           data the proxy serves on GET /profile;
+                           'json' dumps the full snapshot (incl.
+                           per-op records + bucket exemplars),
+                           'folded' prints
                            flamegraph-shaped folded stacks
     pipeline [json]        pipeline utilization observatory (round
                            22): windowed device occupancy, per-cause
@@ -102,7 +95,7 @@ REPL ops (cmd_loop, dhtnode.cpp:104-460):
                            substring (e.g. 'dump health')
     bundle [file]          post-mortem black-box bundle (round 17):
                            last-N history frames + flight-recorder
-                           ring + kernel ledger + keyspace/cache
+                           ring + keyspace/cache
                            snapshots + health report in one JSON
                            artifact — the same document the proxy
                            serves on GET /debug/bundle; with a file
@@ -195,40 +188,6 @@ def cmd_loop(node, args) -> None:            # noqa: C901 — REPL dispatch
                     import json as _json
                     print(_json.dumps(node.get_metrics(), indent=2,
                                       sort_keys=True))
-            elif op == "kernels":
-                # kernel cost ledger (ISSUE-6): lowers each shipped
-                # kernel at its canonical shape on first use (seconds),
-                # cached for the process; 'measure' adds a timed launch
-                # + roofline % of platform peak
-                from .. import profiling
-                led = profiling.get_ledger()
-                if rest and rest[0] == "measure":
-                    led.measure()
-                else:
-                    led.compute()
-                led.export_to_registry()
-                entries = led.snapshot()
-                print("%-28s %s" % ("kernel",
-                                    "  MFLOP  MB-accessed  MB-hbm"))
-                for name in sorted(entries):
-                    e = entries[name]
-                    if "error" in e:
-                        print("%-28s ERROR %s" % (name, e["error"]))
-                        continue
-                    line = "%-28s %7.2f %12.2f %7.2f" % (
-                        name, e["flops"] / 1e6,
-                        e["bytes_accessed"] / 1e6, e["hbm_bytes"] / 1e6)
-                    if "live_p50_s" in e:
-                        line += "  live p50 %.3f ms (n=%d)" % (
-                            e["live_p50_s"] * 1e3, e["live_count"])
-                    rl = e.get("roofline")
-                    if rl:
-                        line += "  %.3f ms -> %s-bound, %.1f%% HBM peak" \
-                            % (e["measured_s"] * 1e3, rl["bound"],
-                               rl["hbm_pct_of_peak"])
-                    print(line)
-                print("%d kernels; budgets gated by ci/perf_gate.py "
-                      "(perf_budgets.json)" % len(entries))
             elif op == "ingest":
                 # continuous-batching ingest health (round 12): the
                 # wave builder's snapshot — same numbers dhtscanner
@@ -412,15 +371,6 @@ def cmd_loop(node, args) -> None:            # noqa: C901 — REPL dispatch
                             budgets.get(stage, 0.0) * 1e3))
                     ops = snap.get("ops", [])
                     print("%d per-op record(s) retained" % len(ops))
-                    ob = snap.get("open_bounds")
-                    if ob:
-                        print("open bounds (%s, status %s):" % (
-                            ob["platform"], ob["status"]))
-                        for key_, b in sorted(ob["bounds"].items()):
-                            print("  %-26s %s" % (
-                                key_, "%.3f" % b["value"]
-                                if b["value"] is not None
-                                else "no measurement"))
             elif op == "pipeline":
                 # pipeline utilization observatory (round 22,
                 # ISSUE-18): same snapshot the proxy serves on

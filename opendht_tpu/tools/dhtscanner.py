@@ -61,7 +61,6 @@ def topology_snapshot(node) -> dict:
         "metrics_gauges": {},
         "maintenance": {},
         "ingest": {},
-        "kernels": {},
         "health": {},
         "keyspace": {},
         "cache": {},
@@ -74,8 +73,8 @@ def topology_snapshot(node) -> dict:
         "events": [],
     }
     try:
-        # round-19 latency waterfall: per-stage p50/p95/p99 + budgets +
-        # the live OPEN-bound comparison, so a soak diff shows WHERE an
+        # round-19 latency waterfall: per-stage p50/p95/p99 + budgets,
+        # so a soak diff shows WHERE an
         # op's milliseconds went between snapshots, not just the
         # end-to-end total
         snap["waterfall"] = node.get_profile()
@@ -145,16 +144,6 @@ def topology_snapshot(node) -> dict:
     except Exception:
         pass
     try:
-        # kernel cost ledger (ISSUE-6): report whatever is already
-        # computed — the snapshot must stay cheap enough for every soak
-        # tick, so it never triggers the (seconds-long) lowering itself;
-        # `dhtscanner --kernels` / the REPL `kernels` cmd arm it
-        from .. import profiling
-        if profiling.ledger_computed():
-            snap["kernels"] = profiling.get_ledger().snapshot()
-    except Exception:
-        pass
-    try:
         metrics = node.get_metrics()
         snap["metrics_gauges"] = {
             k: v for k, v in metrics.get("gauges", {}).items()
@@ -214,23 +203,14 @@ def main(argv=None) -> int:
     p.add_argument("--json", action="store_true",
                    help="emit one JSON document (topology snapshot + "
                         "discovered peers) instead of human output")
-    p.add_argument("--kernels", action="store_true",
-                   help="compute the kernel cost ledger (seconds of "
-                        "one-time lowering) so the snapshot's 'kernels' "
-                        "section carries per-kernel flops/bytes/HBM "
-                        "footprint")
     p.add_argument("--bundle", default="", metavar="DIR",
                    help="collect the scanning node's post-mortem "
                         "black-box bundle (round 17: last-N history "
-                        "frames + flight ring + kernel ledger + "
+                        "frames + flight ring + "
                         "keyspace/cache snapshots — the GET "
                         "/debug/bundle artifact) into "
                         "DIR/bundle-<nodeid>.json after the scan")
     args = p.parse_args(argv)
-    if args.kernels:
-        from .. import profiling
-        profiling.get_ledger().compute()
-        profiling.maybe_export()
     node = setup_node(args)
     if not args.json:
         print_node_info(node)

@@ -12,7 +12,8 @@ import jax
 import pytest
 
 import chip_smoke
-from opendht_tpu import compile_cache, profiling
+from dhtbench.run import peaks_for
+from opendht_tpu import compile_cache
 
 SEED = 0
 
@@ -148,15 +149,20 @@ class _Dev:
         self.device_kind, self.platform = kind, platform
 
 
-def test_peaks_unknown_device_kind_raises():
-    for kind in ("TPU v9", "NVIDIA A100", ""):
-        with pytest.raises(KeyError, match="no peaks recorded"):
-            profiling._match_peaks(_Dev(kind))
+def test_peaks_unknown_device_kind_raises(monkeypatch, capsys):
+    """A TPU whose ``device_kind`` the benchmark's table
+    (dhtbench/peaks.json, the repo's one peaks table) does not hold
+    stops ``main()`` before any work."""
+    for kind in ("TPU v9", ""):
+        monkeypatch.setattr(jax, "devices", lambda *a, k=kind: [_Dev(k)])
+        with pytest.raises(KeyError, match="no peaks for device_kind"):
+            chip_smoke.main([])
+    assert capsys.readouterr().out == ""
 
 
 def test_peaks_row_for_the_kind_the_chip_reported():
-    row = profiling._match_peaks(_Dev("TPU v5 lite"))
-    assert row["peak_key"] == "tpu v5 lite"
-    assert row["hbm_bytes_per_s"] == 819e9 and row["flops_per_s"] == 197e12
-    # the CPU row stays, for the tests themselves
-    assert profiling._match_peaks(_Dev("cpu", "cpu"))["peak_key"] == "cpu"
+    row = peaks_for("TPU v5 lite")
+    assert row["hbm_bytes_per_s"] == 819e9
+    assert row["bf16_flop_per_s"] == 197e12
+    with pytest.raises(KeyError):           # no CPU row: a CPU run has
+        peaks_for("cpu")                    # no device metric to divide

@@ -7,9 +7,9 @@ Snapshot.lookup_launch (the round-20 launch/consume seam every resolve,
 sync or pipelined, funnels through) — and this is asserted, not
 assumed: every closest-node resolve during
 the burst is counted through the snapshot/churn view, and the snapshot
-version must match the table's.  benchmarks/live_node_scale.py is the
-full-scale driver (1M rows on the chip); this test runs the same stack
-at 8K rows over real localhost UDP.
+version must match the table's.  ``chip_smoke.phase_served`` is the same
+stack at 1M rows on the chip; this test runs it at 8K rows over real
+localhost UDP.
 """
 
 import secrets

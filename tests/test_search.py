@@ -492,3 +492,40 @@ def test_no_w_alpha_k_tensor_in_the_lowered_round(state_limbs):
     assert "stage_reply_rows" in text                # the round is in there
     assert f"tensor<{alpha * k}x{W}x" in text        # ... slot-major
     assert f"tensor<{W}x{alpha}x{k}x" not in text
+
+
+@pytest.mark.parametrize("mode", ["single", "tp"])
+def test_wave_span_carries_mode_width_rounds_only(mode):
+    """Under an active root context one wave is ONE ``dht.search.wave``
+    span with exactly three attributes — what was timed and counted,
+    no estimate riding along — from the single-device entry and from
+    its tp twin alike (both go through ``core.search._run_wave``)."""
+    from opendht_tpu import tracing
+
+    sorted_ids, n = _network(2048, 21)
+    targets = jnp.asarray(K.ids_from_bytes(np.random.default_rng(22)
+                          .integers(0, 256, (64, 20), dtype=np.uint8)))
+    if mode == "single":
+        def run():
+            return simulate_lookups(sorted_ids, n, targets, seed=5)
+    else:
+        from opendht_tpu.parallel import make_mesh, tp_simulate_lookups
+        mesh = make_mesh(4, q=1, t=4)
+
+        def run():
+            return tp_simulate_lookups(mesh, np.asarray(sorted_ids), n,
+                                       targets, seed=5)
+    tr = tracing.get_tracer()
+    tr.clear()
+    was, tr.enabled = tr.enabled, True
+    try:
+        root = tracing.TraceContext.new_root()
+        with tracing.activate(root):
+            out = run()
+        spans = tr.spans(root.trace_id)
+    finally:
+        tr.enabled = was
+    assert [s["name"] for s in spans] == ["dht.search.wave"]
+    assert spans[0]["attrs"] == {
+        "mode": mode, "width": 64,
+        "rounds": int(np.asarray(out["hops"]).max())}

@@ -1,7 +1,7 @@
 """Per-op latency waterfall (round 19, opendht_tpu/waterfall.py): the
 always-on stage profiler, the per-op sum≈end-to-end decomposition pin,
 exemplar-stamped hot buckets, the degrade-only stage_budget health
-signal, the OPEN-bound tracker, and the dhtmon/REPL/export surfaces."""
+signal, and the dhtmon/REPL/export surfaces."""
 
 from __future__ import annotations
 
@@ -18,9 +18,8 @@ from opendht_tpu.runtime import Config, Dht
 from opendht_tpu.runtime.live_search import SEARCH_NODES
 from opendht_tpu.scheduler import Scheduler
 from opendht_tpu.sockaddr import SockAddr
-from opendht_tpu.waterfall import (DEFAULT_STAGE_BUDGETS, OPEN_BOUND_KEYS,
-                                   STAGE_ALIASES, STAGES, OpenBoundTracker,
-                                   StageProfiler, WaterfallConfig)
+from opendht_tpu.waterfall import (DEFAULT_STAGE_BUDGETS, STAGE_ALIASES,
+                                   STAGES, StageProfiler, WaterfallConfig)
 
 AF = _socket.AF_INET
 
@@ -265,9 +264,7 @@ def test_stage_budget_health_signal_registered_degrade_only():
 
 def test_profiler_publishes_budget_gauges_on_its_registry():
     """The stage budgets export as gauges from construction (and track
-    a reconfigure) on the profiler's OWN registry — NOT via
-    profiling.maybe_export, which must stay a no-op for ledger-off
-    processes (test_maybe_export_is_gated)."""
+    a reconfigure) on the profiler's OWN registry."""
     reg = telemetry.MetricsRegistry()
     p = StageProfiler(reg=reg)
     g = reg.snapshot()["gauges"]
@@ -293,96 +290,6 @@ def test_snapshot_shape_and_quantiles():
     assert rw["count"] == 4
     assert rw["p50"] is not None and rw["p99"] >= rw["p50"]
     assert doc["budgets"]["rpc_wait"] == DEFAULT_STAGE_BUDGETS["rpc_wait"]
-
-
-# ======================================================= OPEN-bound tracker
-def test_open_bound_keys_match_perf_budgets():
-    """The tracker serves exactly the six ``open: true`` entries —
-    a renamed budget entry fails loudly here, not silently."""
-    with open(waterfall._repo_budgets_path()) as fh:
-        doc = json.load(fh)
-    want = {k for k, v in doc["open_bounds"].items() if v.get("open")}
-    assert want == set(OPEN_BOUND_KEYS)
-    t = OpenBoundTracker(reg=telemetry.MetricsRegistry())
-    assert set(t.bounds) == want
-
-
-def test_open_bound_gauges_live_from_boot_with_sentinel():
-    reg = telemetry.MetricsRegistry()
-    t = OpenBoundTracker(reg=reg)
-    assert t.platform == "cpu" and t.status == "unsettled"
-    out = t.refresh()
-    g = reg.snapshot()["gauges"]
-    for key in OPEN_BOUND_KEYS:
-        series = 'dht_open_bound{key="%s",status="unsettled"}' % key
-        assert series in g, sorted(g)
-        assert g[series] == -1.0             # no measurement yet
-        assert out[key]["value"] is None
-
-
-def test_open_bound_measurements_track_live_series():
-    reg = telemetry.MetricsRegistry()
-    t = OpenBoundTracker(reg=reg)
-    for _ in range(8):
-        reg.histogram("dht_search_wave_seconds", mode="single",
-                      wave="1024").observe(0.004)
-        reg.histogram("dht_search_wave_seconds", mode="tp").observe(0.020)
-        reg.histogram("dht_churn_lookup_seconds").observe(0.010)
-        reg.histogram("dht_maintenance_sweep_seconds").observe(0.003)
-        reg.histogram("dht_op_seconds", op="get").observe(0.002)
-    reg.histogram("dht_ingest_wave_occupancy").observe(6.0)
-    reg.histogram("dht_ingest_wave_occupancy").observe(2.0)
-    out = t.refresh()
-    ms = out["wave_p50_ms_1024"]["value"]
-    assert ms is not None and 0.5 <= ms <= 10.0
-    assert out["shard_wave_10m"]["value"] > ms
-    assert out["maintenance_sweep_config4"]["value"] is not None
-    assert out["ingest_wave_occupancy"]["value"] == 4.0
-    assert out["cache_flood_p50"]["value"] is not None
-    ratio = out["churny_static_ratio"]["value"]
-    assert ratio is not None and ratio > 0
-    g = reg.snapshot()["gauges"]
-    assert g['dht_open_bound{key="ingest_wave_occupancy",'
-             'status="unsettled"}'] == 4.0
-
-
-def test_open_bound_settling_record_roundtrip(tmp_path):
-    """A CPU run writes the full settling-record shape with
-    status="unsettled" — the machinery CI exercises long before an
-    accelerator sees it."""
-    reg = telemetry.MetricsRegistry()
-    t = OpenBoundTracker(reg=reg)
-    assert t.write_record(str(tmp_path)) is None   # nothing measured yet
-    reg.histogram("dht_search_wave_seconds", mode="single").observe(0.004)
-    t.refresh()
-    path = t.write_record(str(tmp_path))
-    assert path is not None
-    with open(path) as fh:
-        doc = json.load(fh)
-    assert doc["name"] == "open_bounds"
-    assert doc["platform"] == "cpu" and doc["status"] == "unsettled"
-    assert set(doc["bounds"]) == {"wave_p50_ms_1024"}
-    b = doc["bounds"]["wave_p50_ms_1024"]
-    assert b["status"] == "unsettled" and b["value"] > 0
-    assert b["metric"] and b["settle"]
-
-
-def test_open_bound_tracker_ticks_on_scheduler(tmp_path, monkeypatch):
-    monkeypatch.setenv("OPENDHT_TPU_SMOKE_RECORD_DIR", str(tmp_path))
-    reg = telemetry.MetricsRegistry()
-    clock = {"t": 100.0}
-    sched = Scheduler(clock=lambda: clock["t"])
-    t = OpenBoundTracker(reg=reg)
-    reg.histogram("dht_op_seconds", op="get").observe(0.002)
-    t.attach(sched, period=1.0)
-    clock["t"] += 1.5
-    sched.run()
-    assert (tmp_path / "open_bounds.json").exists()
-    g = reg.snapshot()["gauges"]
-    assert g['dht_open_bound{key="cache_flood_p50",'
-             'status="unsettled"}'] > 0
-    clock["t"] += 1.5                        # the tick reschedules itself
-    sched.run()
 
 
 # ============================================================ dhtmon gate
@@ -430,8 +337,6 @@ def test_scanner_snapshot_has_waterfall_and_chaos_sections():
         wfs = snap["waterfall"]
         assert wfs["enabled"] is True
         assert set(wfs["stages"]) == set(STAGES) | set(STAGE_ALIASES)
-        assert "open_bounds" in wfs
-        assert set(wfs["open_bounds"]["bounds"]) == set(OPEN_BOUND_KEYS)
         chaos = snap["chaos"]
         assert isinstance(chaos, dict)
         assert all(k.startswith("dht_chaos_") for k in chaos)
