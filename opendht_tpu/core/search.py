@@ -72,7 +72,8 @@ from ..ops.ids import N_LIMBS, ID_BITS, ids_to_bytes, clz32
 from ..ops.radix import _PREFIX_MASKS
 from ..ops.sorted_table import (_lex_lt, _lower_bound, _lut_bits,
                                 build_prefix_lut, default_lut_bits,
-                                fused_gather_planar, lut_budget_steps)
+                                fused_gather_planar, loop_gather_view,
+                                lut_budget_steps)
 from ..telemetry import device_stage
 
 _U32 = jnp.uint32
@@ -276,14 +277,20 @@ def _common_bits_planar(a_l, b_l):
     return out
 
 
-def _reply_rows(pt, qidx, x_rows, round_no, lo, ub, *, n, k, q_total,
+def _reply_rows(pt, qidx, x_rows, round_no, lo, ub, *, n, k, R, q_total,
                 seed_u):
     """The reply model proper (stage ``reply_rows``): block edges → the
-    α·k sampled (or fallback-window) rows per search, as a SLOT-MAJOR
-    plane ``[R, W]`` (R = α·k, slot r = a·k + j is sample j of peer a).
+    k sampled (or fallback-window) rows of each queried peer per search,
+    as a SLOT-MAJOR plane ``[P·k, W]`` (slot r = a·k + j is sample j of
+    peer a).
 
     ``pt``, ``qidx`` [W]; ``x_rows``, ``lo``, ``ub`` PEER-MAJOR
-    ``[alpha, W]``.  Every value here keeps the W lookups on the minor
+    ``[P, W]``: the P peers this call answers for — α in a loop round,
+    ONE in the bootstrap round.  ``R`` is the WAVE's α·k whatever P is:
+    it strides the hash counter and spans the fallback window, so slot
+    r of a one-peer call is slot r of the α-peer call whose other peers
+    sent nothing, bit for bit (:func:`_lookup_engine`, BOOTSTRAP
+    SHAPE).  Every value here keeps the W lookups on the minor
     axis: the logical shape ``[W, α, k]`` this used to compute in is
     tiled (4, 128) over its minor dims (3, 8) on the TPU — 21× the
     bytes of the dense array, written once and read twice more per
@@ -295,15 +302,12 @@ def _reply_rows(pt, qidx, x_rows, round_no, lo, ub, *, n, k, q_total,
     The numbers are those of the ``[W, α, k]`` formula, element for
     element (tests/test_search.py renders it in numpy).
     """
-    alpha = x_rows.shape[0]
-    R = alpha * k
-
-    def per_slot(x):                    # [alpha, W] → [R, W]
+    def per_slot(x):                    # [P, W] → [P·k, W]
         return jnp.repeat(x, k, axis=0)
 
-    size = jnp.maximum(ub - lo, 0)                                   # [a,W]
+    size = jnp.maximum(ub - lo, 0)                                   # [P,W]
     # slot = a·k + j, so ((c·α + a)·k + j) == c·(α·k) + slot (mod 2^32)
-    slot = jnp.arange(R, dtype=_U32)[:, None]
+    slot = jnp.arange(x_rows.shape[0] * k, dtype=_U32)[:, None]
     qi = qidx.astype(_U32)[None, :]                # GLOBAL query ids
     ctr = ((round_no.astype(_U32) * _U32(q_total) + qi) * _U32(R)
            + slot) ^ seed_u
@@ -338,13 +342,23 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     ``tp_simulate_lookups`` — each primitive becomes a shard-local
     partial computation + one ``psum`` over the table axis):
 
-      gather_planar(rows [...]) -> 5×[...] uint32 limb planes of the
-          globally-sorted table rows, in ``rows``' own shape and order
-          (the engine hands over peer-major [alpha, W] and slot-major
-          [R, W] indices; the gather is elementwise in its index, so
-          any shape means the same); entries for out-of-range rows
-          (the −1 of an unsent slot) may be garbage — every caller
-          masks them.
+      gather_planar(rows [...], limbs) -> limbs×[...] uint32 limb
+          planes (top limbs first) of the globally-sorted table rows,
+          in ``rows``' own shape and order (the engine hands over
+          peer-major [P, W] and slot-major [P·k, W] indices; the gather
+          is elementwise in its index, so any shape means the same);
+          entries for out-of-range rows (the −1 of an unsent slot) may
+          be garbage — every caller masks them.  The engine calls it
+          inside its ``while_loop`` bodies, so WHOEVER BUILDS THE
+          CLOSURE OWNS THE TABLE VIEW: what it hands
+          ``fused_gather_planar`` for each limb count is decided
+          before the engine is called, once, by
+          ``ops.sorted_table.loop_gather_view`` — the table itself
+          where the ``limbs``-limb view fits on-chip memory (the slice
+          at the gather is then its staging copy, and sets its price),
+          the finished view where it cannot (the slice would be a
+          table-sized copy every round for nothing).  The engine asks
+          for ``limbs`` ∈ {1, ``state_limbs``, 5}.
       lower(flat [M, 5]) -> [M] int32 global lower-bound positions.
       block_bounds(t0, prefix_len) -> (lo, ub) prefix-block edges
           (optional third primitive): t0 = targets' first limb
@@ -366,6 +380,23 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     goldens so any reply-stream drift fails loudly).  In the
     table-sharded twin the same change removes one of the per-round
     psum sites (parallel/sharded.py).
+
+    BOOTSTRAP SHAPE (PR 31): a lookup boots from ONE peer, so the
+    bootstrap round runs at its own static shape — ``boot`` is [1, Q],
+    its replies [k, Q], its merge sorts [Q, S + k] — through the same
+    ``reply_gather`` and ``merge`` as a loop round, which read the
+    number of peers off ``x_rows.shape[0]`` and the number of replies
+    off ``new_rows.shape[0]``.  Run at the loop's shape it issued α·k·Q
+    gather indices, 2·α·Q LUT reads and (on a mesh) an [NL, α·k, Q]
+    psum of which the last α − 1 parts in α were the −1 of peers that
+    do not exist (6.8 ms of a 119 ms wave on one chip at 10M ids, 25 of
+    323 on a 25M-row shard; PERF.md §6, PR 31).  What does NOT follow
+    the shape is the wave's ``R`` = α·k inside the reply model: the
+    hash counter's stride and the fallback window's span
+    (:func:`_reply_rows`).  The columns that went were invalid (−1)
+    and sorted behind the S initial −1 candidates, all with one key, so
+    the state after the bootstrap is the α-wide one's bit for bit
+    (tests/test_search.py keeps the α-wide bootstrap as its reference).
 
     REPLY-PATH LAYOUT (PR 27): from ``select``'s output to ``insert``'s
     concatenate every per-peer value is a PEER-MAJOR plane [alpha, W]
@@ -451,9 +482,10 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             lambda r: gather_planar(r, limbs))(rows)
 
     def reply_gather(tgt, pt, qidx, x_rows, round_no, x_d0=None):
-        """Simulated answers of the α queried nodes per search.
-        x_rows [alpha, W] int32 (−1 = no request) → node rows [R, W]
-        (peer-major in, slot-major out: W stays on the lanes).
+        """Simulated answers of the queried nodes per search.
+        x_rows [P, W] int32 (−1 = no request; P = α in the loop, 1 in
+        the bootstrap) → node rows [P·k, W] (peer-major in, slot-major
+        out: W stays on the lanes).
 
         ``x_d0``: the queried peers' top distance limb ``x0 ^ t0``
         carried from the candidate state (the ROUND-FUSED form — see
@@ -472,7 +504,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             # the sharded engine) disappears: the round's ONLY table
             # gather is the fused [α·k·W] reply gather in merge().
             # block_mode="exact" keeps the full-width gathered path.
-            t0 = tgt[:, 0][None, :]              # [1, W] against [alpha, W]
+            t0 = tgt[:, 0][None, :]              # [1, W] against [P, W]
             if x_d0 is None:
                 x_d0 = fetch_ids(x_rows, 1)[0] ^ t0
 
@@ -484,7 +516,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         else:
             def edges(x_l):
                 t_l = [tgt[:, l][None, :] for l in range(N_LIMBS)]
-                b = _common_bits_planar(x_l, t_l)                    # [a,W]
+                b = _common_bits_planar(x_l, t_l)                    # [P,W]
                 prefix_len = jnp.clip(b + 1, 0, ID_BITS)
                 return _prefix_block_bounds(
                     lower, n,
@@ -494,30 +526,32 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             lo, ub = device_stage("block_bounds")(edges)(
                 fetch_ids(x_rows, N_LIMBS))          # full ids: exact cb
         return device_stage("reply_rows")(functools.partial(
-            _reply_rows, n=n, k=k, q_total=q_total, seed_u=seed_u))(
+            _reply_rows, n=n, k=k, R=R, q_total=q_total, seed_u=seed_u))(
             pt, qidx, x_rows, round_no, lo, ub)
 
     def merge(tgt, cand_node, cand_l, queried, new_rows):
         """Fetch the replies' ids (stage ``fetch_ids``) and insert them
         (stage ``merge``)."""
         return insert(tgt, cand_node, cand_l, queried, new_rows,
-                      fetch_ids(new_rows, NL))                    # NL×[R,W]
+                      fetch_ids(new_rows, NL))                  # NL×[P·k,W]
 
     @device_stage("merge")
     def insert(tgt, cand_node, cand_l, queried, new_rows, new_l):
         """Insert replies, dedupe by node, keep the S closest
         (↔ Search::insertNode, src/search.h:636-722).  ``cand_l`` is the
         candidate distance as NL limb planes [W, S]; ``new_rows`` and
-        ``new_l`` arrive slot-major [R, W].  On the TPU a [W, S] array
+        ``new_l`` arrive slot-major [P·k, W] (R rows from a loop round,
+        k from the bootstrap).  On the TPU a [W, S] array
         is laid out with W on the lanes (physically [S, W]), so the
         ``.T`` of a slot-major plane is a change of name, not a copy,
         and the concatenate joins along the physical major axis."""
         W = tgt.shape[0]
-        node = jnp.concatenate([cand_node, new_rows.T], axis=1)   # [W,S+R]
+        node = jnp.concatenate([cand_node, new_rows.T], axis=1) # [W,S+P·k]
         d_l = [jnp.concatenate([cand_l[l],
                                 (new_l[l] ^ tgt[:, l][None, :]).T],
                                axis=1) for l in range(NL)]
-        qd = jnp.concatenate([queried, jnp.zeros((W, R), jnp.int32)], axis=1)
+        qd = jnp.concatenate(
+            [queried, jnp.zeros((W, new_rows.shape[0]), jnp.int32)], axis=1)
         inv = (node < 0).astype(jnp.int32)
         # new entries beyond the valid table (padded fallback rows for
         # empty/absent requests) already arrive as -1 via reply_gather;
@@ -549,13 +583,13 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         return node_f, d_f, qd_f
 
     # -- bootstrap: cold start from ONE pseudo-random bootstrap peer per
-    # search (like a node boots from a single well-known host) ------------
+    # search (like a node boots from a single well-known host), at the
+    # shape of one peer (BOOTSTRAP SHAPE) ----------------------------------
     empty = n <= 0
-    boot = jnp.full((alpha, Q), -1, jnp.int32).at[0].set(
-        jnp.where(
-            empty, -1,
-            (_mix32(q_index.astype(_U32) ^ seed_u)
-             % jnp.maximum(n, 1).astype(_U32)).astype(jnp.int32)))
+    boot = jnp.where(
+        empty, -1,
+        (_mix32(q_index.astype(_U32) ^ seed_u)
+         % jnp.maximum(n, 1).astype(_U32)).astype(jnp.int32))[None, :]
     cand_node = jnp.full((Q, S), -1, jnp.int32)
     cand_l = [jnp.full((Q, S), 0xFFFFFFFF, _U32) for _ in range(NL)]
     queried = jnp.zeros((Q, S), jnp.int32)
@@ -802,13 +836,21 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
     # bounded in-bucket budget, else full-depth search (lax.cond)
     lower = _guarded_lower_bound(sorted_ids, n, lut)
 
+    # what the engine's gathers read, decided HERE, once: the engine
+    # calls gather_planar inside its while_loop bodies, where a slice of
+    # the table is the gather's staging copy if the view fits on-chip
+    # memory and a table-sized copy every round for nothing if it does
+    # not (_lookup_engine, the gather_planar contract)
+    views = {l: loop_gather_view(sorted_t, l)
+             for l in (1, state_limbs, N_LIMBS)}
+
     def gather_planar(rows, limbs=N_LIMBS):
         """rows [...] int32 → list of `limbs` limb arrays shaped like
         rows (top limbs first — all the merge ranking needs).  ONE
         fused take per call — ops.sorted_table.fused_gather_planar is
         the shared primitive (pinned against the xor_topk.gather_rows
         oracle)."""
-        return fused_gather_planar(sorted_t, rows, limbs)
+        return fused_gather_planar(views[limbs], rows, limbs)
 
     return _lookup_engine(gather_planar, lower, n, targets,
                           jnp.arange(Q, dtype=jnp.int32), Q, seed_u,

@@ -148,7 +148,8 @@ def lut_budget_steps(n_rows: int, bits: int) -> int:
 
 def fused_gather_planar(sorted_t, rows, limbs: int = N_LIMBS):
     """ONE fused multi-row gather: ``limbs`` limb planes of arbitrary-
-    shaped row indices out of the TRANSPOSED [5, N] table.
+    shaped row indices out of the TRANSPOSED [5, N] table, or out of a
+    finished ``[limbs, N]`` view of it.
 
     THE table-access primitive of the iterative search round
     (core/search.py): the round body packs every row it needs — all
@@ -178,6 +179,16 @@ def fused_gather_planar(sorted_t, rows, limbs: int = N_LIMBS):
     what happens to an out-of-range row (``mode="clip"``), so there is
     no negative-index wrap before the gather and no fill after it.
 
+    WHO SLICES, AND WHEN: the gather reads the top ``limbs`` limbs,
+    ``sorted_t[:limbs]`` — a copy of that many limbs of every row when
+    ``sorted_t`` has more (the planes of a [5, N] array share a tile),
+    nothing at all when it has exactly ``limbs``.  A caller that
+    gathers once hands over the whole table (the build's permutation
+    gathers, parallel/global_sort.py).  A caller that gathers inside a
+    ``while_loop`` body asks :func:`loop_gather_view` what to hand
+    over: the copy is the gather's staging into on-chip memory when
+    the view fits there, and dead weight every trip when it does not.
+
     Exact by construction and pinned against the full-materialization
     oracle :func:`~opendht_tpu.ops.xor_topk.gather_rows`
     (tests/test_topk.py).  Out-of-range rows (e.g. the engine's -1
@@ -188,6 +199,46 @@ def fused_gather_planar(sorted_t, rows, limbs: int = N_LIMBS):
     flat = lax.optimization_barrier(rows).reshape(-1)
     g = jnp.take(sorted_t[:limbs], flat, axis=1, mode="clip")   # [limbs, M]
     return [g[l].reshape(rows.shape) for l in range(limbs)]
+
+
+# The largest ``[limbs, N]`` view the TPU compiler stages into on-chip
+# memory for a gather (v5e: 128 MiB of VMEM).  Read off the optimised
+# HLO of ``_simulate_lookups_jit`` compiled for a v5e, where a staged
+# buffer carries memory space ``S(1)``: the loop's 2-limb slice is
+# staged at 14.68M rows (117.4 MB) and below, and is not at 14.7M rows
+# (117.6 MB) and above — 112 MiB, the VMEM less the 16 MiB the compiler
+# keeps back for its kernels (PERF.md §6, PR 31).
+STAGED_VIEW_MAX_BYTES = 112 << 20
+
+
+def loop_gather_view(sorted_t, limbs: int):
+    """What a closure that gathers ``limbs`` limbs INSIDE a
+    ``while_loop`` body hands :func:`fused_gather_planar`: the whole
+    transposed table, or the ``[limbs, N]`` view of it taken here, once
+    — by whether the view fits the chip's fast memory.
+
+    It fits (a 10M-row table's 2-limb view is 80 MB): hand over the
+    table and let ``fused_gather_planar`` slice next to the gather.
+    XLA keeps that slice in the loop body and assigns its result to
+    on-chip memory (``S(1)`` in the optimised HLO), so the slice IS the
+    gather's staging copy — 0.42 ms a round at 10M rows — and the
+    gather reads 1.57M rows out of it at 4.3 ns a row.  Out of HBM the
+    same gather costs 10 ns a row: with the view taken once outside the
+    loop, XLA stages it for the first gather and evicts it before the
+    loop, and a wave took 172 ms where it takes 123 (PERF.md §6,
+    PR 31).
+
+    It cannot fit (a 25M-row shard's is 200 MB): the slice is staged
+    nowhere, the gather pays HBM's price either way (13.6 ns a row),
+    and the slice is 200 MB read and written every trip for nothing —
+    1.43 ms a round, 11.4 ms of a 323 ms wave.  Take the view once.
+
+    The rule reads one static property, the view's bytes; it is the
+    compiler's own (:data:`STAGED_VIEW_MAX_BYTES`), not a knob.
+    """
+    if limbs * sorted_t.shape[1] * 4 <= STAGED_VIEW_MAX_BYTES:
+        return sorted_t
+    return sorted_t[:limbs]
 
 
 def _lex_lt(g, q_l, limbs: int):

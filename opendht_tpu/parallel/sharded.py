@@ -61,7 +61,7 @@ from ..ops.ids import N_LIMBS
 from ..ops.xor_topk import xor_topk, select_topk, mask_invalid
 from ..ops.sorted_table import (sort_table, window_topk, build_prefix_lut,
                                 default_lut_bits, expand_table, expanded_topk,
-                                fused_gather_planar, _EROW)
+                                fused_gather_planar, loop_gather_view, _EROW)
 from ..core.search import (simulate_lookups, _lookup_engine, _run_wave,
                            _guarded_lower_bound, _lut_block_bounds,
                            TARGET_NODES, ALPHA, SEARCH_NODES)
@@ -355,6 +355,16 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     its local count of valid rows with prefix < p; the sum over shards
     is the global count, so the values are bit-identical to the
     per-hop psum they replace).
+
+    The collective's shape is the gather's: ``[NL, α·k, W]`` in a loop
+    round (``[NL, α·k, C]`` once the wave has cut to ``C`` lanes),
+    ``[NL, k, W]`` in the bootstrap round, whose ONE peer answers with k
+    rows (core/search.py ``_lookup_engine``, BOOTSTRAP SHAPE; lut mode's
+    bootstrap also fetches that peer's top limb, a ``[1, 1, W]`` psum),
+    and ``[5, W, k]`` for the final id fetch.  What the gathers read
+    is decided once, before the engine runs
+    (``ops.sorted_table.loop_gather_view``): a shard whose limb view is
+    too large for on-chip memory is not sliced inside a loop body.
     """
     q_local = q_total // mesh.shape["q"]
 
@@ -381,6 +391,13 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
         local_lower = _guarded_lower_bound(sorted_shard, n_local,
                                            local_lut[0])
         sorted_t = sorted_shard.T                        # [5, shard_n]
+        # what the gathers read, decided once: gather_planar runs inside
+        # the engine's while_loop bodies, where a slice of a shard too
+        # large for on-chip memory (25M rows: 200 MB) is copied every
+        # round for nothing (core/search.py _lookup_engine, the
+        # gather_planar contract)
+        views = {l: loop_gather_view(sorted_t, l)
+                 for l in (1, state_limbs, N_LIMBS)}
 
         def lower(flat):
             # global lower bound = Σ_shards (local rows < q): each
@@ -423,7 +440,7 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             # valid rows, and it leaves the uniform program unchanged
             ok = (loc >= 0) & (loc < (n_local if weighted else shard_n))
             g = jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
-                           fused_gather_planar(sorted_t, loc, limbs)])
+                           fused_gather_planar(views[limbs], loc, limbs)])
             # the round's one collective, a device stage of its own
             g = device_stage("owner_merge")(
                 lambda part: lax.psum(part, "t"))(g)

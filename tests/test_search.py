@@ -2,6 +2,8 @@
 found set, determinism, and hop-count parity with the scalar reference
 port (model of the reference's searchStep loop, src/dht.cpp:561-654)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -466,11 +468,19 @@ def test_reply_rows_equal_the_w_alpha_k_formula(alpha, block_mode):
             clz32(x_d0) + 1)
     np.testing.assert_array_equal(np.asarray(lo_j).T, lo)
     np.testing.assert_array_equal(np.asarray(ub_j).T, ub)
+    kw = dict(n=jnp.int32(n), k=k, R=R, q_total=q_total,
+              seed_u=jnp.uint32(seed))
     got = S._reply_rows(jnp.asarray(pt), jnp.asarray(qidx), xj,
-                        jnp.int32(rnd), lo_j, ub_j, n=jnp.int32(n), k=k,
-                        q_total=q_total, seed_u=jnp.uint32(seed))
+                        jnp.int32(rnd), lo_j, ub_j, **kw)
     assert got.shape == (R, W)                       # slot-major plane
     np.testing.assert_array_equal(np.asarray(got).T, want)
+    # BOOTSTRAP SHAPE: a call for ONE peer at the wave's R computes that
+    # peer's k slots and nothing else — the counter's stride and the
+    # fallback window are the wave's, not the call's
+    one = S._reply_rows(jnp.asarray(pt), jnp.asarray(qidx), xj[:1],
+                        jnp.int32(rnd), lo_j[:1], ub_j[:1], **kw)
+    assert one.shape == (k, W)
+    np.testing.assert_array_equal(np.asarray(one), np.asarray(got)[:k])
 
 
 @pytest.mark.parametrize("state_limbs", [2, 5])
@@ -492,6 +502,138 @@ def test_no_w_alpha_k_tensor_in_the_lowered_round(state_limbs):
     assert "stage_reply_rows" in text                # the round is in there
     assert f"tensor<{alpha * k}x{W}x" in text        # ... slot-major
     assert f"tensor<{W}x{alpha}x{k}x" not in text
+
+
+# -- BOOTSTRAP SHAPE and the table view (core/search.py _lookup_engine,
+# PR 31): the bootstrap round runs at the shape of its one peer, and a
+# loop body slices the table only where the slice is the gather's
+# staging copy (ops.sorted_table.loop_gather_view).
+
+def _walk_equations(jaxpr, in_loop=False):
+    """Walk a jaxpr and every jaxpr in its equations' parameters (jit,
+    shard_map, cond branches, loop bodies): yields ``(equation,
+    in_loop)``, ``in_loop`` true under any ``while`` or ``scan``."""
+    for eqn in jaxpr.eqns:
+        yield eqn, in_loop
+        inner = in_loop or eqn.primitive.name in ("while", "scan")
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)     # ClosedJaxpr → Jaxpr
+                if hasattr(sub, "eqns"):
+                    yield from _walk_equations(sub, inner)
+
+
+def _assert_engine_shape(jaxpr, table_rows, W, k, alpha, staged):
+    """Where the table's limb view fits on-chip memory (``staged``) every
+    loop body slices it next to its gather — the slice is the gather's
+    staging copy — and where it cannot, no ``slice`` / ``dynamic_slice``
+    inside a loop body has a table-sized operand
+    (``ops.sorted_table.loop_gather_view``).  Either way no gather
+    outside the loops issues more than k·W indices (the bootstrap's
+    merge, the final id fetch); the loop's is α·k·W."""
+    widest = {True: 0, False: 0}
+    sliced_in_loop = 0
+    for eqn, in_loop in _walk_equations(jaxpr):
+        name = eqn.primitive.name
+        if in_loop and name in ("slice", "dynamic_slice"):
+            sliced_in_loop += table_rows in eqn.invars[0].aval.shape
+        if name == "gather":
+            n_idx = int(np.prod(eqn.invars[1].aval.shape[:-1]))
+            widest[in_loop] = max(widest[in_loop], n_idx)
+    assert (sliced_in_loop > 0) == staged
+    assert widest[True] == alpha * k * W
+    assert widest[False] == k * W
+
+
+# 10M rows: the 2-limb view is 80 MB and is staged; a 25,060,864-row
+# shard's is 200 MB and is not (the two cells); a 5-limb state gathers
+# from the whole table, which no size slices
+ENGINE_SHAPES = [pytest.param(10_000_000, 2, True, id="10M_rows_staged"),
+                 pytest.param(25_060_864, 2, False, id="25M_rows_hoisted"),
+                 pytest.param(25_060_864, 5, False, id="five_limbs")]
+
+
+@pytest.mark.parametrize("rows, state_limbs, staged", ENGINE_SHAPES)
+def test_the_loop_slices_a_view_that_fits_and_the_bootstrap_is_k_wide(
+        rows, state_limbs, staged):
+    """One device: the jaxpr of ``_simulate_lookups_jit`` at the cells'
+    shapes (abstract operands: nothing is allocated)."""
+    import jax
+    from opendht_tpu.core.search import _simulate_lookups_jit
+
+    W, k, alpha = 65536, 8, 3
+    u32, i32 = jnp.uint32, jnp.int32
+    A = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(functools.partial(
+        _simulate_lookups_jit, k=k, alpha=alpha, state_limbs=state_limbs))(
+        A((rows, 5), u32), A((), i32), A((W, 5), u32), seed=A((), i32),
+        lut=A(((1 << 24) + 1,), i32))
+    _assert_engine_shape(jaxpr.jaxpr, rows, W, k, alpha, staged)
+
+
+@pytest.mark.parametrize("rows, state_limbs, staged", ENGINE_SHAPES)
+def test_the_loop_slices_a_view_that_fits_in_the_tp_twin(
+        rows, state_limbs, staged):
+    """Four virtual devices: the same of ``build_tp_lookup``'s ``local``,
+    whose table is the shard (the weighted layout, as
+    ``sharded_global_sort`` returns it)."""
+    import jax
+    from opendht_tpu.parallel import make_mesh
+    from opendht_tpu.parallel.sharded import build_tp_lookup
+
+    W, k, alpha = 65536, 8, 3
+    fn = build_tp_lookup(make_mesh(4, q=1, t=4), rows, W, k, alpha, 14, 48,
+                         state_limbs, True)
+    u32, i32 = jnp.uint32, jnp.int32
+    A = jax.ShapeDtypeStruct
+    jaxpr = jax.make_jaxpr(fn)(
+        A((4 * rows, 5), u32), A((4, (1 << 22) + 1), i32),
+        A(((1 << 24) + 1,), i32), A((), i32), A((4, 2), i32),
+        A((W, 5), u32), A((), i32))
+    _assert_engine_shape(jaxpr.jaxpr, rows, W, k, alpha, staged)
+
+
+@pytest.mark.parametrize("width, alpha", [
+    pytest.param(4096, 2, id="cuts"), pytest.param(1024, 3, id="one_loop")])
+@pytest.mark.parametrize("state_limbs", [2, 5])
+@pytest.mark.parametrize("block_mode", ["lut", "exact"])
+def test_one_peer_bootstrap_equals_the_alpha_wide_one(
+        cut_network, monkeypatch, block_mode, state_limbs, width, alpha):
+    """The bootstrap round at [1, Q] leaves the engine's whole output as
+    the α·k-wide bootstrap left it.  The old form is the reference: the
+    reply model is handed the one peer padded to α rows with the −1 of
+    peers that do not exist — what ``boot`` used to be — so it returns
+    [α·k, Q], the gather fetches α·k·Q rows and the merge sorts
+    [Q, S + α·k], in a fresh trace of the same engine."""
+    import jax
+    from opendht_tpu.core import search as S
+
+    sorted_ids, n, targets = cut_network
+    kw = dict(seed=11, alpha=alpha, state_limbs=state_limbs,
+              block_mode=block_mode)
+    out = S._simulate_lookups_jit(sorted_ids, n, targets[:width], **kw)
+
+    reply_rows, widened = S._reply_rows, []
+
+    def alpha_wide(pt, qidx, x_rows, round_no, lo, ub, **kwargs):
+        if x_rows.shape[0] == 1:                     # the bootstrap's call
+            widened.append(x_rows.shape)
+            pad = ((0, alpha - 1), (0, 0))
+            x_rows = jnp.pad(x_rows, pad, constant_values=-1)
+            lo, ub = jnp.pad(lo, pad), jnp.pad(ub, pad)
+        return reply_rows(pt, qidx, x_rows, round_no, lo, ub, **kwargs)
+
+    monkeypatch.setattr(S, "_reply_rows", alpha_wide)
+    # (a partial is a new function: jit's trace cache cannot answer with
+    # the program traced above)
+    ref = jax.jit(functools.partial(
+        S._simulate_lookups_jit.__wrapped__, **kw))(
+        sorted_ids, n, targets[:width])
+    assert widened == [(1, width)]
+    assert int(out["narrow_rounds"]) == (1 if width == 4096 else 0)
+    assert np.asarray(out["converged"]).all()
+    assert int(ref["narrow_rounds"]) == int(out["narrow_rounds"])
+    _assert_same_outputs(out, ref)
 
 
 @pytest.mark.parametrize("mode", ["single", "tp"])
