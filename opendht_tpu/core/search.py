@@ -24,11 +24,14 @@ measured wave-latency bound that follows).
 
 State layout (fixed shapes; "no candidate" = node index -1):
 
-    cand_node [Q, S]     int32   sorted-table index of each candidate
-    cand_l    5×[Q, S]   uint32  XOR distance limb planes (sort key;
+    cand_node [Q, S]     int32   node of each candidate: its row in the
+                                 sorted table (under churn also
+                                 ``capacity`` + a slot of the delta)
+    cand_l    NL×[Q, S]  uint32  XOR distance limb planes (sort key;
                                  kept planar — see layout note below)
-    queried   [Q, S]     int32   request sent
-    replied   [Q, S]     int32   reply merged
+    queried   [Q, S]     int32   0 = not asked yet, 1 = asked and
+                                 replied, 2 = asked and EXPIRED (the
+                                 node was gone; under churn only)
     hops      [Q]        int32   rounds taken until convergence
     done      [Q]        bool
 
@@ -43,7 +46,21 @@ reply is the k rows straddling t's sorted position — the closest set a
 real peer that close would answer with (model validated against the
 live protocol path at matched N, tests/test_hop_parity.py).  Replies
 are deterministic in (seed, round, search, slot) via a counter-based
-hash, so runs are reproducible and shardable.
+hash, so runs are reproducible and shardable.  On a table that is
+built once a reply is instantaneous and certain, so one flag says
+both "asked" and "replied".
+
+Under membership CHURN (a table that departs, joins and compacts
+between waves: ``ops/churn_table.py``, ``core.table.DeviceChurnTable``;
+OpenDHT's ``NODE_EXPIRE_TIME`` turns a node table over every ten
+minutes) the model is the lossy one that splits them again: a reply
+draws from the table as last compacted, so it may name a node that has
+left; a request to such a node expires (``src/request.h:108-112``), the
+candidate is marked expired, and ``isSynced`` counts the first k
+candidates that are NOT expired (``src/search.h:734-747``), which are
+also what a lookup returns; a node that joined is named at once by the
+peers close to it.  :func:`_lookup_engine` (CHURN) states it in full,
+:func:`scalar_churn_lookup` is its plain reference.
 
 This module is the *simulation* engine (hop-count / convergence
 studies over the synthetic reply model).  The LIVE serving path's
@@ -68,6 +85,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.churn_table import DELTA_WINDOW, ChurnTable, node_gone
 from ..ops.ids import N_LIMBS, ID_BITS, ids_to_bytes, clz32
 from ..ops.radix import _PREFIX_MASKS
 from ..ops.sorted_table import (_lex_lt, _lower_bound, _lut_bits,
@@ -332,7 +350,7 @@ def _reply_rows(pt, qidx, x_rows, round_no, lo, ub, *, n, k, R, q_total,
 def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                    seed_u, *, k, alpha, search_nodes, max_hops,
                    state_limbs: int = N_LIMBS,
-                   block_bounds=None):
+                   block_bounds=None, alive=None, delta_window=None):
     """The iterative-lookup state machine, abstracted over table access.
 
     ALL access to the (possibly distributed) sorted node table flows
@@ -368,6 +386,50 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
           which the round-body attribution measured as 85% of the
           round; when None the engine falls back to the exact search
           via ``lower`` (:func:`_prefix_block_bounds`).
+      alive(nodes [P, W]) -> bool, delta_window(targets [Q, 5]) ->
+          (node [Q, DW], 5 id planes [Q, DW]): the two optional CHURN
+          primitives (below).  With neither the engine lowers to the
+          program it was before they existed, operation for operation
+          (the committed goldens of tests/test_search.py and
+          tests/test_sharded.py), and the tp twin hands it neither.
+
+    CHURN (PR 32): the table is a sorted BASE as last compacted, a
+    liveness bit a node, and a sorted DELTA of the nodes that joined
+    since (``ops/churn_table.py``); a node is a base row or ``capacity``
+    + a delta slot.  The model, which :func:`scalar_churn_lookup`
+    follows step for step:
+
+      1. Replies draw from the base as last compacted (peers' buckets
+         lag): a block sample or a window row may be a node that has
+         left, and is never a node of the delta.
+      2. Stage ``expire``: of the α peers ``select`` chose, those that
+         have left are found by ONE read of [α, W] liveness bits
+         (``alive``), not by anything reply-sized.  Such a peer's
+         request is spent — its k slots of the round send nothing, and
+         the round is a hop if anything was sent — and its candidate
+         gets ``queried`` = 2, expired.  An expired candidate keeps its
+         place in the set (so a later reply that names it again does
+         not make it new), is skipped by ``synced`` — the first k
+         candidates that are not expired have all replied — and is
+         never among the k nodes returned (``first_k_live``).
+      3. Stage ``delta_window``: a node that joined is known to its
+         neighbourhood at once.  Once a wave the targets are positioned
+         in the delta and the DW = 8 delta rows that straddle each are
+         fetched (``delta_window``); a lookup one of whose peers
+         answered with the window around the target — the reply of a
+         peer close to it — merges them with that round's replies, DW
+         more slot-major rows, departed-again ones included (rule 2
+         holds for them).  Far peers do not name a joined node before
+         the next compaction.  The ids of delta nodes among the result
+         come out of the same window, so the final id fetch reads the
+         base only.
+      4. The bootstrap reply is the lookup's own starting knowledge,
+         not a request: it is drawn as base row ``boot`` would answer
+         whether or not that node has left.
+
+    The engine also counts the requests that found their peer gone, a
+    scalar carried through the loops and the survivors' sub-waves, and
+    returns it as ``expired_peers``.
 
     ROUND-FUSED GATHER (round 6): with ``block_bounds`` provided, the
     steady-state round body issues exactly ONE ``gather_planar`` call —
@@ -474,6 +536,11 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     NL = state_limbs
 
     pos_t_full = lower(targets)                        # [Q], fallback replies
+    churn = alive is not None
+    # CHURN: the delta rows around each target, positioned and fetched
+    # once a wave — (node [Q, DW], 5 id planes [Q, DW]), node −1 = none
+    dwin = (None if delta_window is None else
+            device_stage("delta_window")(delta_window)(targets))
 
     def fetch_ids(rows, limbs):
         """Stage ``fetch_ids``: limb planes of table rows — the round's
@@ -525,15 +592,35 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
             lo, ub = device_stage("block_bounds")(edges)(
                 fetch_ids(x_rows, N_LIMBS))          # full ids: exact cb
-        return device_stage("reply_rows")(functools.partial(
+        rows = device_stage("reply_rows")(functools.partial(
             _reply_rows, n=n, k=k, R=R, q_total=q_total, seed_u=seed_u))(
             pt, qidx, x_rows, round_no, lo, ub)
+        if dwin is None:
+            return rows, None
+        # CHURN: a lookup one of whose peers answered with the window
+        # around the target is near it, and hears of who joined there
+        return rows, device_stage("delta_window")(
+            lambda lo, ub, x: jnp.any((ub - lo < k) & (x >= 0), axis=0))(
+            lo, ub, x_rows)
 
-    def merge(tgt, cand_node, cand_l, queried, new_rows):
+    def merge(tgt, cand_node, cand_l, queried, new_rows, dw=None,
+              near=None):
         """Fetch the replies' ids (stage ``fetch_ids``) and insert them
-        (stage ``merge``)."""
-        return insert(tgt, cand_node, cand_l, queried, new_rows,
-                      fetch_ids(new_rows, NL))                  # NL×[P·k,W]
+        (stage ``merge``); under CHURN the delta rows ``dw`` around the
+        target join the replies of a lookup that is ``near`` it (stage
+        ``delta_window``), DW more slot-major rows."""
+        new_l = fetch_ids(new_rows, NL)                         # NL×[P·k,W]
+        if dw is not None:
+            @device_stage("delta_window")
+            def with_delta(new_rows, new_l, dw, near):
+                node, ids = dw
+                return (jnp.concatenate(
+                            [new_rows, jnp.where(near[None, :], node.T, -1)]),
+                        [jnp.concatenate([new_l[l], ids[l].T])
+                         for l in range(NL)])
+
+            new_rows, new_l = with_delta(new_rows, new_l, dw, near)
+        return insert(tgt, cand_node, cand_l, queried, new_rows, new_l)
 
     @device_stage("merge")
     def insert(tgt, cand_node, cand_l, queried, new_rows, new_l):
@@ -593,16 +680,28 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     cand_node = jnp.full((Q, S), -1, jnp.int32)
     cand_l = [jnp.full((Q, S), 0xFFFFFFFF, _U32) for _ in range(NL)]
     queried = jnp.zeros((Q, S), jnp.int32)
-    first = reply_gather(targets, pos_t_full, q_index, boot, jnp.int32(0))
+    first, near = reply_gather(targets, pos_t_full, q_index, boot,
+                               jnp.int32(0))
+    dw_full = None if dwin is None else (dwin[0], dwin[1][:NL])
     cand_node, cand_l, queried = merge(targets, cand_node, cand_l, queried,
-                                       first)
+                                       first, dw_full, near)
 
     @device_stage("converge")
     def synced(cand_node, queried):
         """First min(k, #candidates) candidates all answered
-        (↔ isSynced, search.h:734-747).  Replies are instantaneous in this
-        network model, so 'queried' doubles as 'replied'; a lossy-network
-        model would split the two flags again."""
+        (↔ isSynced, search.h:734-747).  On a table that is built once
+        replies are instantaneous and certain, so ``queried`` > 0 says
+        'replied' too.  Under CHURN ``queried`` has three states — 0 not
+        asked, 1 asked and replied, 2 asked and expired — and the rule
+        is upstream's in full: the first k candidates that are NOT
+        expired have all replied."""
+        if churn:
+            # CHURN: the first k candidates that are NOT EXPIRED
+            # (queried == 2) have all replied (queried == 1)
+            live = (cand_node >= 0) & (queried != 2)
+            first_k = live & (jnp.cumsum(live.astype(jnp.int32), axis=1) <= k)
+            return jnp.all(~first_k | (queried == 1), axis=1) & \
+                jnp.any(live, axis=1)
         present = cand_node[:, :k] >= 0
         return jnp.all(~present | (queried[:, :k] > 0), axis=1) & \
             jnp.any(present, axis=1)
@@ -653,17 +752,34 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         hops = jnp.where(~done & sent, hops + 1, hops)
         return hops, done | now_done | stalled
 
-    def make_body(tgt, pt, qidx):
+    def expire(cand_node, x_rows, queried):
+        """Stage ``expire`` (CHURN): which of the chosen peers are gone
+        — one read of [P, W] liveness bits — and their marking: the
+        request is spent (the peer's slot sends nothing, ``x_rows`` −1),
+        the candidate is expired (``queried`` 2) and counted."""
+        gone = (x_rows >= 0) & ~alive(x_rows)
+        for j in range(x_rows.shape[0]):
+            queried = jnp.where((cand_node == x_rows[j][:, None])
+                                & gone[j][:, None], 2, queried)
+        return (jnp.where(gone, -1, x_rows), queried,
+                jnp.sum(gone, dtype=jnp.int32))
+
+    def make_body(tgt, pt, qidx, dw):
         def body(state):
-            cand_node, cand_l, queried, hops, done, round_no = state
+            cand_node, cand_l, queried, hops, done, round_no, *gone = state
             sel, x_rows, x_d0, queried = select(cand_node, cand_l[0],
                                                 queried, done)
-            new_rows = reply_gather(tgt, pt, qidx, x_rows, round_no + 1,
-                                    x_d0)
+            if churn:
+                x_rows, queried, gone_now = device_stage("expire")(expire)(
+                    cand_node, x_rows, queried)
+                gone = [gone[0] + gone_now]
+            new_rows, near = reply_gather(tgt, pt, qidx, x_rows,
+                                          round_no + 1, x_d0)
             cand_node, cand_l, queried = merge(
-                tgt, cand_node, cand_l, queried, new_rows)
+                tgt, cand_node, cand_l, queried, new_rows, dw, near)
             hops, done = converge(cand_node, queried, sel, hops, done)
-            return cand_node, cand_l, queried, hops, done, round_no + 1
+            return (cand_node, cand_l, queried, hops, done, round_no + 1,
+                    *gone)
         return body
 
     def live_over(cap):
@@ -673,15 +789,32 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             return (jnp.sum(~done) > cap) & (round_no < max_hops)
         return cond
 
+    def first_k_live(cand_node, cand_l, queried):
+        """CHURN: the first k candidates that are not expired — an
+        expired node is never among those returned — by k masked
+        reductions, as ``select`` picks its α."""
+        live = (cand_node >= 0) & (queried != 2)
+        rank = jnp.cumsum(live.astype(jnp.int32), axis=1)
+        pick = [live & (rank == j + 1) for j in range(k)]
+        return (jnp.stack([jnp.max(jnp.where(p, cand_node, -1), axis=1)
+                           for p in pick], axis=1),
+                [jnp.stack([jnp.min(jnp.where(p, cl, _U32(0xFFFFFFFF)),
+                                    axis=1) for p in pick], axis=1)
+                 for cl in cand_l])
+
     def head(cand_node, cand_l, queried):
         """What the outputs read of a search state: the first k
         candidates, their carried distance planes (exact mode only; the
         2-limb mode fetches ids instead) and the converged flags."""
+        if churn:
+            return (*device_stage("converge")(first_k_live)(
+                cand_node, cand_l if NL == N_LIMBS else [], queried),
+                synced(cand_node, queried))
         return (cand_node[:, :k],
                 [cl[:, :k] for cl in cand_l] if NL == N_LIMBS else [],
                 synced(cand_node, queried))
 
-    def run(width, tgt, pt, qidx, state):
+    def run(width, tgt, pt, qidx, dw, state):
         """Run the ``width`` lookups of ``state`` to the end (SURVIVOR
         COMPACTION): the loop at this width until the live ones fit
         ``C`` lanes, then — packed — as a wave of ``C`` lookups, to
@@ -691,11 +824,12 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         round this width's loop ended in and the round the last one
         did."""
         C = width // NARROW_DIVISOR if width >= NARROW_MIN_WAVE else 0
-        cand_node, cand_l, queried, hops, done, round_no = lax.while_loop(
-            live_over(C), make_body(tgt, pt, qidx), state)
+        (cand_node, cand_l, queried, hops, done, round_no,
+         *gone) = lax.while_loop(
+            live_over(C), make_body(tgt, pt, qidx, dw), state)
         outs = (*head(cand_node, cand_l, queried), hops)
         if not C:
-            return outs, round_no, round_no
+            return outs, round_no, round_no, gone
 
         @device_stage("pack")
         def pack(done, wide):
@@ -714,16 +848,17 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             return jax.tree.map(
                 lambda w, n: w.at[rows].set(n, mode="drop"), wide, narrow)
 
-        rows, filled, (tgt, pt, qidx, *sub) = pack(
-            done, (tgt, pt, qidx, cand_node, cand_l, queried, hops))
-        sub_outs, _, last_round = run(C, tgt, pt, qidx,
-                                      (*sub, filled, round_no))
-        return unpack(rows, outs, sub_outs), round_no, last_round
+        rows, filled, (tgt, pt, qidx, dw, *sub) = pack(
+            done, (tgt, pt, qidx, dw, cand_node, cand_l, queried, hops))
+        sub_outs, _, last_round, gone = run(
+            C, tgt, pt, qidx, dw, (*sub, filled, round_no, *gone))
+        return unpack(rows, outs, sub_outs), round_no, last_round, gone
 
-    (nodes_k, dist_k, converged, hops), cut_round, last_round = run(
-        Q, targets, pos_t_full, q_index,
+    (nodes_k, dist_k, converged, hops), cut_round, last_round, gone = run(
+        Q, targets, pos_t_full, q_index, dw_full,
         (cand_node, cand_l, queried, jnp.zeros((Q,), jnp.int32),
-         synced(cand_node, queried) | empty, jnp.int32(0)))
+         synced(cand_node, queried) | empty, jnp.int32(0),
+         *([jnp.int32(0)] if churn else [])))
 
     if NL == N_LIMBS:
         dist = jnp.stack(dist_k, axis=-1)
@@ -735,17 +870,32 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         # measured 1.75 ms on each of four table shards against 8.90
         # slot-major (one chip: 7.60 against 6.81; PERF.md §6, PR 27)
         id_l = fetch_ids(nodes_k, N_LIMBS)
+        if dwin is not None:
+            # CHURN: a delta node's id is in the window it came from
+            @device_stage("delta_window")
+            def delta_ids(id_l, nodes_k, dwin):
+                node, ids = dwin
+                for j in range(node.shape[1]):
+                    hit = (nodes_k == node[:, j:j + 1]) & (nodes_k >= 0)
+                    id_l = [jnp.where(hit, ids[l][:, j:j + 1], id_l[l])
+                            for l in range(N_LIMBS)]
+                return id_l
+
+            id_l = delta_ids(id_l, nodes_k, dwin)
         dist = jnp.stack(
             [jnp.where(nodes_k >= 0, id_l[l] ^ targets[:, l:l + 1],
                        jnp.uint32(0xFFFFFFFF)) for l in range(N_LIMBS)],
             axis=-1)
-    return {
+    out = {
         "nodes": nodes_k,
         "dist": dist,
         "hops": hops,
         "converged": converged & ~empty,
         "narrow_rounds": last_round - cut_round,
     }
+    if churn:
+        out["expired_peers"] = gone[0]
+    return out
 
 
 @functools.partial(
@@ -763,7 +913,15 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
 
     Args:
       sorted_ids: uint32 [N, 5], lexicographically sorted network ids
-                  (node identity == sorted row index).
+                  (node identity == sorted row index) — or a
+                  ``ops.churn_table.ChurnTable`` (``DeviceChurnTable
+                  .view``), the table under membership churn: the
+                  engine then runs its CHURN model over the table's
+                  base, liveness words and delta, ``n_valid`` and
+                  ``lut`` are the table's own (pass None), and the
+                  result gains ``expired_peers`` (int32: the wave's
+                  requests to nodes that were gone).  Nothing selects
+                  this: it is read off what is handed over.
       n_valid:    number of real rows in sorted_ids.
       targets:    uint32 [Q, 5] lookup keys.
 
@@ -803,6 +961,16 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
     if block_mode not in ("lut", "exact"):
         raise ValueError(f"block_mode must be 'lut' or 'exact', "
                          f"got {block_mode!r}")
+    churn = {}
+    if isinstance(sorted_ids, ChurnTable):
+        # CHURN: the engine reads it off what it is handed — the base
+        # is the table, with its own row count and LUT, and the
+        # liveness words and the delta become the two churn primitives
+        tbl = sorted_ids
+        if n_valid is not None or lut is not None:
+            raise ValueError("a ChurnTable brings its row count and LUT")
+        sorted_ids, n_valid, lut = tbl.base, tbl.n_base, tbl.lut
+        churn = _churn_primitives(tbl)
     N = sorted_ids.shape[0]
     Q = targets.shape[0]
     n = jnp.asarray(n_valid, jnp.int32)
@@ -858,7 +1026,37 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
                           max_hops=max_hops, state_limbs=state_limbs,
                           block_bounds=(
                               (lambda t0, L: _lut_block_bounds(lut, t0, L))
-                              if block_mode == "lut" else None))
+                              if block_mode == "lut" else None), **churn)
+
+
+def _churn_primitives(tbl: ChurnTable) -> dict:
+    """The engine's two CHURN primitives over a device-resident
+    :class:`~opendht_tpu.ops.churn_table.ChurnTable`.
+
+    ``alive(nodes)``: one gather of liveness words (a node is a base
+    row, or ``capacity`` + a delta slot; both ranges in one array).
+    ``delta_window(targets)``: the ``DELTA_WINDOW`` delta rows that
+    straddle each target's place in the sorted delta — positioned by
+    the delta's own LUT behind the same soundness guard as the base —
+    as ``(node [Q, DW], 5 id planes [Q, DW])``, node −1 past either end
+    of the delta.  Fetched slot-major (the Q lookups on the lanes) and
+    handed over as the ``.T`` of that."""
+    C, D = tbl.capacity, tbl.delta_capacity
+
+    def alive(nodes):
+        return ~node_gone(tbl.tomb_bits, jnp.clip(nodes, 0, C + D - 1))
+
+    lower_d = _guarded_lower_bound(tbl.delta, tbl.n_delta, tbl.delta_lut)
+    delta_t = tbl.delta.T
+
+    def delta_window(targets):
+        slot = (lower_d(targets)[None, :] - DELTA_WINDOW // 2
+                + jnp.arange(DELTA_WINDOW, dtype=jnp.int32)[:, None])
+        ids = fused_gather_planar(delta_t, jnp.clip(slot, 0, D - 1))
+        node = jnp.where((slot >= 0) & (slot < tbl.n_delta), C + slot, -1)
+        return node.T, [plane.T for plane in ids]
+
+    return {"alive": alive, "delta_window": delta_window}
 
 
 def _is_tracer(x) -> bool:
@@ -898,8 +1096,14 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     reg = telemetry.get_registry()
     reg.histogram("dht_search_wave_seconds", mode=mode).observe(elapsed_s)
     reg.histogram("dht_search_wave_width", mode=mode).observe(wave_width)
-    # ONE fetch for both: the count rides the copy of ``hops``
-    hops, narrow = jax.device_get((out["hops"], out["narrow_rounds"]))
+    # ONE fetch for all: the counts ride the copy of ``hops``
+    hops, narrow, expired = jax.device_get(
+        (out["hops"], out["narrow_rounds"], out.get("expired_peers")))
+    if expired is not None:
+        # CHURN: the wave's queried peers that were gone (the engine's
+        # own count; on a frozen table the series does not exist)
+        reg.histogram("dht_search_expired_peers", mode=mode).observe(
+            int(np.sum(expired)))
     reg.histogram("dht_search_hops", mode=mode).observe_many(hops)
     # one value a wave: on a mesh the slowest q-rank's (each cuts when
     # its own survivors fit)
@@ -1063,3 +1267,130 @@ def scalar_lookup(sorted_ids_np: np.ndarray, n: int, target_np: np.ndarray,
                 insert(r)
     ordered = sorted(cands.values())[:k]
     return [c[1] for c in ordered], hops, False
+
+
+def scalar_churn_lookup(base_np: np.ndarray, n: int, target_np: np.ndarray,
+                        *, departed=frozenset(), joined=None,
+                        joined_departed=frozenset(), node_base=None,
+                        seed: int = 0, k: int = TARGET_NODES,
+                        alpha: int = ALPHA,
+                        search_nodes: int = SEARCH_NODES, max_hops: int = 48,
+                        delta_window: int = DELTA_WINDOW, rng=None):
+    """:func:`scalar_lookup` over a network whose membership changes —
+    the plain reference of the engine's CHURN model, sequential Python,
+    one lookup at a time, independent of the engine.
+
+    The network: ``base_np`` [≥n, 5], the sorted table as last
+    compacted (node = row), of which the rows in ``departed`` have left
+    since; ``joined`` [J, 5], sorted, the ids that joined since (node =
+    ``node_base`` + its place there, ``node_base`` defaulting to ``n``),
+    of which the places in ``joined_departed`` have left again.
+
+    The model, beside :func:`scalar_lookup`'s: (1) replies draw from the
+    base as last compacted, so they may name a departed node; (2) a
+    queried node that has departed answers nothing — it is marked
+    expired, its request is spent (the round is a hop if anything was
+    sent) and its slot of the fallback window with it; (3) a lookup is
+    synced when the first k candidates that are NOT expired have all
+    replied, and returns those; (4) a peer that answers with the window
+    around the target (its block held fewer than k rows) also names the
+    ``delta_window`` joined nodes that straddle the target's place
+    among the joined ids, departed-again ones included — a node that
+    joined is known to its neighbourhood at once, and to nobody else
+    before the next compaction; (5) the bootstrap reply is the
+    lookup's own starting knowledge, not a request: it never expires.
+    The peer in place ``a`` of a round's α answers a window reply with
+    slice ``a`` (as the engine's slot ``a·k + j`` does), so where every
+    reply is a window the model is deterministic and the two agree
+    lookup for lookup; block samples are random here and hashed there.
+
+    Returns ``(nodes, hops, converged, expired)``: expired = the
+    requests this lookup sent to nodes that were gone."""
+    if rng is None:
+        rng = np.random.default_rng(seed)
+    if node_base is None:
+        node_base = n
+    joined = np.zeros((0, N_LIMBS), np.uint32) if joined is None else joined
+
+    def as_ints(rows) -> list:
+        """[m, 5] uint32 limbs, most significant first -> m Python ints."""
+        rows = np.asarray(rows, dtype=np.uint32).astype(object)
+        return [int(v) for v in sum(rows[:, l] << (32 * (N_LIMBS - 1 - l))
+                                    for l in range(N_LIMBS))] if len(rows) \
+            else []
+
+    base_int = as_ints(base_np[:n])
+    joined_int = as_ints(joined)
+    t_int = as_ints(target_np[None])[0]
+
+    def node_int(node: int) -> int:
+        return (joined_int[node - node_base] if node >= node_base
+                else base_int[node])
+
+    def gone(node: int) -> bool:
+        return (node - node_base in joined_departed if node >= node_base
+                else node in departed)
+
+    def lower_bound(keys: list, v: int) -> int:
+        lo, hi = 0, len(keys)
+        while lo < hi:
+            mid = (lo + hi) // 2
+            if keys[mid] < v:
+                lo = mid + 1
+            else:
+                hi = mid
+        return lo
+
+    pos_t = lower_bound(base_int, t_int)
+    pos_d = lower_bound(joined_int, t_int)
+    window_d = [node_base + j for j in range(pos_d - delta_window // 2,
+                                             pos_d + delta_window // 2)
+                if 0 <= j < len(joined_int)]
+
+    def reply(x: int, place: int):
+        """(nodes named, whether it was the window around the target)."""
+        x_int = node_int(x)
+        cb = 160 - (x_int ^ t_int).bit_length() if x_int != t_int else 160
+        plen = min(cb + 1, 160)
+        mask = ((1 << plen) - 1) << (160 - plen) if plen else 0
+        p_lo = t_int & mask
+        lo = lower_bound(base_int, p_lo)
+        ub = lower_bound(base_int, (p_lo | ((1 << (160 - plen)) - 1)) + 1)
+        if ub - lo >= k:
+            return [lo + int(v) for v in rng.integers(0, ub - lo, k)], False
+        R = alpha * k
+        first = min(max(pos_t - R // 2, 0), max(n - R, 0))
+        return [min(first + place * k + jj, n - 1) for jj in range(k)], True
+
+    cands: dict = {}        # node -> [dist, node, 0 new | 1 replied | 2 expired]
+
+    def hear(nodes, near: bool):
+        for node in list(nodes) + (window_d if near else []):
+            if node not in cands:
+                cands[node] = [node_int(node) ^ t_int, node, 0]
+
+    hear(*reply(int(rng.integers(0, n)), 0))
+
+    hops = expired = 0
+    while True:
+        ordered = sorted(cands.values())[:search_nodes]
+        cands = {c[1]: c for c in ordered}
+        first_k = [c for c in ordered if c[2] != 2][:k]
+        found = [c[1] for c in first_k]
+        if first_k and all(c[2] == 1 for c in first_k):
+            return found, hops, True, expired
+        to_query = [c for c in ordered if c[2] == 0][:alpha]
+        if not to_query or hops >= max_hops:
+            return found, hops, False, expired
+        hops += 1
+        heard, near = [], False
+        for place, c in enumerate(to_query):
+            if gone(c[1]):
+                c[2] = 2
+                expired += 1
+                continue
+            c[2] = 1
+            nodes, window = reply(c[1], place)
+            heard += nodes
+            near |= window
+        hear(heard, near)

@@ -39,8 +39,11 @@ from .. import telemetry
 from ..infohash import InfoHash
 from ..ops import ids as IK
 from ..ops import radix
+from ..ops.churn_table import (ChurnTable, churn_apply, churn_compact,
+                               churn_table, stale_limit, tomb_words)
 from ..ops.sorted_table import (_resolve_merge_pack, sort_table, lookup_topk,
-                                expand_table, churn_lookup_topk)
+                                expand_table, churn_lookup_topk,
+                                default_lut_bits)
 
 # liveness windows (reference include/opendht/node.h:148-158)
 NODE_GOOD_TIME = 120 * 60.0       # replied within 2 h → good
@@ -55,6 +58,7 @@ DELTA_CAP = 4096                  # churn side-slab capacity (inserts
                                   # absorbed without re-sorting)
 TOMB_MIN = 1024                   # compact when tombstones exceed
 TOMB_FRAC = 16                    # max(TOMB_MIN, n_base // TOMB_FRAC)
+MAX_STALE_SHARE = 1 / TOMB_FRAC   # the same rule as a share of the base
 
 # compactions are a first-class perf signal (every full re-sort+re-expand
 # stalls behind a device sort): counted per-process alongside each
@@ -382,7 +386,7 @@ class ChurnView:
         self.inv_perm = np.full(cap_rows, -1, dtype=np.int64)
         pos = np.nonzero(perm >= 0)[0]
         self.inv_perm[perm[pos]] = pos
-        self.tomb_np = np.zeros((n + 31) // 32, dtype=np.uint32)
+        self.tomb_np = np.zeros(tomb_words(n), dtype=np.uint32)
         self.tomb_count = 0
         self.delta_ids_np = np.zeros((delta_cap, IK.N_LIMBS), dtype=np.uint32)
         self.delta_rows = np.full(delta_cap, -1, dtype=np.int64)
@@ -523,6 +527,111 @@ class ChurnView:
             return rows.astype(np.int32), np.asarray(dist)
 
         return PendingLookup(finalize, probe=enc)
+
+
+class DeviceChurnTable:
+    """A sorted id table that departs, joins and compacts ON THE DEVICE
+    — the lookup simulator's table under membership churn
+    (``simulate_lookups(table.view, None, targets, ...)``), where
+    :class:`ChurnView` is the served node's.
+
+    ``ChurnView`` keeps its tombstones and its delta in numpy and takes
+    one Python call a mutation; a simulated network of 10M nodes turns
+    over 33,334 members a second (OpenDHT ``NODE_EXPIRE_TIME``: N/600
+    departures and as many arrivals), which cannot go that way.  Here a
+    tick is one device program over a batch (``ops.churn_table
+    .churn_apply``) and a compaction another (``churn_compact``); the
+    host holds five integers.  Shared with the churn view: the liveness
+    word layout (``ops.sorted_table.unpack_tomb_bits``) and the rule for
+    when departed rows force a compaction (``stale_limit``,
+    :data:`MAX_STALE_SHARE`).
+
+    The table compacts BY ITSELF, before a tick that would take the
+    departed share of the base past :data:`MAX_STALE_SHARE` or the delta
+    past its capacity — sooner than the rule at most by one batch,
+    never later.  Nothing here selects a path: what ``simulate_lookups``
+    does with a table it reads off the table.  ``view`` is the table as
+    it is NOW: a tick and a compaction consume the one before (its
+    buffers are donated), so read ``table.view`` anew after either.
+
+    Telemetry: span ``dht_table_apply_seconds`` around a tick's ingest
+    and ``dht_table_compact_seconds`` around a compaction (each waits
+    for its result); counters ``dht_table_compactions_total``,
+    ``dht_table_rows_departed_total``, ``dht_table_rows_joined_total``;
+    gauges ``dht_churn_tombstones`` and ``dht_churn_delta_rows``.
+    """
+
+    def __init__(self, sorted_ids, n_valid, *, delta_capacity: int):
+        rows = sorted_ids.shape[0]
+        # room for a full delta, in whole liveness words
+        capacity = 32 * tomb_words(rows + delta_capacity)
+        self.view: ChurnTable = churn_table(
+            sorted_ids, n_valid, capacity=capacity,
+            delta_capacity=delta_capacity,
+            stale_rows=stale_limit(capacity, MAX_STALE_SHARE),
+            lut_bits=default_lut_bits(rows))
+        self.compactions = 0
+        # the host's five integers (each read back with a result the
+        # span waits for anyway)
+        self.n_base = int(self.view.n_base)
+        self.n_tomb = self.n_delta = self.n_delta_gone = 0
+
+    @property
+    def n_live(self) -> int:
+        return (self.n_base - self.n_tomb
+                + self.n_delta - self.n_delta_gone)
+
+    @property
+    def stale_rows_max(self) -> int:
+        """Departed base rows this base may hold."""
+        return stale_limit(self.n_base, MAX_STALE_SHARE)
+
+    def apply(self, leave_ids, join_ids) -> None:
+        """One tick: ``leave_ids`` [E,5] depart (found by id; an id that
+        is no live member leaves nothing), then ``join_ids`` [J,5]
+        arrive.  Both are device arrays; each distinct (E, J) is an
+        executable of its own."""
+        E, J = leave_ids.shape[0], join_ids.shape[0]
+        if (self.n_tomb + E > self.stale_rows_max
+                or self.n_delta + J > self.view.delta_capacity):
+            self.compact()
+            if E > self.stale_rows_max or J > self.view.delta_capacity:
+                raise ValueError(
+                    f"a tick of {E} departures and {J} arrivals does not "
+                    f"fit a base of {self.n_base} rows (at most "
+                    f"{self.stale_rows_max} departed) and a delta of "
+                    f"{self.view.delta_capacity}")
+        reg = telemetry.get_registry()
+        with reg.span("dht_table_apply_seconds"):
+            self.view, left = churn_apply(self.view, leave_ids, join_ids)
+            left_base, left_delta = (int(x) for x in jax.device_get(left))
+        self.n_tomb += left_base
+        self.n_delta_gone += left_delta
+        self.n_delta += J
+        reg.counter("dht_table_rows_departed_total").inc(
+            left_base + left_delta)
+        reg.counter("dht_table_rows_joined_total").inc(J)
+        self._gauges(reg)
+
+    def compact(self) -> None:
+        """Merge the live rows of base and delta into a new sorted base
+        with its LUT, departed rows dropped."""
+        if self.n_live > self.view.capacity:
+            raise RuntimeError(
+                f"{self.n_live} live rows do not fit the table's capacity "
+                f"of {self.view.capacity}")
+        reg = telemetry.get_registry()
+        with reg.span("dht_table_compact_seconds"):
+            self.view = churn_compact(self.view)
+            self.n_base = int(self.view.n_base)
+        self.n_tomb = self.n_delta = self.n_delta_gone = 0
+        self.compactions += 1
+        _M_COMPACTIONS.inc()
+        self._gauges(reg)
+
+    def _gauges(self, reg) -> None:
+        reg.gauge("dht_churn_tombstones").set(self.n_tomb)
+        reg.gauge("dht_churn_delta_rows").set(self.n_delta)
 
 
 class NodeTable:
@@ -685,7 +794,7 @@ class NodeTable:
     def _tomb_limit(self) -> int:
         ch = self._churn
         n = ch.n_base if ch is not None else 0
-        return max(TOMB_MIN, n // TOMB_FRAC)
+        return max(TOMB_MIN, stale_limit(n, MAX_STALE_SHARE))
 
     def _delta_growth_limit(self) -> int:
         """Overflow headroom: the delta may double up to 8× its

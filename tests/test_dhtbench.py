@@ -28,6 +28,13 @@ PR 28's cell ``host4-100m.wave-65536`` is rehearsed here too, on four of
 the virtual CPU devices, with the assertions of the sim case; and its
 blockwise reference (``dhtbench/reference_blocks.py``) is held to
 ``reference.XorIndex`` over the whole id set.
+
+PR 32's cell ``sim-10m-churn.wave-65536`` (driver ``sim_churn``) is
+rehearsed the same way, its ``check`` is shown to FAIL a table that lost
+or resurrected a node, its book (``dhtbench/reference_churn.py``) is held
+to a Python set, and the ``stage`` metric files are fourteen: the cell
+reads ``fetch_ids`` and the four stages of the churn model and of the
+mutable table through files of its own.
 """
 
 import json
@@ -184,7 +191,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
              for f in sorted(os.listdir(mdir))}
     staged = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage"}
-    assert len(staged) == 9
+    assert len(staged) == 19
     assert {name: s["stage"] for name, s in staged.items()
             if s["value"] == "stage_ms_per"} == {
         "sim_fetch_ids_ms_per_wave": "fetch_ids",
@@ -194,11 +201,167 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
         "host4_owner_merge_ms_per_wave": "owner_merge",
         "host4_fetch_ids_ms_per_wave": "fetch_ids",
         "host4_block_bounds_ms_per_wave": "block_bounds",
-        "host4_merge_ms_per_wave": "merge"}
+        "host4_merge_ms_per_wave": "merge",
+        "churn_fetch_ids_ms_per_wave": "fetch_ids",
+        "churn_expire_ms_per_wave": "expire",
+        "churn_delta_window_ms_per_wave": "delta_window",
+        "churn_apply_ms_per_wave": "table_apply",
+        "churn_compact_ms_per_wave": "table_compact",
+        "churn_compact_ms": "table_compact",
+        "churn_block_bounds_ms_per_wave": "block_bounds",
+        "churn_merge_ms_per_wave": "merge",
+        "churn_reply_rows_ms_per_wave": "reply_rows"}
+    # one compaction's time is over the compactions, not over the waves:
+    # it does not move with the waves' speed or the window's length
+    assert {name for name, s in staged.items() if s.get("per") != "waves"
+            and s["value"] == "stage_ms_per"} == {"churn_compact_ms"}
+    assert {name for name, s in staged.items()
+            if s["value"] == "unstaged_share"} \
+        == {"sim_unstaged_share", "churn_unstaged_share"}
     # every stage a metric names is one the program names: those of the
-    # round engine, and the tp twin's collective
+    # round engine, the tp twin's collective, the churn model's two and
+    # the mutable table's two
+    from opendht_tpu.core import search
+    from opendht_tpu.ops import churn_table
     from opendht_tpu.parallel import sharded
     import inspect
     assert 'device_stage("owner_merge")' in inspect.getsource(sharded)
+    for name in ("expire", "delta_window"):
+        assert f'device_stage("{name}")' in inspect.getsource(search)
+    for name in ("table_apply", "table_compact"):
+        assert f'device_stage("{name}")' in inspect.getsource(churn_table)
     assert {s["stage"] for s in staged.values() if "stage" in s} \
-        <= set(STAGES) | {"owner_merge"}
+        <= set(STAGES) | {"owner_merge", "expire", "delta_window",
+                          "table_apply", "table_compact"}
+    # the one metric that divides by a stage's time and not the window's
+    shares = {name: m["source"] for name, m in specs.items()
+              if m["source"]["kind"] == "stage_share"}
+    assert {n: s["stage"] for n, s in shares.items()} \
+        == {"churn_compact_hbm_share": "table_compact"}
+
+
+# -- PR 32: sim-10m-churn.wave-65536 ------------------------------------------
+
+CHURN_REHEARSAL = {"n_ids": 16384, "wave_targets": 256, "target_sets": 4,
+                   "leave_per_tick": 64, "join_per_tick": 64,
+                   "delta_rows": 512, "warm_ticks": 5, "schedule_ticks": 600}
+# its per-layer metrics that read the program's registry (the trace and
+# stage ones read nothing on the CPU)
+CHURN_REGISTRY_METRICS = {"churn_expired_peers_per_wave", "churn_tick_ms",
+                          "churn_record_ms_per_wave",
+                          "churn_dispatch_ms_per_wave",
+                          "churn_narrow_rounds_per_wave"}
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_churn_cell_at_toy_size_prints_the_contract_line(manifest, trace):
+    line = run.run_cell("sim-10m-churn.wave-65536", 2 ** 31 + 32032, 1.0,
+                        trace, rehearsal=CHURN_REHEARSAL)
+    line = json.loads(json.dumps(line))
+    assert set(line) == RESULT_KEYS         # no breakdown: the CPU has no device plane
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0 and line["attempted"] % 256 == 0
+    assert set(line["device"]) == {"platform", "kind", "count",
+                                   "memory_peak_bytes"}
+    if trace:
+        assert set(line["metrics"]) == CHURN_REGISTRY_METRICS
+    else:
+        assert set(line["metrics"]) == {"sim_lookups_per_s",
+                                        "sim_wave_p90_ms", "setup_s"}
+    for name, m in line["metrics"].items():
+        # 256 lookups a wave are under the engine's cut: no narrow round
+        assert set(m) == {"value", "unit"} and (
+            m["value"] > 0 or name == "churn_narrow_rounds_per_wave")
+
+
+def test_churn_check_fails_a_table_that_lost_or_resurrected_a_node(manifest):
+    """The membership guarantee is checked, not assumed: a table whose
+    liveness words have lost a departure (a node resurrected), gained
+    one (a node lost), or whose delta dropped an arrival is not
+    ``correct``; nor is a window without a compaction."""
+    import jax.numpy as jnp
+    from dhtbench.drivers import sim_churn
+    cell, config, driver, _ = run.resolve("sim-10m-churn.wave-65536")
+    assert driver is sim_churn
+    config = dict(config, sizes={**config["sizes"], **{
+        k: v for k, v in CHURN_REHEARSAL.items() if k in config["sizes"]}})
+    traffic = {**cell["traffic"], **{
+        k: v for k, v in CHURN_REHEARSAL.items() if k in cell["traffic"]}}
+    st = sim_churn.setup(config, traffic, 5, lambda msg: None)
+    try:
+        result = sim_churn.window(st, 0.5)
+        result["values"]["compiles_in_window"] = 0
+        correct, why = sim_churn.check(st, result)
+        assert correct, why
+        assert result["values"]["compactions"] >= 1
+        assert result["values"]["least_compact_bytes"] == sum(
+            [sim_churn.least_compact_bytes(16384, st.table.view.lut.shape[0])]
+            * result["values"]["compactions"])
+        tbl = st.table
+        good = tbl.view
+        assert tbl.n_tomb > 0 and tbl.n_delta > 0
+        gone = int(np.asarray(good.dead_pos)[0])         # a departed row
+        words = np.asarray(good.tomb_bits).copy()
+        words[gone >> 5] ^= np.uint32(1 << (gone & 31))
+        resurrected = good._replace(tomb_bits=jnp.asarray(words))
+        alive = int(np.nonzero(np.asarray(
+            sim_churn.live_rows(good)[1])[:tbl.n_base])[0][0])
+        words = np.asarray(good.tomb_bits).copy()
+        words[alive >> 5] |= np.uint32(1 << (alive & 31))
+        lost = good._replace(tomb_bits=jnp.asarray(words))
+        dropped = good._replace(n_delta=good.n_delta - 1)
+        for bad in (resurrected, lost, dropped):
+            tbl.view = bad
+            correct, why = sim_churn.check(st, result)
+            assert not correct and "membership" in why, why
+        tbl.view = good
+        assert sim_churn.check(st, result)[0]
+        result["values"]["compactions"] = 0
+        correct, why = sim_churn.check(st, result)
+        assert not correct and "compactions" in why
+    finally:
+        sim_churn.close(st)
+
+
+def test_the_churn_book_is_a_plain_set_of_ids():
+    from dhtbench import reference_churn
+    rng = np.random.default_rng(32)
+    book = rng.integers(0, 2 ** 32, size=(500, 5), dtype=np.uint32)
+    slots, arrivals = reference_churn.make_schedule(rng, 500, 9, 40)
+    leaving = reference_churn.departures(book, slots, arrivals)
+    live = {r.tobytes() for r in book}
+    for t in range(9):
+        assert len(set(slots[t].tolist())) == 40          # no slot twice
+        for r in leaving[t]:
+            live.remove(r.tobytes())                      # each was alive
+        live |= {r.tobytes() for r in arrivals[t]}
+        after = reference_churn.book_after(book, slots, arrivals, t + 1)
+        assert {r.tobytes() for r in after} == live
+    # someone who arrived has departed again
+    assert {r.tobytes() for r in leaving.reshape(-1, 5)} \
+        & {r.tobytes() for r in arrivals.reshape(-1, 5)}
+    # the fingerprint: order-free, and moved by a lost, a doubled or a
+    # limb-swapped row
+    after = reference_churn.book_after(book, slots, arrivals, 9)
+    want = reference_churn.checksum(after).tolist()
+    assert reference_churn.checksum(after[::-1]).tolist() == want
+    swapped = after.copy()
+    swapped[[3, 4], 2] = swapped[[4, 3], 2]
+    for bad in (after[1:], np.concatenate([after, after[:1]]), swapped):
+        assert reference_churn.checksum(bad).tolist() != want
+    # and it is drivers/sim_tp.checksum's arithmetic
+    import jax.numpy as jnp
+    from dhtbench.drivers.sim_tp import checksum
+    assert np.asarray(checksum(jnp.asarray(after),
+                               jnp.ones(500, bool))).tolist() == want
+    # membership and the exact top-k over the live ids
+    live_set = reference_churn.LiveSet(after)
+    probe = np.concatenate([after[:5], book[slots[0][:5]]])
+    probe[2, 4] ^= 1                                      # 159 bits shared
+    assert live_set.holds(probe).tolist() == [
+        True, True, False, True, True] + [
+        r.tobytes() in live for r in book[slots[0][:5]]]
+    target = rng.integers(0, 2 ** 32, size=5, dtype=np.uint32)
+    np.testing.assert_array_equal(
+        live_set.closest_ids(target, 8),
+        after[reference.xor_closest(after, target, 8)])
