@@ -194,7 +194,12 @@ def fused_gather_planar(sorted_t, rows, limbs: int = N_LIMBS):
     (tests/test_topk.py).  Out-of-range rows (e.g. the engine's -1
     "absent" sentinel) are clipped, so their lanes carry garbage —
     every caller masks them (the oracle returns the all-ones sentinel
-    there instead).
+    there instead).  The clip is correct and not free: every clipped
+    lane reads row 0 or the last row, and a gather out of HBM with
+    three lanes in four piled on one row pays 15 ns a row where a
+    scattered index pays 9.7 (PERF.md §7 (1)), so a caller that would
+    clip most of its index hands over in-range, scattered rows instead
+    (parallel/sharded.py ``owner_local_index``).
     """
     flat = lax.optimization_barrier(rows).reshape(-1)
     g = jnp.take(sorted_t[:limbs], flat, axis=1, mode="clip")   # [limbs, M]
@@ -229,7 +234,7 @@ def loop_gather_view(sorted_t, limbs: int):
     PR 31).
 
     It cannot fit (a 25M-row shard's is 200 MB): the slice is staged
-    nowhere, the gather pays HBM's price either way (13.6 ns a row),
+    nowhere, the gather pays HBM's price either way (9.6 ns a row),
     and the slice is 200 MB read and written every trip for nothing —
     1.43 ms a round, 11.4 ms of a 323 ms wave.  Take the view once.
 

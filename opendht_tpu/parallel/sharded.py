@@ -330,6 +330,47 @@ def sharded_lookup(mesh: Mesh, queries, table, *, k: int = 8,
                                  k=k, window=window)
 
 
+# The stride, in rows, between the spare rows of two neighbouring lanes
+# (owner_local_index).  Odd and prime, so that the spares of a round are
+# distinct rows of any shard whose capacity is no multiple of it; wide
+# enough that neighbouring lanes never share a 128-row tile of the
+# view; small enough that lane * stride stays exact in 32 bits up to
+# 4.2M lanes (past that it wraps and scatters all the same).
+SPARE_STRIDE = 1021
+
+
+def owner_local_index(rows, base, n_owned, capacity: int):
+    """What one shard hands its gather for global ``rows``: the local
+    index of every lane, and ``ok``, the lanes whose row this shard owns
+    (``base <= row < base + n_owned``).  An owned lane reads
+    ``rows - base``.  Every other lane — another shard's row, or the
+    engine's -1 for an absent one — is thrown away behind ``ok``, so
+    which row it reads is free to choose, and it reads a spare row of
+    its own: its flat position in ``rows`` times :data:`SPARE_STRIDE`,
+    modulo the shard's ``capacity``.  In range by construction, and
+    scattered over the whole shard.
+
+    Why not leave ``rows - base`` to the gather's clip: the clip sends
+    every such lane to row 0 or to the last row, three lanes in four at
+    ``t`` = 4, and a gather out of HBM whose lanes crowd into few tiles
+    of the view pays 15 ns a row where a scattered index pays 9.7
+    (PERF.md §7 (1), the probe of PR 33: a 25M-row 2-limb view,
+    1,572,864 lanes, a quarter of them owned).  It is the crowding that
+    costs, not the one row: the lane's own position as its spare —
+    distinct but CONSECUTIVE rows, 128 lanes a tile — reads 15.6, as
+    dear as the clip, and the global row wrapped into the shard reads
+    9.9 but 14.5 once two lanes in three are absent and fall back on
+    their position.  The stride holds 9.6–9.7 at every mix, which is
+    why it is the rule; it is a constant of the program.  ``ok`` and
+    everything behind the gather are untouched, so every output bit is.
+    """
+    loc = rows - base
+    ok = (loc >= 0) & (loc < n_owned)
+    lane = lax.iota(_U32, rows.size).reshape(rows.shape)
+    spare = lane * _U32(SPARE_STRIDE) % _U32(capacity)
+    return jnp.where(ok, loc, spare.astype(jnp.int32)), ok
+
+
 @functools.lru_cache(maxsize=16)
 def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
                     alpha: int, search_nodes: int, max_hops: int,
@@ -365,6 +406,11 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     is decided once, before the engine runs
     (``ops.sorted_table.loop_gather_view``): a shard whose limb view is
     too large for on-chip memory is not sliced inside a loop body.
+    Every gather is handed an index that lies in the shard, lane for
+    lane (:func:`owner_local_index`): a lane another shard owns, or
+    that holds no row at all, reads a spare row of its own, never the
+    one row a clip would send them all to, and is zeroed before the
+    collective.
     """
     q_local = q_total // mesh.shape["q"]
 
@@ -422,8 +468,8 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
         def gather_planar(rows, limbs=N_LIMBS):
             # distributed row fetch: the owning shard contributes the
             # row's limbs, every other shard zeros — psum reassembles.
-            # Rows are pre-clipped to [0, n) by the engine; -1 (absent)
-            # rows land out of range on every shard and come back 0,
+            # Rows are pre-clipped to [0, n) by the engine; a -1
+            # (absent) row is owned by no shard and comes back 0,
             # masked by the engine exactly like the unsharded garbage.
             # With the round-6 fused engine this runs ONCE per round
             # (the α·k reply fetch): the per-round 1-limb peer fetch's
@@ -431,14 +477,15 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             # candidate distance instead (core/search.py).
             # The index keeps ``rows``' own shape up to the gather
             # (the engine's: slot-major, W on the lanes) and the planes
-            # come back in it — ops.sorted_table.fused_gather_planar,
-            # which clips to this shard's [0, shard_n).
-            loc = rows - base
-            # ownership test: weighted shards own exactly n_local rows
-            # (the [b_i, b_{i+1}) ranges partition the valid prefix);
-            # the uniform test keeps the static width — equivalent for
-            # valid rows, and it leaves the uniform program unchanged
-            ok = (loc >= 0) & (loc < (n_local if weighted else shard_n))
+            # come back in it (ops.sorted_table.fused_gather_planar).
+            # Ownership: weighted shards own exactly n_local rows (the
+            # [b_i, b_{i+1}) ranges partition the valid prefix); the
+            # uniform test keeps the static width, equivalent for valid
+            # rows.  What a lane that is not owned reads is
+            # owner_local_index's one rule, whatever the layout or the
+            # limb count.
+            loc, ok = owner_local_index(
+                rows, base, n_local if weighted else shard_n, shard_n)
             g = jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
                            fused_gather_planar(views[limbs], loc, limbs)])
             # the round's one collective, a device stage of its own

@@ -17,7 +17,7 @@ from opendht_tpu.core.search import simulate_lookups
 from opendht_tpu.parallel import (
     make_mesh, pad_to_multiple, sharded_xor_topk, sharded_lookup,
     sharded_sort_table, sharded_window_lookup, sharded_maintenance_sweep,
-    dp_simulate_lookups, tp_simulate_lookups,
+    dp_simulate_lookups, sharded_global_sort, tp_simulate_lookups,
 )
 
 
@@ -224,32 +224,124 @@ def test_tp_simulate_mesh_geometries(q, t):
                                       np.asarray(ref[key]))
 
 
+@pytest.mark.parametrize("layout", ["uniform", "weighted"])
 @pytest.mark.parametrize("q,t", [(1, 4), (2, 2)])
-def test_tp_simulate_equals_one_chip_with_the_cut_engaged(q, t):
+def test_tp_simulate_equals_one_chip_with_the_cut_engaged(q, t, layout):
     """SURVIVOR COMPACTION on a mesh (core/search.py _lookup_engine):
     each q-rank's wave is wide enough to cut, so it packs its survivors
     and runs its last rounds narrow — with the round's one psum at the
     narrow width — and the results equal one chip's, which cuts too.
     Every t-rank holds the same search state, so they cut together; a
-    q-rank cuts when ITS survivors fit (one count a rank)."""
+    q-rank cuts when ITS survivors fit (one count a rank).  Lookups are
+    already done in the wide rounds before the cut (their rows are -1),
+    and every shard's gather reads spare rows for those lanes and for
+    the lanes other shards own (``owner_local_index``): on the uniform
+    split of a host-sorted table and on the WEIGHTED state of
+    ``sharded_global_sort``, whose shards own unequal row counts under
+    one capacity."""
     from opendht_tpu.core.search import NARROW_MIN_WAVE
     m = make_mesh(4, q=q, t=t)
     k1, k2 = jax.random.split(jax.random.PRNGKey(3000))
-    sorted_ids, _, n_valid = sort_table(jax.random.bits(
-        k1, (3000, 5), dtype=jnp.uint32))
+    n = 3000 if layout == "uniform" else 4096
+    ids = jax.random.bits(k1, (n, 5), dtype=jnp.uint32)
+    sorted_ids, _, n_valid = sort_table(ids)
     targets = jax.random.bits(k2, (q * NARROW_MIN_WAVE, 5), dtype=jnp.uint32)
     kw = dict(seed=11, alpha=2, state_limbs=2)
     ref = simulate_lookups(sorted_ids, n_valid, targets, **kw)
-    out = tp_simulate_lookups(m, np.asarray(sorted_ids), n_valid,
-                              np.asarray(targets), **kw)
+    if layout == "uniform":
+        out = tp_simulate_lookups(m, np.asarray(sorted_ids), n_valid,
+                                  np.asarray(targets), **kw)
+    else:
+        state = sharded_global_sort(m, np.asarray(ids))
+        widths = np.asarray(state.arrays["shard_rows"])[:, 1]
+        assert len(set(widths.tolist())) > 1 and widths.max() < state.shard_n
+        out = tp_simulate_lookups(m, targets=np.asarray(targets),
+                                  state=state, **kw)
     narrow = np.asarray(out["narrow_rounds"])
     assert narrow.shape == (q,) and (narrow >= 1).all()
     assert int(ref["narrow_rounds"]) >= 1
     if q == 1:
         assert narrow[0] == int(ref["narrow_rounds"])
+    # lookups were dead in a wide round: some took fewer hops than the
+    # round the wave cut in, so their lanes carried -1 rows at full width
+    hops = np.asarray(ref["hops"])
+    assert hops.min() < hops.max() - int(ref["narrow_rounds"])
     for key in ("nodes", "dist", "hops", "converged"):
         np.testing.assert_array_equal(np.asarray(out[key]),
                                       np.asarray(ref[key]), err_msg=key)
+
+
+def _owner_index(rows, shard, shard_n, weighted):
+    """``owner_local_index`` as ``build_tp_lookup`` calls it for one
+    shard: the weighted layout owns ``n_local`` rows of its capacity
+    (here all but 1,000 of them), the uniform one tests the static
+    width."""
+    from opendht_tpu.parallel.sharded import owner_local_index
+    n_owned = shard_n - 1000 if weighted else shard_n
+    base = shard * n_owned
+    # the weighted count is data (a traced scalar), the uniform one static
+    loc, ok = owner_local_index(
+        rows, np.int32(base), np.int32(n_owned) if weighted else n_owned,
+        shard_n)
+    return np.asarray(loc), np.asarray(ok), base, n_owned
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["uniform", "weighted"])
+@pytest.mark.parametrize("t", [2, 4, 8])
+def test_owner_local_index_keeps_owned_lanes_and_spreads_the_rest(t,
+                                                                  weighted):
+    """The index a shard hands its gather (parallel/sharded.py
+    ``owner_local_index``), at the cell's shapes: 1,572,864 lanes
+    (slot-major [24, 65536]) of uniform global rows into ``t`` shards
+    of 25M, an eighth of the lanes dead (-1).  An owned lane keeps
+    ``rows - base``; every other lane, dead ones included, lies in
+    ``[0, shard_n)``, and no row is the spare of more than 8 lanes —
+    where the parent's index, ``rows - base`` left to the gather's
+    clip, piles every lane of the shards above on shard 0's last
+    row."""
+    shard_n = 25_000_000
+    rng = np.random.default_rng(33 + t)
+    rows = rng.integers(0, t * (shard_n - 1000 * weighted),
+                        size=(24, 65536)).astype(np.int32)
+    dead = rng.random(rows.shape) < 0.125
+    rows[dead] = -1
+    for shard in (0, t - 1):
+        loc, ok, base, n_owned = _owner_index(rows, shard, shard_n, weighted)
+        owned = (rows >= base) & (rows < base + n_owned)
+        np.testing.assert_array_equal(ok, owned)
+        assert not ok[dead].any()
+        np.testing.assert_array_equal(loc[ok], rows[ok] - base)
+        assert loc.min() >= 0 and loc.max() < shard_n
+        assert 0.8 / t < ok.mean() < 1.0 / t
+        assert np.unique(loc[~ok], return_counts=True)[1].max() <= 8
+    # the case the rule exists for: the parent's index on shard 0
+    piled = (np.clip(rows, 0, shard_n - 1) == shard_n - 1).sum()
+    assert piled > (1 - 1 / t) * 0.85 * rows.size
+    if t == 4 and not weighted:
+        rows = rng.integers(0, 4 * shard_n, size=rows.shape)
+        assert 1_170_000 < (np.clip(rows, 0, shard_n - 1)
+                            == shard_n - 1).sum() < 1_190_000
+
+
+@pytest.mark.parametrize("weighted", [False, True],
+                         ids=["uniform", "weighted"])
+def test_owner_local_index_stays_in_a_shard_smaller_than_the_wave(weighted):
+    """A toy shard of 1,500 rows under a wave of 24 x 512 lanes: the
+    spare rows wrap into the shard, each the spare of at most
+    ceil(lanes / shard_n) lanes."""
+    shard_n = 1500
+    rng = np.random.default_rng(7)
+    rows = rng.integers(-1, 4 * (shard_n - 1000 * weighted),
+                        size=(24, 512)).astype(np.int32)
+    for shard in range(4):
+        loc, ok, base, n_owned = _owner_index(rows, shard, shard_n, weighted)
+        np.testing.assert_array_equal(
+            ok, (rows >= base) & (rows < base + n_owned))
+        np.testing.assert_array_equal(loc[ok], rows[ok] - base)
+        assert loc.min() >= 0 and loc.max() < shard_n
+        assert np.unique(loc[~ok], return_counts=True)[1].max() \
+            <= -(-rows.size // shard_n)
 
 
 def test_dp_simulate_equals_one_chip_with_the_cut_engaged(mesh):
