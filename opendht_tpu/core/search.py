@@ -391,7 +391,10 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
           primitives (below).  With neither the engine lowers to the
           program it was before they existed, operation for operation
           (the committed goldens of tests/test_search.py and
-          tests/test_sharded.py), and the tp twin hands it neither.
+          tests/test_sharded.py).  The tp twin hands it both over a
+          ``parallel.churn.ShardedChurnTable`` (owner-shard reads and
+          one ``psum`` each, ``parallel/sharded.py
+          _tp_churn_primitives``) and neither over a table built once.
 
     CHURN (PR 32): the table is a sorted BASE as last compacted, a
     liveness bit a node, and a sorted DELTA of the nodes that joined

@@ -554,11 +554,22 @@ class DeviceChurnTable:
     it is NOW: a tick and a compaction consume the one before (its
     buffers are donated), so read ``table.view`` anew after either.
 
+    A table that outgrows one chip is the sibling
+    ``parallel.churn.ShardedChurnTable``: the same object over a table
+    row-sharded over a mesh, one ``ChurnTable`` a shard, searched by
+    ``parallel.tp_simulate_lookups(mesh, state=table.view)``.  It is
+    this class with the two device programs replaced, so the rule, the
+    spans and the series below are one spelling: the host keeps its
+    integers ONE A SHARD (here: one), and whichever shard would pass a
+    limit first makes all compact.
+
     Telemetry: span ``dht_table_apply_seconds`` around a tick's ingest
     and ``dht_table_compact_seconds`` around a compaction (each waits
     for its result); counters ``dht_table_compactions_total``,
     ``dht_table_rows_departed_total``, ``dht_table_rows_joined_total``;
-    gauges ``dht_churn_tombstones`` and ``dht_churn_delta_rows``.
+    gauges ``dht_churn_tombstones`` and ``dht_churn_delta_rows`` (the
+    sum over the shards) and ``dht_churn_delta_rows_max`` (the fullest
+    shard's, the one that triggers).
     """
 
     def __init__(self, sorted_ids, n_valid, *, delta_capacity: int):
@@ -570,61 +581,105 @@ class DeviceChurnTable:
             delta_capacity=delta_capacity,
             stale_rows=stale_limit(capacity, MAX_STALE_SHARE),
             lut_bits=default_lut_bits(rows))
+        self._capacity, self._delta_capacity = capacity, delta_capacity
         self.compactions = 0
-        # the host's five integers (each read back with a result the
-        # span waits for anyway)
-        self.n_base = int(self.view.n_base)
-        self.n_tomb = self.n_delta = self.n_delta_gone = 0
+        self._rebased([int(self.view.n_base)])
+
+    # -- the host's integers, one of each a shard (each read back with a
+    # result the span waits for anyway) ---------------------------------
+    def _rebased(self, n_base) -> None:
+        """The counts right after a build or a compaction."""
+        self._n_base = np.asarray(n_base, np.int64)
+        self._n_tomb = np.zeros_like(self._n_base)
+        self._n_delta = np.zeros_like(self._n_base)
+        self._n_delta_gone = np.zeros_like(self._n_base)
+
+    n_base = property(lambda self: int(self._n_base.sum()))
+    n_tomb = property(lambda self: int(self._n_tomb.sum()))
+    n_delta = property(lambda self: int(self._n_delta.sum()))
+    n_delta_gone = property(lambda self: int(self._n_delta_gone.sum()))
+
+    def _live(self) -> np.ndarray:
+        return (self._n_base - self._n_tomb
+                + self._n_delta - self._n_delta_gone)
 
     @property
     def n_live(self) -> int:
-        return (self.n_base - self.n_tomb
-                + self.n_delta - self.n_delta_gone)
+        return int(self._live().sum())
+
+    def _stale_max(self) -> np.ndarray:
+        return np.array([stale_limit(int(n), MAX_STALE_SHARE)
+                         for n in self._n_base])
 
     @property
     def stale_rows_max(self) -> int:
-        """Departed base rows this base may hold."""
-        return stale_limit(self.n_base, MAX_STALE_SHARE)
+        """Departed base rows a base may hold (the tightest shard's)."""
+        return int(self._stale_max().min())
+
+    # -- the two device programs (parallel.churn replaces them) ---------
+    def _run_tick(self, leave_ids, join_ids):
+        """One tick on the device: ``(left_base, left_delta, joined)``,
+        one of each a shard."""
+        self.view, left = churn_apply(self.view, leave_ids, join_ids)
+        left_base, left_delta = (int(x) for x in jax.device_get(left))
+        return [left_base], [left_delta], [join_ids.shape[0]]
+
+    def _run_compact(self):
+        """One compaction on the device: the new bases' row counts."""
+        self.view = churn_compact(self.view)
+        return [int(self.view.n_base)]
+
+    def _make_room(self, E: int, J: int) -> None:
+        """Before a tick that hands a shard up to ``E`` departures and
+        ``J`` arrivals: compact if any shard's departed rows or delta
+        would pass their limit, and refuse what no compaction makes
+        room for."""
+        D = self._delta_capacity
+        if ((self._n_tomb + E > self._stale_max()).any()
+                or (self._n_delta + J > D).any()):
+            self.compact()
+            if (E > self._stale_max()).any() or J > D:
+                raise ValueError(
+                    f"a tick of {E} departures and {J} arrivals does not "
+                    f"fit a base of {self._n_base.tolist()} rows (at most "
+                    f"{self._stale_max().tolist()} departed) and a delta "
+                    f"of {D}")
+
+    def _tick(self, leave_ids, join_ids, **how) -> None:
+        """The tick proper: span, program (``how``: what the sibling's
+        takes besides), counts, series."""
+        reg = telemetry.get_registry()
+        with reg.span("dht_table_apply_seconds"):
+            left_base, left_delta, joined = self._run_tick(
+                leave_ids, join_ids, **how)
+        self._n_tomb += left_base
+        self._n_delta_gone += left_delta
+        self._n_delta += joined
+        reg.counter("dht_table_rows_departed_total").inc(
+            int(np.sum(left_base) + np.sum(left_delta)))
+        reg.counter("dht_table_rows_joined_total").inc(int(np.sum(joined)))
+        self._gauges(reg)
 
     def apply(self, leave_ids, join_ids) -> None:
         """One tick: ``leave_ids`` [E,5] depart (found by id; an id that
         is no live member leaves nothing), then ``join_ids`` [J,5]
         arrive.  Both are device arrays; each distinct (E, J) is an
         executable of its own."""
-        E, J = leave_ids.shape[0], join_ids.shape[0]
-        if (self.n_tomb + E > self.stale_rows_max
-                or self.n_delta + J > self.view.delta_capacity):
-            self.compact()
-            if E > self.stale_rows_max or J > self.view.delta_capacity:
-                raise ValueError(
-                    f"a tick of {E} departures and {J} arrivals does not "
-                    f"fit a base of {self.n_base} rows (at most "
-                    f"{self.stale_rows_max} departed) and a delta of "
-                    f"{self.view.delta_capacity}")
-        reg = telemetry.get_registry()
-        with reg.span("dht_table_apply_seconds"):
-            self.view, left = churn_apply(self.view, leave_ids, join_ids)
-            left_base, left_delta = (int(x) for x in jax.device_get(left))
-        self.n_tomb += left_base
-        self.n_delta_gone += left_delta
-        self.n_delta += J
-        reg.counter("dht_table_rows_departed_total").inc(
-            left_base + left_delta)
-        reg.counter("dht_table_rows_joined_total").inc(J)
-        self._gauges(reg)
+        self._make_room(leave_ids.shape[0], join_ids.shape[0])
+        self._tick(leave_ids, join_ids)
 
     def compact(self) -> None:
         """Merge the live rows of base and delta into a new sorted base
         with its LUT, departed rows dropped."""
-        if self.n_live > self.view.capacity:
-            raise RuntimeError(
-                f"{self.n_live} live rows do not fit the table's capacity "
-                f"of {self.view.capacity}")
+        if (self._live() > self._capacity).any():
+            raise ValueError(
+                f"{self._live().tolist()} live rows would pass the "
+                f"capacity of {self._capacity} rows (a shard): nothing is "
+                "compacted and no row dropped, build a larger table")
         reg = telemetry.get_registry()
         with reg.span("dht_table_compact_seconds"):
-            self.view = churn_compact(self.view)
-            self.n_base = int(self.view.n_base)
-        self.n_tomb = self.n_delta = self.n_delta_gone = 0
+            n_base = self._run_compact()
+        self._rebased(n_base)
         self.compactions += 1
         _M_COMPACTIONS.inc()
         self._gauges(reg)
@@ -632,6 +687,7 @@ class DeviceChurnTable:
     def _gauges(self, reg) -> None:
         reg.gauge("dht_churn_tombstones").set(self.n_tomb)
         reg.gauge("dht_churn_delta_rows").set(self.n_delta)
+        reg.gauge("dht_churn_delta_rows_max").set(int(self._n_delta.max()))
 
 
 class NodeTable:

@@ -236,9 +236,19 @@ def churn_apply(tbl: ChurnTable, leave_ids, join_ids):
     return device_stage("table_apply")(_apply)(tbl, leave_ids, join_ids)
 
 
-def _apply(tbl: ChurnTable, leave_ids, join_ids):
+def _apply(tbl: ChurnTable, leave_ids, join_ids, n_real=None):
+    """:func:`churn_apply`'s tick.  ``n_real`` = ``(n_leave, n_join)``
+    (traced counts) says that only the first ``n_leave`` rows of
+    ``leave_ids`` and the ``n_join`` smallest of ``join_ids`` are ids
+    and the rest PADDING — what a shard of a row-sharded table is handed
+    when a batch is routed to it at a fixed width
+    (parallel/churn.py): a padding departure leaves nothing whatever it
+    holds, a padding arrival is the all-ones id, sorts behind the real
+    ones and lands past the delta's new end, where the all-ones rows
+    are."""
     C, D = tbl.capacity, tbl.delta_capacity
     E, J = leave_ids.shape[0], join_ids.shape[0]
+    n_leave, n_join = (E, J) if n_real is None else n_real
 
     # -- positions: departures and arrivals in the base, one search ----
     joins = _sorted_rows(join_ids)
@@ -253,9 +263,13 @@ def _apply(tbl: ChurnTable, leave_ids, join_ids):
     # -- departures ----------------------------------------------------
     live_b = hit_b[:E] & ~node_gone(tbl.tomb_bits,
                                      jnp.clip(pos_b[:E], 0, C - 1))
+    if n_real is not None:
+        live_b &= jnp.arange(E, dtype=jnp.int32) < n_leave
     gone_b = _first_of_each(pos_b[:E], live_b, C)
     old_pos = jnp.take(tbl.delta_pos, jnp.clip(pos_d[:E], 0, D - 1))
     live_d = hit_d[:E] & ~live_b & (old_pos >= 0)
+    if n_real is not None:
+        live_d &= jnp.arange(E, dtype=jnp.int32) < n_leave
     gone_d = _first_of_each(pos_d[:E], live_d, D)
     left_b = jnp.sum(gone_b < C, dtype=jnp.int32)
     left_d = jnp.sum(gone_d < D, dtype=jnp.int32)
@@ -283,7 +297,7 @@ def _apply(tbl: ChurnTable, leave_ids, join_ids):
         new_planes, mode="drop")
     delta = merged[:N_LIMBS].T
     delta_pos = lax.bitcast_convert_type(merged[N_LIMBS], jnp.int32)
-    n_delta = tbl.n_delta + J
+    n_delta = tbl.n_delta + n_join
     # the delta's liveness words, from the signs as they lie now
     gone = (delta_pos < 0) & (jnp.arange(D, dtype=jnp.int32) < n_delta)
     weights = _U32(1) << jnp.arange(32, dtype=_U32)
@@ -291,8 +305,11 @@ def _apply(tbl: ChurnTable, leave_ids, join_ids):
                      * weights[None, :], axis=1, dtype=_U32)
     tomb_bits = lax.dynamic_update_slice(tomb_bits, dwords, (C // 32,))
     prefix = (joins[:, 0] >> _U32(32 - DELTA_LUT_BITS)).astype(jnp.int32)
+    if n_real is not None:
+        prefix = jnp.where(jnp.arange(J, dtype=jnp.int32) < n_join, prefix,
+                           1 << DELTA_LUT_BITS)
     below = _running_sum(jnp.zeros(((1 << DELTA_LUT_BITS),), jnp.int32)
-                         .at[prefix].add(1))
+                         .at[prefix].add(1))      # out of range: dropped
     delta_lut = tbl.delta_lut.at[1:].add(below)
     return (tbl._replace(tomb_bits=tomb_bits, dead_pos=dead_pos,
                          n_tomb=tbl.n_tomb + left_b, delta=delta,

@@ -25,3 +25,4 @@ from .sharded import (  # noqa: F401
     build_tp_lookup,
 )
 from .global_sort import sharded_global_sort  # noqa: F401
+from .churn import ShardedChurnTable  # noqa: F401

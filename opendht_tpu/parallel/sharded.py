@@ -57,6 +57,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .partition import (TABLE_AXIS_RULES, DP_AXIS_RULES, TableState,
                         shard_put, shard_table_state)
 
+from ..ops.churn_table import DELTA_WINDOW, node_gone
 from ..ops.ids import N_LIMBS
 from ..ops.xor_topk import xor_topk, select_topk, mask_invalid
 from ..ops.sorted_table import (sort_table, window_topk, build_prefix_lut,
@@ -371,10 +372,80 @@ def owner_local_index(rows, base, n_owned, capacity: int):
     return jnp.where(ok, loc, spare.astype(jnp.int32)), ok
 
 
+def shard_offset(widths, n_t: int):
+    """``(rows of the shards before this one, rows of all shards)`` from
+    the ``n_t`` shards' ``widths``: where this shard's rows begin in an
+    order that is the shards' in turn."""
+    before = jnp.arange(n_t) < lax.axis_index("t")
+    return jnp.sum(jnp.where(before, widths, 0)), jnp.sum(widths)
+
+
+def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int, base,
+                         n_local, tomb_bits, delta, n_delta, delta_lut):
+    """The engine's two CHURN primitives over ONE SHARD's piece of a
+    row-sharded mutable table (parallel/churn.py) — the tp twins of
+    ``core.search._churn_primitives``, each a shard-local read by the
+    owner and one ``psum`` over ``t``.
+
+    A node is a GLOBAL base row, or ``t·shard_n`` + a place in the
+    global order of the shards' deltas; this shard owns base rows
+    ``[base, base + n_local)`` and delta places ``[d_base, d_base +
+    n_delta)``, ``d_base`` the delta rows of the shards before it (one
+    ``all_gather`` of four counts, once a wave).  Its liveness words
+    cover its own rows, laid out as the one-device table's: local base
+    row ``r`` is bit ``r``, local delta slot ``j`` bit ``shard_n + j``.
+
+    ``alive(nodes)``: the owner reads the bit, every other shard a spare
+    word of its own (:func:`owner_local_index`) that it throws away, and
+    the shards' answers are summed — stage ``alive_merge``, inside the
+    engine's ``expire``.  ``delta_window(targets)``: the targets' place
+    in the global order of the deltas is the sum of their places in the
+    shards' (as ``lower`` is for the base), and the ``DELTA_WINDOW``
+    rows around it are fetched by their owners — a window that
+    straddles a shard edge takes rows of both — and summed: stage
+    ``delta_merge``, inside the engine's ``delta_window``.
+    """
+    total = n_t * shard_n                  # where the delta's nodes start
+    d_base, d_total = device_stage("delta_merge")(
+        lambda n: shard_offset(lax.all_gather(n, "t"), n_t))(n_delta)
+
+    def alive(nodes):
+        in_delta = nodes >= total
+        loc, ok = owner_local_index(
+            jnp.where(in_delta, nodes - total, nodes),
+            jnp.where(in_delta, d_base, base),
+            jnp.where(in_delta, n_delta, n_local), shard_n)
+        gone = ok & node_gone(tomb_bits,
+                              jnp.where(ok & in_delta, shard_n + loc, loc))
+        return device_stage("alive_merge")(
+            lambda part: lax.psum(part, "t"))(gone.astype(jnp.int32)) == 0
+
+    lower_d = _guarded_lower_bound(delta, n_delta, delta_lut)
+    delta_t = delta.T
+
+    def delta_window(targets):
+        @device_stage("delta_merge")
+        def place(at):
+            return lax.psum(at, "t")
+
+        slot = (place(lower_d(targets))[None, :] - DELTA_WINDOW // 2
+                + jnp.arange(DELTA_WINDOW, dtype=jnp.int32)[:, None])
+        loc, ok = owner_local_index(slot, d_base, n_delta, delta_rows)
+        part = jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
+                          fused_gather_planar(delta_t, loc)])
+        ids = device_stage("delta_merge")(
+            lambda part: lax.psum(part, "t"))(part)
+        node = jnp.where((slot >= 0) & (slot < d_total), total + slot, -1)
+        return node.T, [ids[l].T for l in range(N_LIMBS)]
+
+    return {"alive": alive, "delta_window": delta_window}
+
+
 @functools.lru_cache(maxsize=16)
 def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
                     alpha: int, search_nodes: int, max_hops: int,
-                    state_limbs: int = N_LIMBS, weighted: bool = False):
+                    state_limbs: int = N_LIMBS, weighted: bool = False,
+                    delta_rows: int = 0):
     """Compile the table-sharded iterative lookup for one geometry.
 
     Returns a jitted ``fn(sorted_ids, local_lut, block_lut, n_valid,
@@ -411,10 +482,23 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     that holds no row at all, reads a spare row of its own, never the
     one row a clip would send them all to, and is zeroed before the
     collective.
+
+    ``delta_rows`` is part of the geometry like ``shard_n``: the delta
+    slab of each shard of a table under membership CHURN
+    (parallel/churn.py ``ShardedChurnTable.view``; 0 = a table that is
+    built once).  The program then takes the shards' liveness words,
+    deltas, delta row counts and delta LUTs after ``seed``, hands the
+    engine its two churn primitives (:func:`_tp_churn_primitives`) and
+    returns ``expired_peers`` too, one count a ``q``-rank.  With 0 it
+    is, operation for operation, the program it was before.
     """
     q_local = q_total // mesh.shape["q"]
+    n_t = mesh.shape["t"]
+    if delta_rows and not weighted:
+        raise ValueError("a table under churn lies in the weighted layout")
 
     def local(*op):
+        op, churn_op = (op[:-4], op[-4:]) if delta_rows else (op, None)
         if weighted:
             # load-aware layout (ISSUE-17): each shard owns rows
             # [base, base+width) of the global sorted order, carried as
@@ -493,26 +577,37 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
                 lambda part: lax.psum(part, "t"))(g)
             return [g[l] for l in range(limbs)]
 
+        churn = {}
+        if delta_rows:
+            tomb_bits, delta, n_delta, delta_lut = churn_op
+            churn = _tp_churn_primitives(
+                shard_n, delta_rows, n_t, base, n_local, tomb_bits, delta,
+                n_delta[0], delta_lut[0])
         q_index = (lax.axis_index("q").astype(jnp.int32) * q_local
                    + jnp.arange(q_local, dtype=jnp.int32))
         out = _lookup_engine(gather_planar, lower, n, targets_local,
                              q_index, q_total, seed.astype(_U32),
                              k=k, alpha=alpha, search_nodes=search_nodes,
                              max_hops=max_hops, state_limbs=state_limbs,
-                             block_bounds=block_bounds)
+                             block_bounds=block_bounds, **churn)
         # one count a q-rank (t-ranks hold the same search state and
         # cut in the same round)
-        return dict(out, narrow_rounds=out["narrow_rounds"][None])
+        return {name: value[None] if name in per_rank else value
+                for name, value in out.items()}
 
+    per_rank = ("narrow_rounds", "expired_peers")
     in_specs = ((P("t", None), P("t", None), P(), P(), P("t", None),
                  P("q", None), P()) if weighted else
                 (P("t", None), P("t", None), P(), P(), P("q", None), P()))
+    out_specs = {"nodes": P("q", None), "dist": P("q", None, None),
+                 "hops": P("q"), "converged": P("q"), "narrow_rounds": P("q")}
+    if delta_rows:
+        in_specs += (P("t"), P("t", None), P("t"), P("t", None))
+        out_specs["expired_peers"] = P("q")
     fn = jax.shard_map(
         local, mesh=mesh,
         in_specs=in_specs,
-        out_specs={"nodes": P("q", None), "dist": P("q", None, None),
-                   "hops": P("q"), "converged": P("q"),
-                   "narrow_rounds": P("q")},
+        out_specs=out_specs,
         check_vma=False,
     )
     return jax.jit(fn)
@@ -580,8 +675,13 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
                          f"{mesh.shape['q']}")
     a = state.arrays
     weighted = "shard_rows" in a
+    # a table under membership churn (parallel/churn.py) brings its
+    # shards' deltas and liveness words: read off the table, as
+    # simulate_lookups reads a ChurnTable off its first argument
+    delta_rows = (a["delta"].shape[0] // mesh.shape["t"]
+                  if "delta" in a else 0)
     fn = build_tp_lookup(mesh, state.shard_n, Q, k, alpha, search_nodes,
-                         max_hops, state_limbs, weighted)
+                         max_hops, state_limbs, weighted, delta_rows)
     targets = shard_put(mesh, {"targets": _as_operand(targets, np.uint32)},
                         TABLE_AXIS_RULES)["targets"]
     if weighted:
@@ -591,6 +691,8 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
     else:
         args = (a["sorted_ids"], a["local_lut"], a["block_lut"],
                 a["n_valid"], targets, jnp.asarray(seed, jnp.int32))
+    if delta_rows:
+        args += (a["tomb_bits"], a["delta"], a["n_delta"], a["delta_lut"])
     from .. import telemetry
     if not telemetry.get_registry().enabled:
         return fn(*args)

@@ -191,7 +191,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
              for f in sorted(os.listdir(mdir))}
     staged = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage"}
-    assert len(staged) == 19
+    assert len(staged) == 31
     assert {name: s["stage"] for name, s in staged.items()
             if s["value"] == "stage_ms_per"} == {
         "sim_fetch_ids_ms_per_wave": "fetch_ids",
@@ -210,17 +210,32 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
         "churn_compact_ms": "table_compact",
         "churn_block_bounds_ms_per_wave": "block_bounds",
         "churn_merge_ms_per_wave": "merge",
-        "churn_reply_rows_ms_per_wave": "reply_rows"}
+        "churn_reply_rows_ms_per_wave": "reply_rows",
+        "host4churn_fetch_ids_ms_per_wave": "fetch_ids",
+        "host4churn_block_bounds_ms_per_wave": "block_bounds",
+        "host4churn_merge_ms_per_wave": "merge",
+        "host4churn_owner_merge_ms_per_wave": "owner_merge",
+        "host4churn_expire_ms_per_wave": "expire",
+        "host4churn_delta_window_ms_per_wave": "delta_window",
+        "host4churn_route_ms_per_wave": "table_route",
+        "host4churn_apply_ms_per_wave": "table_apply",
+        "host4churn_compact_ms_per_wave": "table_compact",
+        "host4churn_compact_ms": "table_compact",
+        "host4churn_relayout_ms": "table_relayout"}
     # one compaction's time is over the compactions, not over the waves:
     # it does not move with the waves' speed or the window's length
     assert {name for name, s in staged.items() if s.get("per") != "waves"
-            and s["value"] == "stage_ms_per"} == {"churn_compact_ms"}
+            and s["value"] == "stage_ms_per"} == {
+        "churn_compact_ms", "host4churn_compact_ms", "host4churn_relayout_ms"}
+    assert {s["per"] for name, s in staged.items() if s.get("per") != "waves"
+            and s["value"] == "stage_ms_per"} == {"compactions"}
     assert {name for name, s in staged.items()
             if s["value"] == "unstaged_share"} \
-        == {"sim_unstaged_share", "churn_unstaged_share"}
+        == {"sim_unstaged_share", "churn_unstaged_share",
+            "host4churn_unstaged_share"}
     # every stage a metric names is one the program names: those of the
-    # round engine, the tp twin's collective, the churn model's two and
-    # the mutable table's two
+    # round engine, the tp twin's collective, the churn model's two, the
+    # mutable table's two and the sharded mutable table's two more
     from opendht_tpu.core import search
     from opendht_tpu.ops import churn_table
     from opendht_tpu.parallel import sharded
@@ -230,14 +245,20 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
         assert f'device_stage("{name}")' in inspect.getsource(search)
     for name in ("table_apply", "table_compact"):
         assert f'device_stage("{name}")' in inspect.getsource(churn_table)
+    from opendht_tpu.parallel import churn as sharded_churn
+    for name in ("table_route", "table_relayout", "table_apply",
+                 "table_compact"):
+        assert f'device_stage("{name}")' in inspect.getsource(sharded_churn)
     assert {s["stage"] for s in staged.values() if "stage" in s} \
         <= set(STAGES) | {"owner_merge", "expire", "delta_window",
-                          "table_apply", "table_compact"}
+                          "table_apply", "table_compact", "table_route",
+                          "table_relayout"}
     # the one metric that divides by a stage's time and not the window's
     shares = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage_share"}
     assert {n: s["stage"] for n, s in shares.items()} \
-        == {"churn_compact_hbm_share": "table_compact"}
+        == {"churn_compact_hbm_share": "table_compact",
+            "host4churn_compact_hbm_share": "table_compact"}
 
 
 # -- PR 32: sim-10m-churn.wave-65536 ------------------------------------------
@@ -365,3 +386,163 @@ def test_the_churn_book_is_a_plain_set_of_ids():
     np.testing.assert_array_equal(
         live_set.closest_ids(target, 8),
         after[reference.xor_closest(after, target, 8)])
+
+
+# -- PR 34: host4-100m-churn.wave-65536 ----------------------------------------
+
+TP_CHURN_REHEARSAL = {"n_ids": 16384, "wave_targets": 256, "target_sets": 4,
+                      "leave_per_tick": 64, "join_per_tick": 64,
+                      "delta_rows": 128, "warm_ticks": 5,
+                      "schedule_ticks": 200}
+TP_CHURN_REGISTRY_METRICS = {
+    "host4churn_expired_peers_per_wave", "host4churn_tick_ms",
+    "host4churn_record_ms_per_wave", "host4churn_dispatch_ms_per_wave",
+    "host4churn_narrow_rounds_per_wave"}
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_tp_churn_cell_at_toy_size_prints_the_contract_line(manifest, trace):
+    """The cell on four virtual devices: the sharded table built across
+    the mesh, ticked, compacted shard by shard and searched, ends
+    ``correct`` (membership and placement range by range, order, the
+    sampled closest sets, no departed id returned)."""
+    line = run.run_cell("host4-100m-churn.wave-65536", 2 ** 31 + 34034, 1.0,
+                        trace, rehearsal=TP_CHURN_REHEARSAL)
+    line = json.loads(json.dumps(line))
+    assert set(line) == RESULT_KEYS
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0 and line["attempted"] % 256 == 0
+    if trace:
+        assert set(line["metrics"]) == TP_CHURN_REGISTRY_METRICS
+    else:
+        assert set(line["metrics"]) == {"sim_lookups_per_s",
+                                        "sim_wave_p90_ms", "setup_s"}
+    for name, m in line["metrics"].items():
+        assert set(m) == {"value", "unit"} and (
+            m["value"] > 0 or name == "host4churn_narrow_rounds_per_wave")
+
+
+def test_every_metric_of_the_tp_churn_cell_is_in_the_manifest(manifest):
+    cell = "host4-100m-churn.wave-65536"
+    _cell, config, driver, files = run.resolve(cell)
+    assert config["driver"] == "sim_tp_churn" and _cell["chips"] == 4
+    listed = {m["name"] for m in manifest["per_layer"]
+              if cell in m.get("workloads", ())}
+    assert listed == set(files) and len(listed) == 21
+    assert all(m["cells"] == [cell] for m in files.values())
+    for m in manifest["end_to_end"]:
+        if m["name"] != "setup_s":
+            assert m["workloads"][-1] == cell
+    entry = [c for c in manifest["configs"] if c["name"] == config["name"]]
+    assert entry[0]["source"] == config["source"] and \
+        entry[0]["reduced"] == config["reduced"] == ["chips"]
+
+
+def test_tp_churn_check_fails_a_table_that_lost_or_misplaced_a_node(manifest):
+    """Membership, placement and order are checked, not assumed: a shard
+    that lost a departure (a node resurrected), dropped an arrival, or
+    holds its rows out of order is not ``correct``; nor is a window
+    without a compaction."""
+    import jax
+    import jax.numpy as jnp
+    from dhtbench.drivers import sim_tp_churn
+    cell, config, driver, _ = run.resolve("host4-100m-churn.wave-65536")
+    assert driver is sim_tp_churn
+    config = dict(config, sizes={**config["sizes"], **{
+        k: v for k, v in TP_CHURN_REHEARSAL.items() if k in config["sizes"]}})
+    traffic = {**cell["traffic"], **{
+        k: v for k, v in TP_CHURN_REHEARSAL.items() if k in cell["traffic"]}}
+    st = sim_tp_churn.setup(config, traffic, 5, lambda msg: None)
+    try:
+        result = sim_tp_churn.window(st, 0.5)
+        result["values"]["compiles_in_window"] = 0
+        correct, why = sim_tp_churn.check(st, result)
+        assert correct, why
+        v = result["values"]
+        assert v["compactions"] >= 1
+        lut_entries = st.table.view.arrays["local_lut"].shape[1]
+        assert v["least_compact_bytes"] == v["compactions"] * \
+            sim_tp_churn.least_compact_bytes(16384, lut_entries, 4)
+        assert sim_tp_churn.least_compact_bytes(16384, lut_entries, 4) \
+            == 2 * 4096 * 20 + 4 * lut_entries
+        tbl = st.table
+        good = tbl.table
+        assert tbl.n_tomb > 0 and tbl.n_delta > 0
+
+        def place(**leaves):
+            return good._replace(**{
+                name: jax.device_put(jnp.asarray(value),
+                                     getattr(good, name).sharding)
+                for name, value in leaves.items()})
+
+        gone = int(np.asarray(good.dead_pos)[0])     # departed, shard 0
+        words = np.asarray(good.tomb_bits).copy()
+        words[gone >> 5] ^= np.uint32(1 << (gone & 31))
+        n_delta = np.asarray(good.n_delta).copy()
+        n_delta[1] -= 1
+        base = np.asarray(good.base).copy()
+        base[[0, 1]] = base[[1, 0]]
+        for bad, what in ((place(tomb_bits=words), "checksums"),
+                          (place(n_delta=n_delta), "checksums"),
+                          (place(base=base), "ascending")):
+            tbl._table = bad
+            correct, why = sim_tp_churn.check(st, result)
+            assert not correct and what in why, why
+        tbl._table = good
+        assert sim_tp_churn.check(st, result)[0]
+        v["compactions"] = 0
+        correct, why = sim_tp_churn.check(st, result)
+        assert not correct and "compactions" in why
+    finally:
+        sim_tp_churn.close(st)
+
+
+def test_the_index_book_and_its_ids():
+    """``reference_tp_churn``: ids are a function of an index, the same
+    on the host and on the device; the book is indices; the live ids
+    near a target answer for the whole live set."""
+    import jax.numpy as jnp
+    from dhtbench import reference_churn, reference_tp_churn as ref
+    from dhtbench.drivers import sim_tp_churn
+    from opendht_tpu.parallel.global_sort import dest_shard
+    keys = ref.seed_keys(2 ** 31 + 7)
+    index = np.arange(20000, dtype=np.uint32)
+    ids = ref.ids_of(index, keys)
+    np.testing.assert_array_equal(
+        np.asarray(sim_tp_churn.ids_of(jnp.asarray(index), keys)), ids)
+    assert len({r.tobytes() for r in ids}) == 20000
+    assert (ref.seed_keys(3) != keys).any()
+    # the owner, written again: the program's splitter
+    np.testing.assert_array_equal(ref.key_range(ids[:, 0], 4),
+                                  dest_shard(ids[:, 0], 4))
+    counts = np.bincount(ref.key_range(ids[:, 0], 4), minlength=4)
+    assert abs(counts - 5000).max() < 5 * np.sqrt(20000 * 3 / 16)
+    # the book: slots never repeat in a tick, an arrival takes a slot
+    rng = np.random.default_rng(34)
+    slots = ref.make_slots(rng, 20000 - 360, 9, 40)
+    n = 20000 - 360
+    book, left = ref.play(n, slots, 9)
+    live = set(range(n))
+    for t in range(9):
+        assert len(set(slots[t].tolist())) == 40
+        live -= set(left[t].tolist())
+        live |= set(ref.arrivals(n, t, 40).tolist())
+    assert set(book.tolist()) == live and len(live) == n
+    assert set(left.reshape(-1).tolist()) & set(range(n, n + 360))
+    # near a target, the candidates are the whole live set's answer
+    alive = ref.ids_of(book, keys)
+    whole = reference_churn.LiveSet(alive)
+    bits = ref.prefix_bits(n, 8)
+    targets = rng.integers(0, 2 ** 32, size=(16, 5), dtype=np.uint32)
+    found = np.concatenate([alive[:50], ref.ids_of(left[0][:5], keys)])
+    hit = ref.near_buckets(found[:, 0], targets[:, 0], bits)
+    near = reference_churn.LiveSet(alive[hit[alive[:, 0] >> 8]])
+    assert near.index.ids.shape[0] < n
+    assert near.holds(found).tolist() == whole.holds(found).tolist() \
+        == [True] * 50 + [False] * 5
+    for target in targets:
+        np.testing.assert_array_equal(
+            ref.closest_ids(near, target, 8, bits),
+            whole.closest_ids(target, 8))
+    with pytest.raises(RuntimeError):
+        ref.closest_ids(reference_churn.LiveSet(alive[:4]), targets[0], 8, 0)
