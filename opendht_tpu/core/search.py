@@ -376,7 +376,18 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
           at the gather is then its staging copy, and sets its price),
           the finished view where it cannot (the slice would be a
           table-sized copy every round for nothing).  The engine asks
-          for ``limbs`` ∈ {1, ``state_limbs``, 5}.
+          for ``limbs`` ∈ {1, ``state_limbs``, 5}.  THE OPTIONAL COUNT:
+          a closure may return ``(planes, one_pass)`` instead —
+          ``one_pass`` an int32 0 / 1 of its own meaning (the tp twin's:
+          this shard served the index in ONE pass over its lane window,
+          ``parallel/sharded.py window_gather``).  The engine then
+          carries the sum of the IN-LOOP round gathers' reports through
+          its loops and the survivors' sub-waves, as it carries
+          ``expired_peers``, and returns it as ``window_rounds``; the
+          bootstrap's and the final fetch's reports are dropped.  A
+          closure that returns bare planes gets the program it always
+          got, operation for operation (tests/test_sharded.py pins the
+          lowered text's hash).
       lower(flat [M, 5]) -> [M] int32 global lower-bound positions.
       block_bounds(t0, prefix_len) -> (lo, ub) prefix-block edges
           (optional third primitive): t0 = targets' first limb
@@ -547,9 +558,11 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
     def fetch_ids(rows, limbs):
         """Stage ``fetch_ids``: limb planes of table rows — the round's
-        one fused gather, and the final id fetch."""
-        return device_stage("fetch_ids")(
+        one fused gather, and the final id fetch — and the closure's
+        report on how it served them (None from one that gives none)."""
+        got = device_stage("fetch_ids")(
             lambda r: gather_planar(r, limbs))(rows)
+        return got if isinstance(got, tuple) else (got, None)
 
     def reply_gather(tgt, pt, qidx, x_rows, round_no, x_d0=None):
         """Simulated answers of the queried nodes per search.
@@ -576,7 +589,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             # block_mode="exact" keeps the full-width gathered path.
             t0 = tgt[:, 0][None, :]              # [1, W] against [P, W]
             if x_d0 is None:
-                x_d0 = fetch_ids(x_rows, 1)[0] ^ t0
+                x_d0 = fetch_ids(x_rows, 1)[0][0] ^ t0
 
             def edges(x_d0):
                 b = clz32(x_d0)                  # clz32(0) == 32 by contract
@@ -594,7 +607,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                     prefix_len)
 
             lo, ub = device_stage("block_bounds")(edges)(
-                fetch_ids(x_rows, N_LIMBS))          # full ids: exact cb
+                fetch_ids(x_rows, N_LIMBS)[0])       # full ids: exact cb
         rows = device_stage("reply_rows")(functools.partial(
             _reply_rows, n=n, k=k, R=R, q_total=q_total, seed_u=seed_u))(
             pt, qidx, x_rows, round_no, lo, ub)
@@ -611,8 +624,9 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         """Fetch the replies' ids (stage ``fetch_ids``) and insert them
         (stage ``merge``); under CHURN the delta rows ``dw`` around the
         target join the replies of a lookup that is ``near`` it (stage
-        ``delta_window``), DW more slot-major rows."""
-        new_l = fetch_ids(new_rows, NL)                         # NL×[P·k,W]
+        ``delta_window``), DW more slot-major rows.  Returns the new
+        candidate state and the gather's report (:func:`fetch_ids`)."""
+        new_l, one_pass = fetch_ids(new_rows, NL)               # NL×[P·k,W]
         if dw is not None:
             @device_stage("delta_window")
             def with_delta(new_rows, new_l, dw, near):
@@ -623,7 +637,8 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                          for l in range(NL)])
 
             new_rows, new_l = with_delta(new_rows, new_l, dw, near)
-        return insert(tgt, cand_node, cand_l, queried, new_rows, new_l)
+        return (insert(tgt, cand_node, cand_l, queried, new_rows, new_l),
+                one_pass)
 
     @device_stage("merge")
     def insert(tgt, cand_node, cand_l, queried, new_rows, new_l):
@@ -686,8 +701,17 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     first, near = reply_gather(targets, pos_t_full, q_index, boot,
                                jnp.int32(0))
     dw_full = None if dwin is None else (dwin[0], dwin[1][:NL])
-    cand_node, cand_l, queried = merge(targets, cand_node, cand_l, queried,
-                                       first, dw_full, near)
+    (cand_node, cand_l, queried), one_pass = merge(
+        targets, cand_node, cand_l, queried, first, dw_full, near)
+    # the counts a wave carries through its loops and its survivors'
+    # sub-waves, each only where it exists: the requests that found
+    # their peer gone (CHURN), and the round gathers the closure served
+    # in one pass (a closure that reports; the bootstrap's is not one)
+    counts = {}
+    if churn:
+        counts["expired_peers"] = jnp.int32(0)
+    if one_pass is not None:
+        counts["window_rounds"] = jnp.int32(0)
 
     @device_stage("converge")
     def synced(cand_node, queried):
@@ -769,20 +793,24 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
     def make_body(tgt, pt, qidx, dw):
         def body(state):
-            cand_node, cand_l, queried, hops, done, round_no, *gone = state
+            cand_node, cand_l, queried, hops, done, round_no, counts = state
             sel, x_rows, x_d0, queried = select(cand_node, cand_l[0],
                                                 queried, done)
             if churn:
                 x_rows, queried, gone_now = device_stage("expire")(expire)(
                     cand_node, x_rows, queried)
-                gone = [gone[0] + gone_now]
+                counts = dict(counts, expired_peers=counts["expired_peers"]
+                              + gone_now)
             new_rows, near = reply_gather(tgt, pt, qidx, x_rows,
                                           round_no + 1, x_d0)
-            cand_node, cand_l, queried = merge(
+            (cand_node, cand_l, queried), one_pass = merge(
                 tgt, cand_node, cand_l, queried, new_rows, dw, near)
+            if one_pass is not None:
+                counts = dict(counts, window_rounds=counts["window_rounds"]
+                              + one_pass)
             hops, done = converge(cand_node, queried, sel, hops, done)
             return (cand_node, cand_l, queried, hops, done, round_no + 1,
-                    *gone)
+                    counts)
         return body
 
     def live_over(cap):
@@ -824,15 +852,15 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         which the same rule applies.  ``C`` = 0 (a wave under the
         threshold): the loop runs until nobody is live and is the last.
         Returns what the outputs read (:func:`head` and ``hops``), the
-        round this width's loop ended in and the round the last one
-        did."""
+        round this width's loop ended in, the round the last one did
+        and the wave's counts."""
         C = width // NARROW_DIVISOR if width >= NARROW_MIN_WAVE else 0
         (cand_node, cand_l, queried, hops, done, round_no,
-         *gone) = lax.while_loop(
+         counts) = lax.while_loop(
             live_over(C), make_body(tgt, pt, qidx, dw), state)
         outs = (*head(cand_node, cand_l, queried), hops)
         if not C:
-            return outs, round_no, round_no, gone
+            return outs, round_no, round_no, counts
 
         @device_stage("pack")
         def pack(done, wide):
@@ -853,15 +881,14 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
         rows, filled, (tgt, pt, qidx, dw, *sub) = pack(
             done, (tgt, pt, qidx, dw, cand_node, cand_l, queried, hops))
-        sub_outs, _, last_round, gone = run(
-            C, tgt, pt, qidx, dw, (*sub, filled, round_no, *gone))
-        return unpack(rows, outs, sub_outs), round_no, last_round, gone
+        sub_outs, _, last_round, counts = run(
+            C, tgt, pt, qidx, dw, (*sub, filled, round_no, counts))
+        return unpack(rows, outs, sub_outs), round_no, last_round, counts
 
-    (nodes_k, dist_k, converged, hops), cut_round, last_round, gone = run(
+    (nodes_k, dist_k, converged, hops), cut_round, last_round, counts = run(
         Q, targets, pos_t_full, q_index, dw_full,
         (cand_node, cand_l, queried, jnp.zeros((Q,), jnp.int32),
-         synced(cand_node, queried) | empty, jnp.int32(0),
-         *([jnp.int32(0)] if churn else [])))
+         synced(cand_node, queried) | empty, jnp.int32(0), counts))
 
     if NL == N_LIMBS:
         dist = jnp.stack(dist_k, axis=-1)
@@ -872,7 +899,7 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         # the sorted table, and in that order this once-a-wave fetch
         # measured 1.75 ms on each of four table shards against 8.90
         # slot-major (one chip: 7.60 against 6.81; PERF.md §6, PR 27)
-        id_l = fetch_ids(nodes_k, N_LIMBS)
+        id_l, _ = fetch_ids(nodes_k, N_LIMBS)
         if dwin is not None:
             # CHURN: a delta node's id is in the window it came from
             @device_stage("delta_window")
@@ -889,16 +916,14 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
             [jnp.where(nodes_k >= 0, id_l[l] ^ targets[:, l:l + 1],
                        jnp.uint32(0xFFFFFFFF)) for l in range(N_LIMBS)],
             axis=-1)
-    out = {
+    return {
         "nodes": nodes_k,
         "dist": dist,
         "hops": hops,
         "converged": converged & ~empty,
         "narrow_rounds": last_round - cut_round,
+        **counts,
     }
-    if churn:
-        out["expired_peers"] = gone[0]
-    return out
 
 
 @functools.partial(
@@ -1079,7 +1104,9 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     time goes is read off a device trace by the stages
     ``_lookup_engine`` names), the wave-width / hops distributions,
     and ``dht_search_narrow_rounds``: how many of the wave's rounds ran
-    under its full width (the engine's own count, 0 = it never cut).
+    under its full width (the engine's own count, 0 = it never cut);
+    from the tp twin also ``dht_search_window_rounds``: how many of its
+    loop rounds every shard gathered in one pass over its lane window.
     Shared by the single-device engine and the tp-sharded twin
     (``mode="tp"``, parallel/sharded.py); both time the whole of this
     call as ``dht_search_record_seconds``.
@@ -1100,13 +1127,22 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     reg.histogram("dht_search_wave_seconds", mode=mode).observe(elapsed_s)
     reg.histogram("dht_search_wave_width", mode=mode).observe(wave_width)
     # ONE fetch for all: the counts ride the copy of ``hops``
-    hops, narrow, expired = jax.device_get(
-        (out["hops"], out["narrow_rounds"], out.get("expired_peers")))
+    hops, narrow, expired, windowed = jax.device_get(
+        (out["hops"], out["narrow_rounds"], out.get("expired_peers"),
+         out.get("window_rounds")))
     if expired is not None:
         # CHURN: the wave's queried peers that were gone (the engine's
         # own count; on a frozen table the series does not exist)
         reg.histogram("dht_search_expired_peers", mode=mode).observe(
             int(np.sum(expired)))
+    if windowed is not None:
+        # the tp twin's: the wave's in-loop round gathers that every
+        # shard served in one pass over its lane window (one value a
+        # q-rank, each the least over its t-ranks; parallel/sharded.py
+        # window_gather) — the wave's loop rounds where its lanes
+        # grouped by home shard, 0 where they pile on one
+        reg.histogram("dht_search_window_rounds", mode=mode).observe(
+            int(np.min(windowed)))
     reg.histogram("dht_search_hops", mode=mode).observe_many(hops)
     # one value a wave: on a mesh the slowest q-rank's (each cuts when
     # its own survivors fit)

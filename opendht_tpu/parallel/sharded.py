@@ -351,10 +351,19 @@ def owner_local_index(rows, base, n_owned, capacity: int):
     modulo the shard's ``capacity``.  In range by construction, and
     scattered over the whole shard.
 
+    Since the gather runs over a lane window (:func:`window_gather`)
+    ``rows`` is as a rule one window of a round's index, and the lanes
+    that are not owned are the window's slack — the sixteenth it is cut
+    wider than a shard's share, the lookups of a neighbouring home that
+    fall inside it, the lanes of lookups that are done — and, in a
+    bootstrap round, a skewed wave or a pass beyond the first, whatever
+    else the pass covers: up to three lanes in four again.
+
     Why not leave ``rows - base`` to the gather's clip: the clip sends
     every such lane to row 0 or to the last row, three lanes in four at
-    ``t`` = 4, and a gather out of HBM whose lanes crowd into few tiles
-    of the view pays 15 ns a row where a scattered index pays 9.7
+    ``t`` = 4 where the index is a whole wave, and a gather out of HBM
+    whose lanes crowd into few tiles of the view pays 15 ns a row where
+    a scattered index pays 9.7
     (PERF.md §7 (1), the probe of PR 33: a 25M-row 2-limb view,
     1,572,864 lanes, a quarter of them owned).  It is the crowding that
     costs, not the one row: the lane's own position as its spare —
@@ -370,6 +379,116 @@ def owner_local_index(rows, base, n_owned, capacity: int):
     lane = lax.iota(_U32, rows.size).reshape(rows.shape)
     spare = lane * _U32(SPARE_STRIDE) % _U32(capacity)
     return jnp.where(ok, loc, spare.astype(jnp.int32)), ok
+
+
+# A wave narrower than this keeps its gathers at full width (the toy
+# waves of the tests and rehearsals, and the 1,024-lane sub-wave a cell's
+# wave ends in: an index of 24,576 rows, a few tenths of a millisecond).
+WINDOW_MIN_LANES = 2048
+_LANE_TILE = 128
+
+
+def window_width(lanes: int, n_t: int) -> int:
+    """The lane window of a shard's gather (:func:`window_gather`), a
+    static function of the lane count alone: a shard's share of the
+    lanes and a sixteenth more, rounded up to whole 128-lane tiles —
+    17,408 of 65,536 lanes at ``t`` = 4, 2,176 of 8,192 — and ``lanes``
+    itself where that is not smaller or the wave is under
+    :data:`WINDOW_MIN_LANES`: the gather is then the one full-width
+    pass it always was.  The sixteenth holds what a grouped uniform
+    wave needs over its share: the up to 127 lanes an aligned start
+    gives away, and the spread of a home's count (16,384 ± 111 at
+    65,536 targets: 9σ)."""
+    if lanes < WINDOW_MIN_LANES:
+        return lanes
+    width = -(-lanes * 17 // (n_t * 16 * _LANE_TILE)) * _LANE_TILE
+    return min(width, lanes)
+
+
+def lane_window(lane_any, width: int):
+    """``(start, passes)`` of the windows that cover the set lanes of
+    ``lane_any`` [lanes]: the first set lane rounded down to a 128-lane
+    tile, and ``ceil(span / width)`` windows of ``width`` lanes from
+    there, ``span`` reaching to the last set lane; no lane set, no pass.
+    Pass ``p`` serves lanes ``[start + p·width, start + (p+1)·width)``,
+    so every set lane is served by exactly one."""
+    lanes = lane_any.shape[0]
+    lane = jnp.arange(lanes, dtype=jnp.int32)
+    first = jnp.min(jnp.where(lane_any, lane, lanes))
+    last = jnp.max(jnp.where(lane_any, lane, -1))
+    start = first // _LANE_TILE * _LANE_TILE
+    span = jnp.maximum(last + 1 - start, 0)
+    return start, (span + (width - 1)) // width
+
+
+def window_gather(view, rows, base, n_owned, capacity: int, limbs: int,
+                  width: int):
+    """One shard's part of a distributed row fetch: ``limbs`` planes
+    ``[limbs, *rows.shape]`` of the global ``rows`` it owns (``base <=
+    row < base + n_owned``), 0 in every other lane, gathered over a
+    LANE WINDOW — and whether ONE pass did it (int32 0 / 1).
+
+    A gathered row costs HBM's 9.6 ns whether its lane is owned or
+    thrown away (PERF.md §7 (1)), so the cost of a round is the number
+    of indices ISSUED, and a shard that issues a whole wave's index to
+    keep a quarter of it pays four times its share.  Which lanes it owns
+    is nearly a property of the lookup: from loop round 1 on every reply
+    row of a lookup lies in the shard whose key range holds its target
+    (the reply model answers from the block that shares one more bit
+    with the TARGET than the queried peer does, core/search.py
+    ``_reply_rows``; only a fallback window that straddles a shard edge
+    leaves it).  ``build_tp_lookup`` therefore groups a wave's lanes by
+    home shard, and the lanes a shard owns are then one run of about
+    ``lanes / t``.  The rule here knows nothing of that; it reads the
+    index, as :func:`owner_local_index` does:
+
+    - ``ok`` over the whole index (compares: no memory), the lanes (the
+      MINOR axis of ``rows``) with any owned row, their first and last:
+      :func:`lane_window`;
+    - as many passes of ONE executable as cover them: the index's
+      ``width`` lanes from a tile-aligned offset, ``owner_local_index``
+      on those, the gather, the mask, written into a zeroed
+      ``[limbs, *rows.shape]``.  The last window is held inside the
+      index, so it may take lanes of the one before again, to the same
+      values.
+
+    One pass is the grouped case.  A bootstrap round (a random peer's
+    block: two shards a lookup), a skewed or clustered wave, a wave
+    nobody grouped take up to ``t`` passes — a full-width gather's
+    indices and a sixteenth — and a shard none of whose rows is asked
+    for takes none.  The pass count is this shard's own: keep every
+    collective OUTSIDE (the caller's ``psum`` takes the finished
+    planes).  With ``width`` = the lane count (:func:`window_width`:
+    small waves, and an index whose minor axis is not the wave — the
+    final id fetch is lookup-major ``[W, k]``) it is the full-width
+    gather, operation for operation, and one pass by definition.
+    """
+    def part(view, rows):
+        loc, ok = owner_local_index(rows, base, n_owned, capacity)
+        return jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
+                          fused_gather_planar(view, loc, limbs)])
+
+    lanes, axis = rows.shape[-1], rows.ndim - 1
+    if width >= lanes:
+        return part(view, rows), jnp.int32(1)
+    # a view that is sliced at the gather (ops.sorted_table
+    # .loop_gather_view) is sliced once for all passes, where the
+    # full-width gather slices it: in the caller's loop body, not in
+    # the pass loop's
+    view = view[:limbs]
+    _, ok = owner_local_index(rows, base, n_owned, capacity)
+    start, passes = lane_window(jnp.any(ok, axis=tuple(range(axis))), width)
+
+    def one_pass(p, planes):
+        at = jnp.minimum(start + p * width, lanes - width)
+        return lax.dynamic_update_slice_in_dim(
+            planes,
+            part(view, lax.dynamic_slice_in_dim(rows, at, width, axis=axis)),
+            at, axis=axis + 1)
+
+    planes = lax.fori_loop(0, passes, one_pass,
+                           jnp.zeros((limbs,) + rows.shape, _U32))
+    return planes, (passes <= 1).astype(jnp.int32)
 
 
 def shard_offset(widths, n_t: int):
@@ -468,8 +587,8 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     is the global count, so the values are bit-identical to the
     per-hop psum they replace).
 
-    The collective's shape is the gather's: ``[NL, α·k, W]`` in a loop
-    round (``[NL, α·k, C]`` once the wave has cut to ``C`` lanes),
+    The collective's shape is the round's index: ``[NL, α·k, W]`` in a
+    loop round (``[NL, α·k, C]`` once the wave has cut to ``C`` lanes),
     ``[NL, k, W]`` in the bootstrap round, whose ONE peer answers with k
     rows (core/search.py ``_lookup_engine``, BOOTSTRAP SHAPE; lut mode's
     bootstrap also fetches that peer's top limb, a ``[1, 1, W]`` psum),
@@ -477,11 +596,33 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     is decided once, before the engine runs
     (``ops.sorted_table.loop_gather_view``): a shard whose limb view is
     too large for on-chip memory is not sliced inside a loop body.
-    Every gather is handed an index that lies in the shard, lane for
-    lane (:func:`owner_local_index`): a lane another shard owns, or
-    that holds no row at all, reads a spare row of its own, never the
-    one row a clip would send them all to, and is zeroed before the
-    collective.
+
+    THE HOME-LANE WINDOW (PR 35).  The row fetch is NOT full-width: a
+    gathered row costs what an issued index costs, owned or thrown
+    away, so a shard issues the lanes it owns and little more.  A wave
+    of ``WINDOW_MIN_LANES`` lookups or more is grouped by the HOME shard
+    of its targets once, before the engine (stage ``group``: one
+    ``all_gather`` of ``t`` words, one stable sort on a ``log2 t``-bit
+    key; the outputs go back to the caller's order behind the engine),
+    and every gather whose index has the lookups on its minor axis runs
+    over a lane window of ``window_width(lanes, t)`` — 17,408 of 65,536
+    lanes at ``t`` = 4 — in as many passes of one executable as cover
+    the lanes the shard owns (:func:`window_gather`): one in a loop
+    round of a grouped wave, two to ``t`` in the bootstrap round (a
+    random peer's block spans two shards), for a lookup whose fallback
+    window straddles a shard edge, or on a skewed wave; ``t`` passes
+    are a full-width gather and a sixteenth.  The pass loop holds no
+    collective: the round's ``psum`` takes the finished planes.  Inside
+    a window every lane reads a row of the shard
+    (:func:`owner_local_index`): a lane that is not owned reads a spare
+    row of its own, never the one row a clip would send them all to,
+    and is zeroed before the collective.  The lookup-major final fetch
+    and the churn primitives' owner reads keep one full-width pass.
+    The program also returns ``window_rounds``, one count a ``q``-rank:
+    the wave's in-loop round gathers that EVERY shard served in one
+    pass (the engine sums each shard's report, one ``pmin`` over ``t``
+    once a wave) — the wave's loop rounds where the grouping holds.
+    Every other output bit is the full-width program's.
 
     ``delta_rows`` is part of the geometry like ``shard_n``: the delta
     slab of each shard of a table under membership CHURN
@@ -565,17 +706,21 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             # Ownership: weighted shards own exactly n_local rows (the
             # [b_i, b_{i+1}) ranges partition the valid prefix); the
             # uniform test keeps the static width, equivalent for valid
-            # rows.  What a lane that is not owned reads is
+            # rows.  The shard gathers over the lane window that holds
+            # the lanes it owns, in as many passes as cover them
+            # (window_gather: one where the wave is grouped by home,
+            # below), and says whether one did — the engine's optional
+            # count.  What a lane that is not owned reads is
             # owner_local_index's one rule, whatever the layout or the
             # limb count.
-            loc, ok = owner_local_index(
-                rows, base, n_local if weighted else shard_n, shard_n)
-            g = jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
-                           fused_gather_planar(views[limbs], loc, limbs)])
-            # the round's one collective, a device stage of its own
+            g, one_pass = window_gather(
+                views[limbs], rows, base, n_local if weighted else shard_n,
+                shard_n, limbs, window_width(rows.shape[-1], n_t))
+            # the round's one collective, a device stage of its own —
+            # behind the passes, whose number is each shard's own
             g = device_stage("owner_merge")(
                 lambda part: lax.psum(part, "t"))(g)
-            return [g[l] for l in range(limbs)]
+            return [g[l] for l in range(limbs)], one_pass
 
         churn = {}
         if delta_rows:
@@ -585,22 +730,72 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
                 n_delta[0], delta_lut[0])
         q_index = (lax.axis_index("q").astype(jnp.int32) * q_local
                    + jnp.arange(q_local, dtype=jnp.int32))
+        grouped = window_width(q_local, n_t) < q_local
+        if grouped:
+            # GROUP THE WAVE BY HOME SHARD, once a wave: a lookup's home
+            # is the shard whose key range holds its target — read off
+            # the shards' first rows' top limbs, one all_gather of t
+            # words — and from loop round 1 on every reply row of a
+            # lookup lies there (window_gather), so with the lanes of a
+            # home side by side the lanes a shard owns are one run of
+            # about q_local / t, and its gather covers them in one
+            # window.  A stable partition on a log2(t)-bit key that
+            # carries the operands (never a sort by the target: a sorted
+            # index is dearer out of HBM, PERF.md §7 (1)).  A hint
+            # only: a home that is off (shards that share a top limb, an
+            # empty shard) costs passes, never an answer.  The reply
+            # hash is keyed by the GLOBAL q_index, which travels with
+            # its lane; live counts and cuts are sums over lanes; pack
+            # keeps lane order, so a narrow sub-wave stays grouped; and
+            # the outputs are put back in the caller's order below.
+            @device_stage("group")
+            def group(first_row, targets, q_index):
+                first = lax.all_gather(
+                    jnp.where(n_local > 0, first_row, _U32(0xFFFFFFFF)), "t")
+                home = sum((targets[:, 0] >= first[s]).astype(jnp.int32)
+                           for s in range(1, n_t))
+                _, q_index, *limbs = lax.sort(
+                    (home, q_index) + tuple(targets[:, l]
+                                            for l in range(N_LIMBS)),
+                    num_keys=1, is_stable=True)
+                return jnp.stack(limbs, axis=1), q_index
+
+            targets_local, q_index = group(sorted_shard[0, 0], targets_local,
+                                           q_index)
         out = _lookup_engine(gather_planar, lower, n, targets_local,
                              q_index, q_total, seed.astype(_U32),
                              k=k, alpha=alpha, search_nodes=search_nodes,
                              max_hops=max_hops, state_limbs=state_limbs,
                              block_bounds=block_bounds, **churn)
+        if grouped:
+            @device_stage("group")
+            def ungroup(q_index, per_lookup):
+                # lane i holds the lookup of the caller's lane
+                # q_index[i] - q_index.min(): the inverse of that
+                # permutation by one more sort, and a gather each
+                back = lax.sort((q_index, jnp.arange(q_local,
+                                                     dtype=jnp.int32)),
+                                num_keys=1)[1]
+                return jax.tree.map(
+                    lambda a: jnp.take(a, back, axis=0), per_lookup)
+
+            out.update(ungroup(q_index, {name: out[name] for name in
+                                         ("nodes", "dist", "hops",
+                                          "converged")}))
+        # the wave's in-loop gathers that EVERY shard served in one pass
+        out["window_rounds"] = lax.pmin(out["window_rounds"], "t")
         # one count a q-rank (t-ranks hold the same search state and
         # cut in the same round)
         return {name: value[None] if name in per_rank else value
                 for name, value in out.items()}
 
-    per_rank = ("narrow_rounds", "expired_peers")
+    per_rank = ("narrow_rounds", "expired_peers", "window_rounds")
     in_specs = ((P("t", None), P("t", None), P(), P(), P("t", None),
                  P("q", None), P()) if weighted else
                 (P("t", None), P("t", None), P(), P(), P("q", None), P()))
     out_specs = {"nodes": P("q", None), "dist": P("q", None, None),
-                 "hops": P("q"), "converged": P("q"), "narrow_rounds": P("q")}
+                 "hops": P("q"), "converged": P("q"), "narrow_rounds": P("q"),
+                 "window_rounds": P("q")}
     if delta_rows:
         in_specs += (P("t"), P("t", None), P("t"), P("t", None))
         out_specs["expired_peers"] = P("q")
@@ -641,14 +836,21 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
       global block LUT — ZERO collectives (see
       :func:`build_tp_lookup`);
     - row fetch (per hop): owner-shard gather + ONE psum — the round's
-      only in-loop collective, O(queries·k) bytes, never O(table).
+      only in-loop collective, O(queries·k) bytes, never O(table).  A
+      shard gathers only the lane window that holds the lookups whose
+      targets live in its key range (a wave is grouped by home shard
+      first; :func:`build_tp_lookup`, THE HOME-LANE WINDOW): about a
+      ``t``-th of the round's indices and a sixteenth, not all of them.
 
     Search state is sharded over ``q`` and replicated over ``t``
     (deterministic identical compute per t-rank, like the merge
     re-sort in :func:`sharded_window_lookup`).  Results are
     BIT-IDENTICAL to :func:`~opendht_tpu.core.search.simulate_lookups`
-    on the same table (the reply hash is seeded by global query
-    identity) — asserted in tests/test_sharded.py.
+    on the same table, in the caller's order (the reply hash is seeded
+    by global query identity, which travels with a lookup's lane) —
+    asserted in tests/test_sharded.py.  The result also carries
+    ``window_rounds`` [q]: the wave's loop rounds in which every shard
+    gathered in one pass (``dht_search_window_rounds{mode="tp"}``).
 
     Callers serving a stable table should pass ``state=`` from
     ``sharded_global_sort`` or

@@ -92,7 +92,8 @@ def test_sim_cell_at_toy_size_reports_every_registry_metric(manifest, trace):  #
 # mode="tp", and the build span its driver reads in set-up
 HOST4_REGISTRY_METRICS = {"host4_record_ms_per_wave",
                           "host4_dispatch_ms_per_wave", "host4_table_build_s",
-                          "host4_narrow_rounds_per_wave"}
+                          "host4_narrow_rounds_per_wave",
+                          "host4_window_rounds_per_wave"}
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -397,7 +398,8 @@ TP_CHURN_REHEARSAL = {"n_ids": 16384, "wave_targets": 256, "target_sets": 4,
 TP_CHURN_REGISTRY_METRICS = {
     "host4churn_expired_peers_per_wave", "host4churn_tick_ms",
     "host4churn_record_ms_per_wave", "host4churn_dispatch_ms_per_wave",
-    "host4churn_narrow_rounds_per_wave"}
+    "host4churn_narrow_rounds_per_wave",
+    "host4churn_window_rounds_per_wave"}
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -428,7 +430,7 @@ def test_every_metric_of_the_tp_churn_cell_is_in_the_manifest(manifest):
     assert config["driver"] == "sim_tp_churn" and _cell["chips"] == 4
     listed = {m["name"] for m in manifest["per_layer"]
               if cell in m.get("workloads", ())}
-    assert listed == set(files) and len(listed) == 21
+    assert listed == set(files) and len(listed) == 22
     assert all(m["cells"] == [cell] for m in files.values())
     for m in manifest["end_to_end"]:
         if m["name"] != "setup_s":

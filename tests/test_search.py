@@ -523,14 +523,19 @@ def _walk_equations(jaxpr, in_loop=False):
                     yield from _walk_equations(sub, inner)
 
 
-def _assert_engine_shape(jaxpr, table_rows, W, k, alpha, staged):
+def _assert_engine_shape(jaxpr, table_rows, W, k, alpha, staged,
+                         window=None, final_fetch=True):
     """Where the table's limb view fits on-chip memory (``staged``) every
     loop body slices it next to its gather — the slice is the gather's
     staging copy — and where it cannot, no ``slice`` / ``dynamic_slice``
     inside a loop body has a table-sized operand
     (``ops.sorted_table.loop_gather_view``).  Either way no gather
     outside the loops issues more than k·W indices (the bootstrap's
-    merge, the final id fetch); the loop's is α·k·W."""
+    merge, the final id fetch); the loop's is α·k·W — or, in the tp
+    twin, α·k·``window``: a shard gathers over its lane window, in a
+    pass loop of its own (``parallel.sharded.window_gather``), and only
+    the lookup-major final fetch is left outside every loop, which a
+    5-limb state does not need (``final_fetch``)."""
     widest = {True: 0, False: 0}
     sliced_in_loop = 0
     for eqn, in_loop in _walk_equations(jaxpr):
@@ -541,8 +546,9 @@ def _assert_engine_shape(jaxpr, table_rows, W, k, alpha, staged):
             n_idx = int(np.prod(eqn.invars[1].aval.shape[:-1]))
             widest[in_loop] = max(widest[in_loop], n_idx)
     assert (sliced_in_loop > 0) == staged
-    assert widest[True] == alpha * k * W
-    assert widest[False] == k * W
+    assert widest[True] == alpha * k * (window or W)
+    # (without it the bootstrap's two LUT edge reads are the widest)
+    assert widest[False] == (k * W if final_fetch or not window else 2 * W)
 
 
 # 10M rows: the 2-limb view is 80 MB and is staged; a 25,060,864-row
@@ -579,7 +585,7 @@ def test_the_loop_slices_a_view_that_fits_in_the_tp_twin(
     ``sharded_global_sort`` returns it)."""
     import jax
     from opendht_tpu.parallel import make_mesh
-    from opendht_tpu.parallel.sharded import build_tp_lookup
+    from opendht_tpu.parallel.sharded import build_tp_lookup, window_width
 
     W, k, alpha = 65536, 8, 3
     fn = build_tp_lookup(make_mesh(4, q=1, t=4), rows, W, k, alpha, 14, 48,
@@ -590,7 +596,9 @@ def test_the_loop_slices_a_view_that_fits_in_the_tp_twin(
         A((4 * rows, 5), u32), A((4, (1 << 22) + 1), i32),
         A(((1 << 24) + 1,), i32), A((), i32), A((4, 2), i32),
         A((W, 5), u32), A((), i32))
-    _assert_engine_shape(jaxpr.jaxpr, rows, W, k, alpha, staged)
+    assert window_width(W, 4) == 17408
+    _assert_engine_shape(jaxpr.jaxpr, rows, W, k, alpha, staged,
+                         window=17408, final_fetch=state_limbs == 2)
 
 
 @pytest.mark.parametrize("width, alpha", [

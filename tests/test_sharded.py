@@ -613,3 +613,261 @@ def test_sharded_maintenance_sweep_padded_table(mesh):
     for a, b, name in zip(got, ref, ("counts", "last", "stale", "targets")):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
                                       err_msg=name)
+
+
+# -- PR 35: the home-lane window (parallel/sharded.py window_gather) ----------
+# A wave of WINDOW_MIN_LANES lookups or more is grouped by the home shard
+# of its targets, and a shard gathers over one lane window of a
+# sixteenth more than its share, in as many passes as cover the lanes it
+# owns.  Every case below runs with the window engaged (Wh < W).
+
+WINDOW_Q = 2048
+WINDOW_KW = dict(seed=35, alpha=3, state_limbs=2)
+
+
+@pytest.fixture(scope="module")
+def window_network():
+    """16,384 uniform ids: as they come, sorted on one device, and as the
+    key-range state of ``sharded_global_sort`` on a t=4 mesh."""
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    ids = jax.random.bits(jax.random.PRNGKey(3500), (16384, 5),
+                          dtype=jnp.uint32)
+    sorted_ids, _, n_valid = sort_table(ids)
+    mesh = make_mesh(4, q=1, t=4)
+    return (np.asarray(ids), sorted_ids, n_valid, mesh,
+            sharded_global_sort(mesh, np.asarray(ids)))
+
+
+def _uniform_targets(seed, q=WINDOW_Q):
+    return np.array(jax.random.bits(jax.random.PRNGKey(seed), (q, 5),
+                                    dtype=jnp.uint32))
+
+
+def _assert_equals_one_chip(out, sorted_ids, n_valid, targets):
+    ref = simulate_lookups(sorted_ids, n_valid, jnp.asarray(targets),
+                           **WINDOW_KW)
+    assert np.asarray(ref["converged"]).all()
+    for key in ("nodes", "dist", "hops", "converged"):
+        np.testing.assert_array_equal(np.asarray(out[key]),
+                                      np.asarray(ref[key]), err_msg=key)
+    return ref
+
+
+def test_window_width_is_a_function_of_the_lane_count():
+    from opendht_tpu.parallel.sharded import WINDOW_MIN_LANES, window_width
+    assert window_width(65536, 4) == 17408 and window_width(8192, 4) == 2176
+    assert window_width(WINDOW_Q, 4) == 640 and WINDOW_Q == WINDOW_MIN_LANES
+    # small waves, one shard, and a minor axis that is not the wave (the
+    # lookup-major final fetch [W, k]): one full-width pass
+    for lanes, n_t in ((1024, 4), (256, 4), (8, 4), (65536, 1), (2048, 1)):
+        assert window_width(lanes, n_t) == lanes
+    assert window_width(65536, 2) == 34816 and window_width(65536, 8) == 8704
+    for lanes in (2048, 4096, 8192, 65536):
+        for n_t in (2, 4, 8):
+            w = window_width(lanes, n_t)
+            assert w % 128 == 0 and lanes / n_t < w < lanes
+
+
+@pytest.mark.parametrize("layout", ["key_range", "row_split", "q2"])
+def test_tp_simulate_equals_one_chip_over_lane_windows(window_network,
+                                                       layout):
+    """(a) ``tp_simulate_lookups`` with the window engaged equals
+    ``simulate_lookups`` bit for bit, in the caller's order: over the
+    key-range shards of ``sharded_global_sort``, over the uniform row
+    split of a host-sorted table of 15,000 rows (shard edges on no key
+    prefix, the last shard part padding), and with ``q`` = 2 (each
+    q-rank groups its own 2,048 lanes)."""
+    from opendht_tpu.parallel.sharded import window_width
+    ids, sorted_ids, n_valid, mesh, state = window_network
+    targets = _uniform_targets(3501)
+    assert window_width(WINDOW_Q, 4) < WINDOW_Q
+    if layout == "key_range":
+        out = tp_simulate_lookups(mesh, targets=targets, state=state,
+                                  **WINDOW_KW)
+    elif layout == "row_split":
+        sorted_ids, _, n_valid = sort_table(jnp.asarray(ids[:15000]))
+        out = tp_simulate_lookups(mesh, np.asarray(sorted_ids), n_valid,
+                                  targets, **WINDOW_KW)
+    else:
+        mesh2 = make_mesh(8, q=2, t=4)
+        targets = _uniform_targets(3502, 2 * WINDOW_Q)
+        out = tp_simulate_lookups(mesh2, targets=targets,
+                                  state=sharded_global_sort(mesh2, ids),
+                                  **WINDOW_KW)
+        assert np.asarray(out["window_rounds"]).shape == (2,)
+    ref = _assert_equals_one_chip(out, sorted_ids, n_valid, targets)
+    assert (np.asarray(out["window_rounds"]) >= 0).all()
+    assert (np.asarray(out["window_rounds"])
+            <= np.asarray(ref["hops"]).max()).all()
+
+
+def _edge_targets(rng, sorted_ids, state):
+    """Targets within 12 rows of a shard edge: the ids of those rows with
+    their low limbs redrawn, so the 24-row fallback window around each
+    straddles the edge."""
+    edges = np.cumsum(np.asarray(state.arrays["shard_rows"])[:, 1])[:-1]
+    rows = (rng.choice(edges, WINDOW_Q)
+            + rng.integers(-12, 13, WINDOW_Q)).astype(np.int64)
+    targets = np.asarray(sorted_ids)[rows].copy()
+    targets[:, 3:] = rng.integers(0, 2 ** 32, size=(WINDOW_Q, 2),
+                                  dtype=np.uint32)
+    return targets
+
+
+@pytest.mark.parametrize("wave", ["one_home", "shard_edge", "empty_shard"])
+def test_tp_simulate_equals_one_chip_on_adversarial_waves(window_network,
+                                                          wave):
+    """(b) Waves the grouping cannot help cost passes, never an answer:
+    every target in ONE shard's key range (its shard owns every lane and
+    takes t passes a round, so no round counts: ``window_rounds`` 0);
+    every target within 12 rows of a shard edge (fallback windows that
+    straddle it: a lookup's rows in two shards to the end); a table
+    whose last shard holds no row."""
+    ids, sorted_ids, n_valid, mesh, state = window_network
+    rng = np.random.default_rng(3510)
+    if wave == "one_home":
+        targets = _uniform_targets(3511)
+        targets[:, 0] = (targets[:, 0] >> 2) | np.uint32(2 << 30)
+    elif wave == "shard_edge":
+        targets = _edge_targets(rng, sorted_ids, state)
+    else:
+        targets = _uniform_targets(3512)
+    if wave == "empty_shard":
+        sorted_ids, _, n_valid = sort_table(jnp.asarray(ids[:3000]))
+        padded, _ = pad_to_multiple(np.asarray(sorted_ids), 4096)
+        out = tp_simulate_lookups(mesh, padded, n_valid, targets,
+                                  **WINDOW_KW)
+    else:
+        out = tp_simulate_lookups(mesh, targets=targets, state=state,
+                                  **WINDOW_KW)
+    _assert_equals_one_chip(out, sorted_ids, n_valid, targets)
+    if wave == "one_home":
+        assert int(out["window_rounds"][0]) == 0
+
+
+def test_window_rounds_are_the_loop_rounds_of_a_grouped_wave(window_network):
+    """(d) A wave whose homes are balanced (512 lanes each: every group
+    starts on a lane tile) and whose targets lie in the middle half of
+    their home's key range (no fallback window reaches a shard edge):
+    from loop round 1 on every reply row of a lookup lies in its home
+    shard, every shard serves every round's gather in one pass, and
+    ``window_rounds`` is the number of loop rounds — the deepest
+    lookup's hops."""
+    _ids, sorted_ids, n_valid, mesh, state = window_network
+    targets = _uniform_targets(3520)
+    home = np.arange(WINDOW_Q, dtype=np.uint32) % 4
+    rng = np.random.default_rng(3521)
+    rng.shuffle(home)
+    mid = rng.integers(1, 3, WINDOW_Q).astype(np.uint32)
+    targets[:, 0] = (home << 30) | (mid << 28) | (targets[:, 0] >> 4)
+    out = tp_simulate_lookups(mesh, targets=targets, state=state,
+                              **WINDOW_KW)
+    ref = _assert_equals_one_chip(out, sorted_ids, n_valid, targets)
+    rounds = int(np.asarray(ref["hops"]).max())
+    assert rounds >= 4
+    assert np.asarray(out["window_rounds"]).tolist() == [rounds]
+
+
+def _np_lane_window(lane_any, width):
+    """The window rule in numpy."""
+    if not lane_any.any():
+        return None, 0
+    first, last = np.flatnonzero(lane_any)[[0, -1]]
+    start = first // 128 * 128
+    return start, -(-(last + 1 - start) // width)
+
+
+WINDOW_MASKS = ["grouped", "ungrouped", "none", "stray_lane", "tail",
+                "two_homes", "one_lane", "all", "ragged_lanes"]
+
+
+@pytest.mark.parametrize("mask", WINDOW_MASKS)
+def test_window_passes_cover_every_owned_lane_once(mask):
+    """(c) The window rule against its numpy rendering, on index masks
+    of every kind: the passes number ``ceil(span / Wh)``, the lanes each
+    SERVES partition the span (every owned lane in exactly one), each
+    window as the program slices it (held inside the index) contains
+    what its pass serves, and ``window_gather`` returns the owned rows'
+    limbs, 0 elsewhere, and whether one pass did it."""
+    from opendht_tpu.parallel.sharded import (lane_window, window_gather,
+                                              window_width)
+    rng = np.random.default_rng(WINDOW_MASKS.index(mask))
+    lanes = 4000 if mask == "ragged_lanes" else 4096
+    width = window_width(lanes, 4)
+    assert width == 1152
+    shard_n, slots = 5000, 6
+    base = 2 * shard_n                           # shard 2 of 4
+    home = np.sort(rng.integers(0, 4, lanes))
+    if mask == "ungrouped":
+        rng.shuffle(home)
+    elif mask == "none":
+        home = np.where(home == 2, 3, home)
+    elif mask == "stray_lane":
+        home[5] = 2
+    elif mask == "tail":
+        home = np.sort(rng.choice([0, 1, 3, 3], lanes))
+        home[-700:] = 2
+    elif mask == "one_lane":
+        home = np.where(home == 2, 1, home)
+        home[1234] = 2
+    elif mask == "all":
+        home[:] = 2
+    rows = home[None, :] * shard_n + rng.integers(0, shard_n, (slots, lanes))
+    if mask == "two_homes":
+        rows = np.where(rng.random(rows.shape) < 0.5, rows, rows - shard_n)
+    rows[rng.random(rows.shape) < 0.1] = -1      # lookups that are done
+    rows = rows.astype(np.int32)
+    ok = (rows >= base) & (rows < base + shard_n)
+    lane_any = ok.any(axis=0)
+
+    start, passes = (int(x) for x in lane_window(jnp.asarray(lane_any),
+                                                 width))
+    want_start, want_passes = _np_lane_window(lane_any, width)
+    assert passes == want_passes
+    covered = np.zeros(lanes, int)
+    if passes:
+        assert start == want_start and start % 128 == 0
+        span = np.flatnonzero(lane_any)[-1] + 1 - start
+        assert passes == -(-span // width)
+        for p in range(passes):
+            lo = start + p * width
+            at = min(lo, lanes - width)          # the program's slice
+            assert at <= lo and min(lo + width, lanes) <= at + width
+            covered[lo:lo + width] += 1
+    assert (covered[lane_any] == 1).all() and covered.max() <= 1
+    assert {"grouped": 1, "ungrouped": 4, "none": 0, "stray_lane": 3,
+            "tail": 1, "one_lane": 1, "all": 4}.get(mask, passes) == passes
+
+    view = rng.integers(0, 2 ** 32, (2, shard_n), dtype=np.uint32)
+    planes, one_pass = jax.jit(
+        lambda v, r: window_gather(v, r, np.int32(base), np.int32(shard_n),
+                                   shard_n, 2, width))(view, rows)
+    want = np.where(ok[None], view[:, np.clip(rows - base, 0, shard_n - 1)],
+                    0)
+    np.testing.assert_array_equal(np.asarray(planes), want)
+    assert int(one_pass) == (passes <= 1)
+    # and at full width: the one pass it always was
+    full, one = window_gather(view, rows, np.int32(base), np.int32(shard_n),
+                              shard_n, 2, lanes)
+    np.testing.assert_array_equal(np.asarray(full), want)
+    assert int(one) == 1
+
+
+def test_one_chip_program_is_the_parents():
+    """(e) The one-chip engine never sees the window: with a gather
+    closure that reports nothing ``_lookup_engine`` lowers to the
+    program it was before the optional count existed — the sha-256 of
+    ``_simulate_lookups_jit``'s lowered text at a toy shape, taken on
+    the parent commit of PR 35 (abstract operands: nothing runs)."""
+    import hashlib
+    from opendht_tpu.core.search import _simulate_lookups_jit
+    from opendht_tpu.ops.sorted_table import default_lut_bits
+    A = jax.ShapeDtypeStruct
+    u32, i32 = jnp.uint32, jnp.int32
+    text = _simulate_lookups_jit.lower(
+        A((16384, 5), u32), A((), i32), A((4096, 5), u32), seed=A((), i32),
+        lut=A(((1 << default_lut_bits(16384)) + 1,), i32), k=8, alpha=3,
+        search_nodes=14, state_limbs=2).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "e13fcbd00248f195034072b8f79c8e6d90dee22651f20afdd2ee17fa6cdff206")

@@ -122,6 +122,65 @@ def test_equals_the_one_chip_table_lookup_for_lookup(t):
     assert _live_ids(tb) == set(book) and tb.n_live == one.n_live == N
 
 
+@pytest.mark.parametrize("wave", ["uniform", "one_home", "shard_edge"])
+def test_equals_the_one_chip_table_over_lane_windows(wave):
+    """PR 35: a wave wide enough that a shard gathers over a lane window
+    (2,048 lanes at t = 4: windows of 640), over a table that has
+    ticked: lookup for lookup the one-chip table's answers, in the
+    caller's order — for uniform targets, for targets that all lie in
+    ONE shard's key range (that shard takes t passes a round, so no
+    round counts for ``window_rounds``) and for targets within 12 live ids of a key
+    range's edge (fallback windows that straddle it).  ``alive`` and
+    ``delta_window`` keep their full-width owner reads."""
+    from opendht_tpu.parallel.sharded import window_width
+    rng = np.random.default_rng(35)
+    mesh, tb, one, book = _pair(rng, 4)
+    for _ in range(3):
+        _tick((tb, one), book, rng, 60, 60)
+    Q = 2048
+    assert window_width(Q, 4) == 640
+    targets = _ids(rng, Q)
+    if wave == "one_home":
+        targets[:, 0] = (targets[:, 0] >> 2) | np.uint32(1 << 30)
+    elif wave == "shard_edge":
+        live = np.array(sorted(book), dtype=np.uint32)
+        edges = np.searchsorted(live[:, 0], [1 << 30, 2 << 30, 3 << 30])
+        targets[:, :3] = live[rng.choice(edges, Q)
+                              + rng.integers(-12, 13, Q), :3]
+    got = _same_lookups(mesh, tb, one, jnp.asarray(targets), 7)
+    assert np.asarray(got["converged"]).all()
+    rounds = np.asarray(got["window_rounds"])
+    assert rounds.shape == (1,) and 0 <= rounds[0] <= np.max(got["hops"])
+    if wave == "one_home":
+        # (only a last round, whose few lookups still live — expired
+        # peers keep stragglers going — span less than a window)
+        assert rounds[0] <= 1 < np.max(got["hops"]) - 2
+
+
+def test_one_chip_churn_program_is_the_parents():
+    """PR 35: the one-chip engine under churn lowers to the program it
+    was before the gather closure could report its passes (sha-256 of
+    the lowered text at a toy shape, taken on the parent commit;
+    abstract operands: nothing runs)."""
+    import functools
+    import hashlib
+    from opendht_tpu.core.search import _simulate_lookups_jit
+    from opendht_tpu.core.table import (MAX_STALE_SHARE, stale_limit,
+                                        tomb_words)
+    from opendht_tpu.ops.sorted_table import default_lut_bits
+    A = jax.ShapeDtypeStruct
+    u32, i32 = jnp.uint32, jnp.int32
+    capacity = 32 * tomb_words(N + 1024)
+    view = jax.eval_shape(functools.partial(
+        CT.churn_table, capacity=capacity, delta_capacity=1024,
+        stale_rows=stale_limit(capacity, MAX_STALE_SHARE),
+        lut_bits=default_lut_bits(N)), A((N, 5), u32), A((), i32))
+    text = _simulate_lookups_jit.lower(view, None, A((4096, 5), u32),
+                                       seed=A((), i32), **KW).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest() == (
+        "5336a9979e6a46efb34b1093a5cca245fee48084eeb7377de6ec89172125b25c")
+
+
 @pytest.mark.parametrize("block_bits", [None, 18])
 def test_after_a_compaction_equals_a_fresh_build_of_the_live_ids(block_bits):
     """Bit for bit; with a block LUT wider than the shards' own
