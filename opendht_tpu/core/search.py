@@ -350,15 +350,19 @@ def _reply_rows(pt, qidx, x_rows, round_no, lo, ub, *, n, k, R, q_total,
 def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                    seed_u, *, k, alpha, search_nodes, max_hops,
                    state_limbs: int = N_LIMBS,
-                   block_bounds=None, alive=None, delta_window=None):
+                   block_bounds=None, alive=None, delta_window=None,
+                   live_count=None):
     """The iterative-lookup state machine, abstracted over table access.
 
     ALL access to the (possibly distributed) sorted node table flows
     through two injected primitives, which is what lets the same engine
     run single-device (:func:`simulate_lookups`) and with the table
     row-sharded over a mesh axis (parallel/sharded.py:
-    ``tp_simulate_lookups`` — each primitive becomes a shard-local
-    partial computation + one ``psum`` over the table axis):
+    ``tp_simulate_lookups`` — each shard runs the engine over its own
+    chunk of the wave, and each primitive becomes a shard-local partial
+    computation for the whole wave between two collectives over the
+    table axis, ``lane_exchange``).  The engine itself names no mesh
+    axis and calls no collective:
 
       gather_planar(rows [...], limbs) -> limbs×[...] uint32 limb
           planes (top limbs first) of the globally-sorted table rows,
@@ -404,8 +408,16 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
           (the committed goldens of tests/test_search.py and
           tests/test_sharded.py).  The tp twin hands it both over a
           ``parallel.churn.ShardedChurnTable`` (owner-shard reads and
-          one ``psum`` each, ``parallel/sharded.py
+          one lane exchange each, ``parallel/sharded.py
           _tp_churn_primitives``) and neither over a table built once.
+      live_count(done [W] bool) -> int32, the optional HOOK for the one
+          cross-lane fact the engine acts on: the live count that its
+          loop conditions and its cut rule read (SURVIVOR COMPACTION).
+          Left out it is ``jnp.sum(~done)``, the operation that was
+          there.  A caller whose wave is one CHUNK of a larger one hands
+          in the count all chunks must agree on (the tp twin: the
+          fullest shard's), so that loops which hold a collective run
+          the same number of rounds and cut in the same one.
 
     CHURN (PR 32): the table is a sorted BASE as last compacted, a
     liveness bit a node, and a sorted DELTA of the nodes that joined
@@ -538,11 +550,16 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     ``q_index``, so every output equals the one-loop engine's bit for
     bit, a lookup that runs into ``max_hops`` included (no loop runs
     past it).  Waves under ``NARROW_MIN_WAVE`` keep one loop.  On a mesh
-    the search state is replicated over ``t``: every ``t``-rank sees the
-    same ``done``, cuts in the same round and packs the same survivors;
-    ``q``-ranks may cut in different rounds (the round's psum is over
-    ``t`` only).  The engine also returns ``narrow_rounds``, the number
-    of rounds it ran under the wave's full width (0 = never cut).
+    the search state is SHARDED over ``t`` as well as over ``q`` (PR 38:
+    a ``t``-rank runs this engine over its own ``W/t`` lanes, and both
+    constants apply to that width): the ``t``-ranks hold different
+    ``done`` flags, so the tp twin hands in ``live_count`` — the fullest
+    shard's count — and every ``t``-rank runs the same rounds, cuts in
+    the same one, when every shard's survivors fit its ``C``, and packs
+    its own; ``q``-ranks may cut in different rounds (a round's
+    collectives are over ``t`` only).  The engine also returns
+    ``narrow_rounds``, the number of rounds it ran under the wave's full
+    width (0 = never cut).
     """
     Q = targets.shape[0]
     S = search_nodes
@@ -813,11 +830,15 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                     counts)
         return body
 
+    if live_count is None:
+        def live_count(done):
+            return jnp.sum(~done)
+
     def live_over(cap):
         """Loop condition: more than ``cap`` lookups live, rounds left."""
         def cond(state):
             done, round_no = state[4], state[5]
-            return (jnp.sum(~done) > cap) & (round_no < max_hops)
+            return (live_count(done) > cap) & (round_no < max_hops)
         return cond
 
     def first_k_live(cand_node, cand_l, queried):
@@ -1106,7 +1127,9 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     and ``dht_search_narrow_rounds``: how many of the wave's rounds ran
     under its full width (the engine's own count, 0 = it never cut);
     from the tp twin also ``dht_search_window_rounds``: how many of its
-    loop rounds every shard gathered in one pass over its lane window.
+    loop rounds every shard gathered in one pass over its lane window,
+    and ``dht_search_home_lanes``: how many of its lanes ran on the
+    shard that holds their rows.
     Shared by the single-device engine and the tp-sharded twin
     (``mode="tp"``, parallel/sharded.py); both time the whole of this
     call as ``dht_search_record_seconds``.
@@ -1127,9 +1150,9 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     reg.histogram("dht_search_wave_seconds", mode=mode).observe(elapsed_s)
     reg.histogram("dht_search_wave_width", mode=mode).observe(wave_width)
     # ONE fetch for all: the counts ride the copy of ``hops``
-    hops, narrow, expired, windowed = jax.device_get(
+    hops, narrow, expired, windowed, at_home = jax.device_get(
         (out["hops"], out["narrow_rounds"], out.get("expired_peers"),
-         out.get("window_rounds")))
+         out.get("window_rounds"), out.get("home_lanes")))
     if expired is not None:
         # CHURN: the wave's queried peers that were gone (the engine's
         # own count; on a frozen table the series does not exist)
@@ -1143,6 +1166,14 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
         # grouped by home shard, 0 where they pile on one
         reg.histogram("dht_search_window_rounds", mode=mode).observe(
             int(np.min(windowed)))
+    if at_home is not None:
+        # the tp twin's: the wave's lanes that ran on their HOME shard,
+        # the one whose key range holds the lookup's target (a sum over
+        # the q-ranks; parallel/sharded.py build_tp_lookup, THE SEARCH
+        # STATE) — all but a few hundred of a uniform wave's, few of a
+        # skewed one's: the lanes whose rows their own shard holds
+        reg.histogram("dht_search_home_lanes", mode=mode).observe(
+            int(np.sum(at_home)))
     reg.histogram("dht_search_hops", mode=mode).observe_many(hops)
     # one value a wave: on a mesh the slowest q-rank's (each cuts when
     # its own survivors fit)

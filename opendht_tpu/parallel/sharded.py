@@ -28,11 +28,13 @@ replicated global block LUT, validity — is built ONCE by
 no single memory should hold) and reused across waves.  Each ``t``
 shard holds ~N/t rows (plus the 4·2^bb-byte block LUT); nothing
 table-sized is replicated, so the servable id set scales linearly in
-mesh size.  The steady-state search round costs exactly ONE in-loop
-collective — the reply-row merge psum, O(queries·k) bytes — because
-reply-block edges read the replicated global LUT locally instead of
-psumming per-shard edge counts every hop (stage ``owner_merge``, 8% of
-a wave on four chips: PERF.md §5).
+mesh size.  The search STATE is sharded too (PR 38): a ``t``-rank runs
+the engine over its own chunk of a wave, and the steady-state search
+round costs exactly ONE in-loop lane exchange — the reply-row index
+all-gathered, the owners' rows summed back to the lanes' shard,
+O(queries·k) bytes — because reply-block edges read the replicated
+global LUT locally instead of psumming per-shard edge counts every hop
+(stage ``owner_merge``: PERF.md §5).
 
 Compiled programs are cached per (mesh, k, tile/window, shard size) —
 repeated calls with the same geometry reuse one XLA executable.
@@ -491,6 +493,75 @@ def window_gather(view, rows, base, n_owned, capacity: int, limbs: int,
     return planes, (passes <= 1).astype(jnp.int32)
 
 
+def lane_chunk(lanes: int, n_t: int) -> int:
+    """The lanes of a ``q``-rank's wave that ONE ``t``-rank runs the
+    engine over (:func:`build_tp_lookup`, THE SEARCH STATE), a static
+    function of the lane count alone, like :func:`window_width`: an
+    equal share, ``lanes / t`` — 16,384 of 65,536 at ``t`` = 4 — and the
+    whole wave where ``t`` does not divide it (or is 1): every rank then
+    runs every lookup, and :func:`lane_exchange` is one ``psum``."""
+    return lanes // n_t if lanes % n_t == 0 else lanes
+
+
+def lane_exchange(owner_read, chunked: bool, stage: Optional[str] = None,
+                  has_aux: bool = False):
+    """Turn an owner-shard read written for the WHOLE wave into one for
+    this shard's CHUNK of it (:func:`lane_chunk`) — the one way a lookup
+    that runs on one shard reaches the rows of another.
+
+    ``owner_read(whole)`` is this shard's PART of a distributed read:
+    the answer in the lanes whose row it owns, 0 in every other, so that
+    the parts' sum over ``t`` is the read.  The returned ``read(arg,
+    axis, part_axis)`` takes the chunk's argument, the wave's lanes on
+    its ``axis``:
+
+    - ``all_gather`` it over ``t`` along that axis, tiled — the chunks
+      are equal runs of the wave in rank order, so this IS the wave's
+      argument, and ``owner_read`` runs on it at full width, exactly as
+      when every rank held the whole wave;
+    - ``psum`` the part over ``t`` and keep this rank's run of the lanes
+      of ``part_axis`` (the lane axis of the answer: ``axis`` unless
+      given): every lane's sum goes to the shard that runs the lane.
+      Written as the all-reduce and the slice, not as ``psum_scatter``:
+      the TPU compiler renders a reduce-scatter as exactly those two
+      (v5e 2x2: the optimised program holds no reduce-scatter) and
+      gives the all-reduce it makes NO ``op_name``, so the round's
+      cross-shard cost would leave its stage and land under none
+      (PERF.md §6, PR 38: 6.1 ms a wave did).
+
+    The read is elementwise in its lanes, so any axis all ranks agree on
+    gives the same values; which one matters to ``owner_read`` alone
+    (:func:`window_gather` windows the minor one).  Not ``chunked`` (a
+    chunk that is the whole wave): no gather and no slice, the ``psum``
+    it always was.  Both collectives run under device stage ``stage``
+    (none: unstaged, as the positioning's always was); ``has_aux``:
+    ``owner_read`` returns ``(part, aux)`` and ``aux`` — this shard's
+    own report, no lane's — comes back beside the answer.
+    """
+    staged = device_stage(stage) if stage else (lambda fn: fn)
+
+    def read(arg, axis: int = 0, part_axis: Optional[int] = None):
+        part_axis = axis if part_axis is None else part_axis
+        width = arg.shape[axis]                    # the chunk's lanes
+        if chunked:
+            arg = staged(lambda chunk: lax.all_gather(
+                chunk, "t", axis=axis, tiled=True))(arg)
+        part, aux = owner_read(arg) if has_aux else (owner_read(arg), None)
+
+        @staged
+        def own_lanes(part):
+            whole = lax.psum(part, "t")
+            if not chunked:
+                return whole
+            return lax.dynamic_slice_in_dim(
+                whole, lax.axis_index("t") * width, width, axis=part_axis)
+
+        mine = own_lanes(part)
+        return (mine, aux) if has_aux else mine
+
+    return read
+
+
 def shard_offset(widths, n_t: int):
     """``(rows of the shards before this one, rows of all shards)`` from
     the ``n_t`` shards' ``widths``: where this shard's rows begin in an
@@ -499,12 +570,14 @@ def shard_offset(widths, n_t: int):
     return jnp.sum(jnp.where(before, widths, 0)), jnp.sum(widths)
 
 
-def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int, base,
-                         n_local, tomb_bits, delta, n_delta, delta_lut):
+def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int,
+                         chunked: bool, base, n_local, tomb_bits, delta,
+                         n_delta, delta_lut):
     """The engine's two CHURN primitives over ONE SHARD's piece of a
     row-sharded mutable table (parallel/churn.py) — the tp twins of
     ``core.search._churn_primitives``, each a shard-local read by the
-    owner and one ``psum`` over ``t``.
+    owner, for the whole wave, inside one :func:`lane_exchange` over
+    ``t`` (``chunked``: the engine runs a chunk of the wave).
 
     A node is a GLOBAL base row, or ``t·shard_n`` + a place in the
     global order of the shards' deltas; this shard owns base rows
@@ -516,19 +589,20 @@ def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int, base,
 
     ``alive(nodes)``: the owner reads the bit, every other shard a spare
     word of its own (:func:`owner_local_index`) that it throws away, and
-    the shards' answers are summed — stage ``alive_merge``, inside the
-    engine's ``expire``.  ``delta_window(targets)``: the targets' place
-    in the global order of the deltas is the sum of their places in the
-    shards' (as ``lower`` is for the base), and the ``DELTA_WINDOW``
-    rows around it are fetched by their owners — a window that
-    straddles a shard edge takes rows of both — and summed: stage
-    ``delta_merge``, inside the engine's ``delta_window``.
+    the shards' answers are summed to the lanes' shard — stage
+    ``alive_merge``, inside the engine's ``expire``.
+    ``delta_window(targets)``: the targets' place in the global order of
+    the deltas is the sum of their places in the shards' (as ``lower``
+    is for the base), and the ``DELTA_WINDOW`` rows around it are
+    fetched by their owners — a window that straddles a shard edge takes
+    rows of both — and summed: two exchanges, stage ``delta_merge``,
+    inside the engine's ``delta_window``.
     """
     total = n_t * shard_n                  # where the delta's nodes start
     d_base, d_total = device_stage("delta_merge")(
         lambda n: shard_offset(lax.all_gather(n, "t"), n_t))(n_delta)
 
-    def alive(nodes):
+    def owner_gone(nodes):
         in_delta = nodes >= total
         loc, ok = owner_local_index(
             jnp.where(in_delta, nodes - total, nodes),
@@ -536,24 +610,28 @@ def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int, base,
             jnp.where(in_delta, n_delta, n_local), shard_n)
         gone = ok & node_gone(tomb_bits,
                               jnp.where(ok & in_delta, shard_n + loc, loc))
-        return device_stage("alive_merge")(
-            lambda part: lax.psum(part, "t"))(gone.astype(jnp.int32)) == 0
+        return gone.astype(jnp.int32)
 
-    lower_d = _guarded_lower_bound(delta, n_delta, delta_lut)
+    gone = lane_exchange(owner_gone, chunked, "alive_merge")
+
+    def alive(nodes):
+        return gone(nodes, nodes.ndim - 1) == 0
+
+    place = lane_exchange(_guarded_lower_bound(delta, n_delta, delta_lut),
+                          chunked, "delta_merge")
     delta_t = delta.T
 
-    def delta_window(targets):
-        @device_stage("delta_merge")
-        def place(at):
-            return lax.psum(at, "t")
-
-        slot = (place(lower_d(targets))[None, :] - DELTA_WINDOW // 2
-                + jnp.arange(DELTA_WINDOW, dtype=jnp.int32)[:, None])
+    def owner_rows(slot):
         loc, ok = owner_local_index(slot, d_base, n_delta, delta_rows)
-        part = jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
+        return jnp.stack([jnp.where(ok, plane, _U32(0)) for plane in
                           fused_gather_planar(delta_t, loc)])
-        ids = device_stage("delta_merge")(
-            lambda part: lax.psum(part, "t"))(part)
+
+    rows = lane_exchange(owner_rows, chunked, "delta_merge")
+
+    def delta_window(targets):
+        slot = (place(targets)[None, :] - DELTA_WINDOW // 2
+                + jnp.arange(DELTA_WINDOW, dtype=jnp.int32)[:, None])
+        ids = rows(slot, 1, 2)
         node = jnp.where((slot >= 0) & (slot < d_total), total + slot, -1)
         return node.T, [ids[l].T for l in range(N_LIMBS)]
 
@@ -577,22 +655,50 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     :func:`tp_simulate_lookups` is the convenience entry that builds
     and places the state per call.
 
-    The steady-state round costs exactly ONE collective: the fused
-    reply-row merge psum (O(queries·k) bytes).  Reply-block edges —
-    one whole psum site per hop in the round-12 layout — are now two
-    LOCAL reads of the replicated global block LUT, which
-    ``shard_table_state`` assembled with a single one-shot psum of the
-    per-shard LUTs at table-build time (entry p of a shard's LUT is
-    its local count of valid rows with prefix < p; the sum over shards
-    is the global count, so the values are bit-identical to the
-    per-hop psum they replace).
+    THE SEARCH STATE (PR 38) is sharded over ``t`` as well as over
+    ``q``: a lookup's rounds run on ONE shard.  After the grouping
+    (below) shard ``ti`` keeps lanes ``[ti·Wl, (ti+1)·Wl)`` of its
+    ``q``-rank's wave, ``Wl = lane_chunk(q_local, t)`` — equal chunks of
+    the grouped order, not "the lanes whose home I am": the split is
+    static and exact, a skewed wave stays balanced in compute, and on a
+    uniform wave a chunk is its shard's home lanes to within the spread
+    of the home counts (``home_lanes`` counts them) — and runs
+    ``_lookup_engine`` over those: the LUT edge reads, the merge sorts,
+    ``select``, ``converge`` and ``pack`` of a lookup are done by one
+    chip, where every chip did them for every lookup while the state
+    was replicated.  Only the engine's primitives, the places a lookup
+    touches the TABLE, cross the mesh, each through one
+    :func:`lane_exchange` — the chunks' argument all-gathered over
+    ``t``, the shard's part computed for the whole wave as before, the
+    parts summed back to the lanes' shard: ``gather_planar``
+    (stage ``owner_merge``: the round's two collectives), ``lower``
+    (positioning, once a wave) and under churn ``alive`` and
+    ``delta_window``.  ``block_bounds`` needs nothing: the block LUT is
+    the replicated GLOBAL prefix LUT (``shard_table_state`` assembled
+    it with a single one-shot psum of the per-shard LUTs at table-build
+    time, so the values are bit-identical to the sum of per-shard
+    counts), and a shard reads the edges of its own lanes.  The engine's
+    one cross-lane fact, the live count its loops and its cut read, is
+    handed in as the FULLEST shard's (``pmax`` over ``t``): the loop
+    bodies hold collectives, so every ``t``-rank runs the same number
+    of rounds and cuts in the same one, once every shard's survivors
+    fit its ``C`` (``NARROW_MIN_WAVE`` / ``NARROW_DIVISOR`` apply to
+    ``Wl``: 16,384 → 2,048 lanes a shard).  Behind the engine the
+    outputs are all-gathered over ``t`` (stage ``group``) and
+    ``expired_peers`` is summed.  The reply hash is keyed by the global
+    ``q_index``, which travels with its lane, so no output bit depends
+    on where a lane runs.  A wave ``t`` does not divide takes the same
+    code with a chunk that is the whole wave: every rank runs every
+    lookup, as all did before PR 38, and the exchange is its ``psum``.
 
-    The collective's shape is the round's index: ``[NL, α·k, W]`` in a
-    loop round (``[NL, α·k, C]`` once the wave has cut to ``C`` lanes),
-    ``[NL, k, W]`` in the bootstrap round, whose ONE peer answers with k
-    rows (core/search.py ``_lookup_engine``, BOOTSTRAP SHAPE; lut mode's
-    bootstrap also fetches that peer's top limb, a ``[1, 1, W]`` psum),
-    and ``[5, W, k]`` for the final id fetch.  What the gathers read
+    The exchange's shape is the round's index: ``[α·k, W]`` int32
+    gathered and ``[NL, α·k, W]`` scattered in a loop round (``C`` lanes
+    a shard once the wave has cut), ``[k, W]`` / ``[NL, k, W]`` in the
+    bootstrap round, whose ONE peer answers with k rows (core/search.py
+    ``_lookup_engine``, BOOTSTRAP SHAPE; lut mode's bootstrap also
+    fetches that peer's top limb, ``[1, W]``), and ``[W, k]`` /
+    ``[5, W, k]`` for the final id fetch, whose lanes are its MAJOR
+    axis (the wave is an index's longest axis).  What the gathers read
     is decided once, before the engine runs
     (``ops.sorted_table.loop_gather_view``): a shard whose limb view is
     too large for on-chip memory is not sliced inside a loop body.
@@ -621,8 +727,11 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     The program also returns ``window_rounds``, one count a ``q``-rank:
     the wave's in-loop round gathers that EVERY shard served in one
     pass (the engine sums each shard's report, one ``pmin`` over ``t``
-    once a wave) — the wave's loop rounds where the grouping holds.
-    Every other output bit is the full-width program's.
+    once a wave) — the wave's loop rounds where the grouping holds —
+    and ``home_lanes``, the lanes of the rank's wave that run on their
+    home shard (all but a few hundred of a uniform wave's; a skewed
+    wave reads low, and says why ``window_rounds`` fell).  Every other
+    output bit is the one-chip engine's.
 
     ``delta_rows`` is part of the geometry like ``shard_n``: the delta
     slab of each shard of a table under membership CHURN
@@ -670,40 +779,44 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
         views = {l: loop_gather_view(sorted_t, l)
                  for l in (1, state_limbs, N_LIMBS)}
 
-        def lower(flat):
-            # global lower bound = Σ_shards (local rows < q): each
-            # shard's local lower-bound index IS that count, and the
-            # global sorted order is the in-order concatenation of
-            # shard ranges — one [M]-int32 psum over the table axis.
-            # Called ONCE per wave (the pre-loop target positioning),
-            # never inside the hop loop.
-            return lax.psum(local_lower(flat), "t")
+        # THE SEARCH STATE: this rank runs the engine over a chunk of the
+        # wave, and the engine's primitives exchange lanes across t
+        chunk = lane_chunk(q_local, n_t)
+        chunked = chunk < q_local
+
+        # global lower bound = Σ_shards (local rows < q): each shard's
+        # local lower-bound index IS that count, and the global sorted
+        # order is the in-order concatenation of shard ranges — one
+        # [M]-int32 exchange over the table axis.  Called ONCE per wave
+        # (the pre-loop target positioning), never inside the hop loop.
+        lower = lane_exchange(local_lower, chunked)
 
         def block_bounds(t0, prefix_len):
             # ZERO collectives: the block LUT is the replicated GLOBAL
             # prefix LUT (built once per table — shard_table_state), so
-            # both edges are plain local gathers.  Values are the exact
-            # Σ-of-per-shard-counts the round-12 in-loop psum computed,
-            # hence bit-identical to the single-device engine at the
-            # same block width (default_lut_bits(N), never the shard
-            # size — a shard-sized width would make the clamp depth,
-            # and hence the reply stream, vary with the mesh split).
+            # both edges are plain local gathers, of this rank's own
+            # lanes.  Values are the exact Σ-of-per-shard-counts the
+            # round-12 in-loop psum computed, hence bit-identical to the
+            # single-device engine at the same block width
+            # (default_lut_bits(N), never the shard size — a
+            # shard-sized width would make the clamp depth, and hence
+            # the reply stream, vary with the mesh split).
             return _lut_block_bounds(block_lut, t0, prefix_len)
 
         def gather_planar(rows, limbs=N_LIMBS):
-            # distributed row fetch: the owning shard contributes the
-            # row's limbs, every other shard zeros — psum reassembles.
-            # Rows are pre-clipped to [0, n) by the engine; a -1
-            # (absent) row is owned by no shard and comes back 0,
-            # masked by the engine exactly like the unsharded garbage.
-            # With the round-6 fused engine this runs ONCE per round
-            # (the α·k reply fetch): the per-round 1-limb peer fetch's
-            # psum site is gone — the engine reads the carried
-            # candidate distance instead (core/search.py).
-            # The index keeps ``rows``' own shape up to the gather
-            # (the engine's: slot-major, W on the lanes) and the planes
-            # come back in it (ops.sorted_table.fused_gather_planar).
-            # Ownership: weighted shards own exactly n_local rows (the
+            # distributed row fetch: of the WHOLE wave's index (the
+            # chunks', all-gathered) the owning shard contributes the
+            # row's limbs, every other shard zeros — the exchange's sum
+            # reassembles a chunk's rows on its shard.  Rows are
+            # pre-clipped to [0, n) by the engine; a -1 (absent) row is
+            # owned by no shard and comes back 0, masked by the engine
+            # exactly like the unsharded garbage.  With the round-6
+            # fused engine this runs ONCE per round (the α·k reply
+            # fetch).  The index keeps ``rows``' own shape up to the
+            # gather (the engine's: slot-major, the lookups on the
+            # lanes) and the planes come back in it
+            # (ops.sorted_table.fused_gather_planar).  Ownership:
+            # weighted shards own exactly n_local rows (the
             # [b_i, b_{i+1}) ranges partition the valid prefix); the
             # uniform test keeps the static width, equivalent for valid
             # rows.  The shard gathers over the lane window that holds
@@ -713,89 +826,131 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             # count.  What a lane that is not owned reads is
             # owner_local_index's one rule, whatever the layout or the
             # limb count.
-            g, one_pass = window_gather(
-                views[limbs], rows, base, n_local if weighted else shard_n,
-                shard_n, limbs, window_width(rows.shape[-1], n_t))
-            # the round's one collective, a device stage of its own —
-            # behind the passes, whose number is each shard's own
-            g = device_stage("owner_merge")(
-                lambda part: lax.psum(part, "t"))(g)
+            def owner_rows(rows):
+                return window_gather(
+                    views[limbs], rows, base,
+                    n_local if weighted else shard_n, shard_n, limbs,
+                    window_width(rows.shape[-1], n_t))
+
+            # the lookups are an index's longest axis: the minor one of
+            # a round's slot-major [P·k, W], the major one of the
+            # lookup-major final fetch [W, k]
+            lanes = int(np.argmax(rows.shape))
+            # the round's two collectives, a device stage of their own —
+            # around the passes, whose number is each shard's own
+            g, one_pass = lane_exchange(owner_rows, chunked, "owner_merge",
+                                        has_aux=True)(rows, lanes, lanes + 1)
             return [g[l] for l in range(limbs)], one_pass
 
         churn = {}
         if delta_rows:
             tomb_bits, delta, n_delta, delta_lut = churn_op
             churn = _tp_churn_primitives(
-                shard_n, delta_rows, n_t, base, n_local, tomb_bits, delta,
-                n_delta[0], delta_lut[0])
+                shard_n, delta_rows, n_t, chunked, base, n_local, tomb_bits,
+                delta, n_delta[0], delta_lut[0])
         q_index = (lax.axis_index("q").astype(jnp.int32) * q_local
                    + jnp.arange(q_local, dtype=jnp.int32))
         grouped = window_width(q_local, n_t) < q_local
-        if grouped:
+        home_lanes = jnp.int32(q_local)    # a chunk that is the whole wave
+        if grouped or chunked:
             # GROUP THE WAVE BY HOME SHARD, once a wave: a lookup's home
             # is the shard whose key range holds its target — read off
             # the shards' first rows' top limbs, one all_gather of t
             # words — and from loop round 1 on every reply row of a
             # lookup lies there (window_gather), so with the lanes of a
             # home side by side the lanes a shard owns are one run of
-            # about q_local / t, and its gather covers them in one
-            # window.  A stable partition on a log2(t)-bit key that
-            # carries the operands (never a sort by the target: a sorted
-            # index is dearer out of HBM, PERF.md §7 (1)).  A hint
-            # only: a home that is off (shards that share a top limb, an
-            # empty shard) costs passes, never an answer.  The reply
-            # hash is keyed by the GLOBAL q_index, which travels with
-            # its lane; live counts and cuts are sums over lanes; pack
-            # keeps lane order, so a narrow sub-wave stays grouped; and
-            # the outputs are put back in the caller's order below.
+            # about q_local / t, its gather covers them in one window,
+            # and its chunk is, to a few hundred lanes, the lookups
+            # whose rows it holds.  A stable partition on a log2(t)-bit
+            # key that carries the operands (never a sort by the target:
+            # a sorted index is dearer out of HBM, PERF.md §7 (1)).  A
+            # hint only: a home that is off (shards that share a top
+            # limb, an empty shard) costs passes, never an answer.  The
+            # reply hash is keyed by the GLOBAL q_index, which travels
+            # with its lane; pack keeps lane order, so a shard's narrow
+            # sub-wave stays grouped; and the outputs are put back in
+            # the caller's order below.  A wave under WINDOW_MIN_LANES
+            # is chunked in the caller's order; its homes are counted
+            # all the same.
             @device_stage("group")
             def group(first_row, targets, q_index):
                 first = lax.all_gather(
                     jnp.where(n_local > 0, first_row, _U32(0xFFFFFFFF)), "t")
                 home = sum((targets[:, 0] >= first[s]).astype(jnp.int32)
                            for s in range(1, n_t))
-                _, q_index, *limbs = lax.sort(
-                    (home, q_index) + tuple(targets[:, l]
-                                            for l in range(N_LIMBS)),
-                    num_keys=1, is_stable=True)
-                return jnp.stack(limbs, axis=1), q_index
+                if grouped:
+                    home, q_index, *limbs = lax.sort(
+                        (home, q_index) + tuple(targets[:, l]
+                                                for l in range(N_LIMBS)),
+                        num_keys=1, is_stable=True)
+                    targets = jnp.stack(limbs, axis=1)
+                if not chunked:
+                    return targets, q_index, q_index, home_lanes
+                # lane i runs on shard i // chunk
+                ran_at = jnp.arange(q_local, dtype=jnp.int32) // chunk
+                mine = lax.axis_index("t") * chunk
+                return (lax.dynamic_slice_in_dim(targets, mine, chunk),
+                        lax.dynamic_slice_in_dim(q_index, mine, chunk),
+                        q_index, jnp.sum(home == ran_at, dtype=jnp.int32))
 
-            targets_local, q_index = group(sorted_shard[0, 0], targets_local,
-                                           q_index)
-        out = _lookup_engine(gather_planar, lower, n, targets_local,
-                             q_index, q_total, seed.astype(_U32),
-                             k=k, alpha=alpha, search_nodes=search_nodes,
-                             max_hops=max_hops, state_limbs=state_limbs,
-                             block_bounds=block_bounds, **churn)
+            # wave_order: whose lookup each lane of the wave holds, the
+            # chunks side by side in rank order
+            targets_local, q_index, wave_order, home_lanes = group(
+                sorted_shard[0, 0], targets_local, q_index)
+        out = _lookup_engine(
+            gather_planar, lower, n, targets_local, q_index, q_total,
+            seed.astype(_U32), k=k, alpha=alpha, search_nodes=search_nodes,
+            max_hops=max_hops, state_limbs=state_limbs,
+            block_bounds=block_bounds, **churn,
+            # the fullest shard's live count: the loops hold collectives
+            # over t, so every t-rank runs the same rounds and cuts in
+            # the same one, when every shard's survivors fit
+            live_count=(lambda done: lax.pmax(jnp.sum(~done), "t"))
+            if chunked else None)
+        per_lookup = {name: out[name]
+                      for name in ("nodes", "dist", "hops", "converged")}
+        if chunked:
+            @device_stage("group")
+            def whole_wave(per_lookup, counts):
+                # the chunks' outputs, side by side in rank order: the
+                # (grouped) wave's; and the shards' counts, summed
+                return jax.tree.map(
+                    lambda a: lax.all_gather(a, "t", axis=0, tiled=True),
+                    per_lookup), lax.psum(counts, "t")
+
+            per_lookup, counts = whole_wave(
+                per_lookup,
+                {"expired_peers": out["expired_peers"]} if delta_rows else {})
+            out.update(counts)
         if grouped:
             @device_stage("group")
-            def ungroup(q_index, per_lookup):
+            def ungroup(wave_order, per_lookup):
                 # lane i holds the lookup of the caller's lane
-                # q_index[i] - q_index.min(): the inverse of that
+                # wave_order[i] - wave_order.min(): the inverse of that
                 # permutation by one more sort, and a gather each
-                back = lax.sort((q_index, jnp.arange(q_local,
-                                                     dtype=jnp.int32)),
+                back = lax.sort((wave_order, jnp.arange(q_local,
+                                                        dtype=jnp.int32)),
                                 num_keys=1)[1]
                 return jax.tree.map(
                     lambda a: jnp.take(a, back, axis=0), per_lookup)
 
-            out.update(ungroup(q_index, {name: out[name] for name in
-                                         ("nodes", "dist", "hops",
-                                          "converged")}))
+            per_lookup = ungroup(wave_order, per_lookup)
+        out.update(per_lookup, home_lanes=home_lanes)
         # the wave's in-loop gathers that EVERY shard served in one pass
         out["window_rounds"] = lax.pmin(out["window_rounds"], "t")
-        # one count a q-rank (t-ranks hold the same search state and
-        # cut in the same round)
+        # one count a q-rank (t-ranks run the same rounds and cut in the
+        # same one; the other counts are sums or the least over them)
         return {name: value[None] if name in per_rank else value
                 for name, value in out.items()}
 
-    per_rank = ("narrow_rounds", "expired_peers", "window_rounds")
+    per_rank = ("narrow_rounds", "expired_peers", "window_rounds",
+                "home_lanes")
     in_specs = ((P("t", None), P("t", None), P(), P(), P("t", None),
                  P("q", None), P()) if weighted else
                 (P("t", None), P("t", None), P(), P(), P("q", None), P()))
     out_specs = {"nodes": P("q", None), "dist": P("q", None, None),
                  "hops": P("q"), "converged": P("q"), "narrow_rounds": P("q"),
-                 "window_rounds": P("q")}
+                 "window_rounds": P("q"), "home_lanes": P("q")}
     if delta_rows:
         in_specs += (P("t"), P("t", None), P("t"), P("t", None))
         out_specs["expired_peers"] = P("q")
@@ -830,27 +985,35 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
     split by ``shard_table_state``.  That contiguity is what makes the
     distributed primitives cheap:
 
-    - positioning (once per wave): global lower_bound = ONE psum of
+    - positioning (once per wave): global lower_bound = ONE sum of
       per-shard local counts;
     - reply-block edges (per hop): two LOCAL reads of the replicated
       global block LUT — ZERO collectives (see
       :func:`build_tp_lookup`);
-    - row fetch (per hop): owner-shard gather + ONE psum — the round's
-      only in-loop collective, O(queries·k) bytes, never O(table).  A
-      shard gathers only the lane window that holds the lookups whose
-      targets live in its key range (a wave is grouped by home shard
-      first; :func:`build_tp_lookup`, THE HOME-LANE WINDOW): about a
-      ``t``-th of the round's indices and a sixteenth, not all of them.
+    - row fetch (per hop): owner-shard gather inside ONE lane exchange
+      (:func:`lane_exchange`) — the round's only in-loop collectives,
+      O(queries·k) bytes, never O(table).  A shard gathers only the
+      lane window that holds the lookups whose targets live in its key
+      range (a wave is grouped by home shard first;
+      :func:`build_tp_lookup`, THE HOME-LANE WINDOW): about a ``t``-th
+      of the round's indices and a sixteenth, not all of them.
 
-    Search state is sharded over ``q`` and replicated over ``t``
-    (deterministic identical compute per t-rank, like the merge
-    re-sort in :func:`sharded_window_lookup`).  Results are
+    Search state is sharded over ``q`` AND over ``t`` (PR 38;
+    :func:`build_tp_lookup`, THE SEARCH STATE): each ``t``-rank runs a
+    lookup's rounds — LUT edge reads, merge sorts, selection — for an
+    equal chunk of its ``q``-rank's wave, ``lane_chunk(Q / q, t)``
+    lanes, and only the three reads above cross the mesh; a wave ``t``
+    does not divide runs whole on every rank.  Results are
     BIT-IDENTICAL to :func:`~opendht_tpu.core.search.simulate_lookups`
     on the same table, in the caller's order (the reply hash is seeded
     by global query identity, which travels with a lookup's lane) —
-    asserted in tests/test_sharded.py.  The result also carries
-    ``window_rounds`` [q]: the wave's loop rounds in which every shard
-    gathered in one pass (``dht_search_window_rounds{mode="tp"}``).
+    asserted in tests/test_sharded.py.  ``narrow_rounds`` [q] counts
+    the rounds after the cut of a CHUNK (``NARROW_MIN_WAVE`` applies to
+    the chunk's width).  The result also carries ``window_rounds`` [q]:
+    the wave's loop rounds in which every shard gathered in one pass
+    (``dht_search_window_rounds{mode="tp"}``), and ``home_lanes`` [q]:
+    the lanes that ran on the shard whose key range holds their target
+    (``dht_search_home_lanes{mode="tp"}``).
 
     Callers serving a stable table should pass ``state=`` from
     ``sharded_global_sort`` or

@@ -93,7 +93,8 @@ def test_sim_cell_at_toy_size_reports_every_registry_metric(manifest, trace):  #
 HOST4_REGISTRY_METRICS = {"host4_record_ms_per_wave",
                           "host4_dispatch_ms_per_wave", "host4_table_build_s",
                           "host4_narrow_rounds_per_wave",
-                          "host4_window_rounds_per_wave"}
+                          "host4_window_rounds_per_wave",
+                          "host4_home_lanes_per_wave"}
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -192,7 +193,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
              for f in sorted(os.listdir(mdir))}
     staged = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage"}
-    assert len(staged) == 31
+    assert len(staged) == 32
     assert {name: s["stage"] for name, s in staged.items()
             if s["value"] == "stage_ms_per"} == {
         "sim_fetch_ids_ms_per_wave": "fetch_ids",
@@ -233,7 +234,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
     assert {name for name, s in staged.items()
             if s["value"] == "unstaged_share"} \
         == {"sim_unstaged_share", "churn_unstaged_share",
-            "host4churn_unstaged_share"}
+            "host4_unstaged_share", "host4churn_unstaged_share"}
     # every stage a metric names is one the program names: those of the
     # round engine, the tp twin's collective, the churn model's two, the
     # mutable table's two and the sharded mutable table's two more
@@ -241,7 +242,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
     from opendht_tpu.ops import churn_table
     from opendht_tpu.parallel import sharded
     import inspect
-    assert 'device_stage("owner_merge")' in inspect.getsource(sharded)
+    assert '"owner_merge"' in inspect.getsource(sharded.build_tp_lookup)
     for name in ("expire", "delta_window"):
         assert f'device_stage("{name}")' in inspect.getsource(search)
     for name in ("table_apply", "table_compact"):
@@ -399,7 +400,8 @@ TP_CHURN_REGISTRY_METRICS = {
     "host4churn_expired_peers_per_wave", "host4churn_tick_ms",
     "host4churn_record_ms_per_wave", "host4churn_dispatch_ms_per_wave",
     "host4churn_narrow_rounds_per_wave",
-    "host4churn_window_rounds_per_wave"}
+    "host4churn_window_rounds_per_wave",
+    "host4churn_home_lanes_per_wave"}
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -430,7 +432,7 @@ def test_every_metric_of_the_tp_churn_cell_is_in_the_manifest(manifest):
     assert config["driver"] == "sim_tp_churn" and _cell["chips"] == 4
     listed = {m["name"] for m in manifest["per_layer"]
               if cell in m.get("workloads", ())}
-    assert listed == set(files) and len(listed) == 22
+    assert listed == set(files) and len(listed) == 23
     assert all(m["cells"] == [cell] for m in files.values())
     for m in manifest["end_to_end"]:
         if m["name"] != "setup_s":

@@ -285,6 +285,38 @@ def test_cut_with_nobody_live(n_ids):
                                             seed=3, state_limbs=2))
 
 
+def test_an_explicit_live_count_hook_is_the_engine_without_one(
+        cut_network, monkeypatch):
+    """PR 38: the live count that the loop conditions and the cut rule
+    read is a hook (the tp twin hands in the fullest shard's).  Left out
+    it is ``jnp.sum(~done)`` — the goldens below and the lowered text's
+    hash in tests/test_sharded.py pin that — and handed in as exactly
+    that it gives the same outputs and the same ``narrow_rounds``, asked
+    once a loop at that loop's width."""
+    import jax
+    from opendht_tpu.core import search as S
+    from opendht_tpu.core.search import NARROW_DIVISOR, NARROW_MIN_WAVE
+
+    sorted_ids, n, targets = cut_network
+    targets = targets[:NARROW_MIN_WAVE]
+    kw = dict(seed=11, alpha=2, state_limbs=2)
+    out = S._simulate_lookups_jit(sorted_ids, n, targets, **kw)
+    engine, asked = S._lookup_engine, []
+
+    def hooked(*args, **kwargs):
+        def live_count(done):
+            asked.append(done.shape[0])
+            return jnp.sum(~done)
+        return engine(*args, live_count=live_count, **kwargs)
+
+    monkeypatch.setattr(S, "_lookup_engine", hooked)
+    ref = jax.jit(functools.partial(
+        S._simulate_lookups_jit.__wrapped__, **kw))(sorted_ids, n, targets)
+    assert set(asked) == {NARROW_MIN_WAVE, NARROW_MIN_WAVE // NARROW_DIVISOR}
+    assert int(ref["narrow_rounds"]) == int(out["narrow_rounds"]) == 1
+    _assert_same_outputs(out, ref)
+
+
 def test_engine_reply_stream_goldens():
     """The deterministic reply streams are pinned by committed goldens
     (tests/goldens/search_engine.json): the round-6 ROUND-FUSED engine
@@ -535,7 +567,10 @@ def _assert_engine_shape(jaxpr, table_rows, W, k, alpha, staged,
     twin, α·k·``window``: a shard gathers over its lane window, in a
     pass loop of its own (``parallel.sharded.window_gather``), and only
     the lookup-major final fetch is left outside every loop, which a
-    5-limb state does not need (``final_fetch``)."""
+    5-limb state does not need (``final_fetch``); the widest gather
+    outside the loops is then one of the W probes of the once-a-wave
+    positioning (the twin's bootstrap reads the LUT edges of its own
+    chunk of the wave: 2·W/t indices, PR 38)."""
     widest = {True: 0, False: 0}
     sliced_in_loop = 0
     for eqn, in_loop in _walk_equations(jaxpr):
@@ -547,8 +582,7 @@ def _assert_engine_shape(jaxpr, table_rows, W, k, alpha, staged,
             widest[in_loop] = max(widest[in_loop], n_idx)
     assert (sliced_in_loop > 0) == staged
     assert widest[True] == alpha * k * (window or W)
-    # (without it the bootstrap's two LUT edge reads are the widest)
-    assert widest[False] == (k * W if final_fetch or not window else 2 * W)
+    assert widest[False] == (k * W if final_fetch or not window else W)
 
 
 # 10M rows: the 2-limb view is 80 MB and is staged; a 25,060,864-row

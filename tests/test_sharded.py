@@ -228,11 +228,14 @@ def test_tp_simulate_mesh_geometries(q, t):
 @pytest.mark.parametrize("q,t", [(1, 4), (2, 2)])
 def test_tp_simulate_equals_one_chip_with_the_cut_engaged(q, t, layout):
     """SURVIVOR COMPACTION on a mesh (core/search.py _lookup_engine):
-    each q-rank's wave is wide enough to cut, so it packs its survivors
-    and runs its last rounds narrow — with the round's one psum at the
+    the search state is sharded over ``t`` too (PR 38), so the width
+    that cuts is a shard's CHUNK: each t-rank's chunk of each q-rank's
+    wave is wide enough to cut, so every rank packs its own survivors
+    and runs its last rounds narrow — with the round's exchange at the
     narrow width — and the results equal one chip's, which cuts too.
-    Every t-rank holds the same search state, so they cut together; a
-    q-rank cuts when ITS survivors fit (one count a rank).  Lookups are
+    The t-ranks hold different lookups but are handed ONE live count
+    (the fullest shard's), so they cut together; a q-rank's ranks cut
+    when THEIR survivors fit (one count a q-rank).  Lookups are
     already done in the wide rounds before the cut (their rows are -1),
     and every shard's gather reads spare rows for those lanes and for
     the lanes other shards own (``owner_local_index``): on the uniform
@@ -240,12 +243,15 @@ def test_tp_simulate_equals_one_chip_with_the_cut_engaged(q, t, layout):
     ``sharded_global_sort``, whose shards own unequal row counts under
     one capacity."""
     from opendht_tpu.core.search import NARROW_MIN_WAVE
+    from opendht_tpu.parallel.sharded import lane_chunk
     m = make_mesh(4, q=q, t=t)
     k1, k2 = jax.random.split(jax.random.PRNGKey(3000))
     n = 3000 if layout == "uniform" else 4096
     ids = jax.random.bits(k1, (n, 5), dtype=jnp.uint32)
     sorted_ids, _, n_valid = sort_table(ids)
-    targets = jax.random.bits(k2, (q * NARROW_MIN_WAVE, 5), dtype=jnp.uint32)
+    Q = q * t * NARROW_MIN_WAVE
+    assert lane_chunk(Q // q, t) == NARROW_MIN_WAVE
+    targets = jax.random.bits(k2, (Q, 5), dtype=jnp.uint32)
     kw = dict(seed=11, alpha=2, state_limbs=2)
     ref = simulate_lookups(sorted_ids, n_valid, targets, **kw)
     if layout == "uniform":
@@ -260,12 +266,10 @@ def test_tp_simulate_equals_one_chip_with_the_cut_engaged(q, t, layout):
     narrow = np.asarray(out["narrow_rounds"])
     assert narrow.shape == (q,) and (narrow >= 1).all()
     assert int(ref["narrow_rounds"]) >= 1
-    if q == 1:
-        assert narrow[0] == int(ref["narrow_rounds"])
     # lookups were dead in a wide round: some took fewer hops than the
     # round the wave cut in, so their lanes carried -1 rows at full width
     hops = np.asarray(ref["hops"])
-    assert hops.min() < hops.max() - int(ref["narrow_rounds"])
+    assert hops.min() < hops.max() - int(narrow.max())
     for key in ("nodes", "dist", "hops", "converged"):
         np.testing.assert_array_equal(np.asarray(out[key]),
                                       np.asarray(ref[key]), err_msg=key)
@@ -871,3 +875,172 @@ def test_one_chip_program_is_the_parents():
         search_nodes=14, state_limbs=2).as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == (
         "e13fcbd00248f195034072b8f79c8e6d90dee22651f20afdd2ee17fa6cdff206")
+
+
+# -- PR 38: the search state sharded over t (parallel/sharded.py
+# build_tp_lookup, THE SEARCH STATE; lane_chunk, lane_exchange) ---------------
+# A t-rank runs the engine over its own chunk of the (grouped) wave, and
+# only the engine's primitives cross the mesh.  Every bit-identity test
+# above already runs that way (any wave t divides is chunked); the cases
+# below are the ones the chunking itself brings.
+
+def test_lane_chunk_is_a_function_of_the_lane_count():
+    from opendht_tpu.parallel.sharded import lane_chunk
+    assert lane_chunk(65536, 4) == 16384 and lane_chunk(2048, 4) == 512
+    assert lane_chunk(64, 8) == 8 and lane_chunk(4, 4) == 1
+    # one shard, or a wave t does not divide: the chunk is the whole wave
+    for lanes, n_t in ((65536, 1), (2050, 4), (66, 4), (3, 4)):
+        assert lane_chunk(lanes, n_t) == lanes
+
+
+def test_deep_lookups_in_one_shards_chunk_cut_with_the_rest():
+    """(a) Live counts that differ across ``t``: three shards of a
+    row-split table hold ids spread over the key space, the last holds
+    as many in a band 2^-14 of it wide, so the lookups whose home it is
+    run hops longer than everybody else's — and, grouped, they ARE the
+    last rank's chunk (4,096 lanes a home, exactly).  The other ranks'
+    lookups are done rounds before: every rank keeps running rounds
+    (the loop bodies hold collectives over ``t``) and cuts when the
+    FULLEST shard's survivors fit, in the same round — no hang, one
+    ``narrow_rounds`` — and the outputs are the one-chip engine's."""
+    from opendht_tpu.core.search import NARROW_MIN_WAVE
+    from opendht_tpu.parallel.sharded import lane_chunk
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    t, per_shard = 4, 2048
+    rng = np.random.default_rng(3800)
+    band = np.uint32(0xFFFC0000)
+    ids = _rand_ids(rng, t * per_shard)
+    ids[:3 * per_shard, 0] = rng.integers(0, band, 3 * per_shard,
+                                          dtype=np.uint32)
+    ids[3 * per_shard:, 0] |= band
+    sorted_ids, _, n_valid = sort_table(jnp.asarray(ids))
+    first = np.asarray(sorted_ids)[::per_shard, 0].astype(np.int64)
+    assert first[3] >= band                       # shard 3 is the band
+    Q = t * NARROW_MIN_WAVE
+    assert lane_chunk(Q, t) == NARROW_MIN_WAVE
+    home = rng.permutation(np.arange(Q) % t)      # 4,096 lanes a home
+    targets = _rand_ids(rng, Q)
+    edges = np.append(first, 1 << 32)
+    targets[:, 0] = (edges[home] + rng.integers(0, 1 << 32, Q)
+                     % (edges[home + 1] - edges[home])).astype(np.uint32)
+    kw = dict(seed=38, alpha=3, state_limbs=2)
+    ref = simulate_lookups(sorted_ids, n_valid, jnp.asarray(targets), **kw)
+    hops = np.asarray(ref["hops"])
+    # the band's lookups are the deep ones: when the last of the others
+    # ends, three ranks in four have nobody live, and most of the
+    # band's run on for rounds
+    others = hops[home != 3].max()
+    assert (hops[home == 3] > others).mean() > 0.5 and hops.max() > others + 2
+    out = tp_simulate_lookups(make_mesh(t, q=1, t=t), np.asarray(sorted_ids),
+                              n_valid, targets, **kw)
+    for key in ("nodes", "dist", "hops", "converged"):
+        np.testing.assert_array_equal(np.asarray(out[key]),
+                                      np.asarray(ref[key]), err_msg=key)
+    assert np.asarray(out["converged"]).all()
+    assert np.asarray(out["home_lanes"]).tolist() == [Q]
+    narrow = np.asarray(out["narrow_rounds"])
+    assert narrow.shape == (1,) and 1 <= narrow[0] < hops.max()
+
+
+@pytest.mark.parametrize("q,t,lanes,chunked,grouped", [
+    pytest.param(1, 4, 66, False, False, id="t_does_not_divide"),
+    pytest.param(1, 4, 2050, False, True, id="t_does_not_divide_grouped"),
+    pytest.param(1, 4, 256, True, False, id="under_window_min_lanes"),
+    pytest.param(1, 4, 4, True, False, id="one_lane_a_shard"),
+    pytest.param(2, 2, 4096, True, True, id="q2_t2_sharded_over_both"),
+    pytest.param(2, 2, 1030, False, False, id="q2_t2_whole_waves"),
+])
+def test_tp_simulate_equals_one_chip_at_every_chunking(window_network, q, t,
+                                                       lanes, chunked,
+                                                       grouped):
+    """(b), (c) One rule from ``(q_local, t)`` (``lane_chunk``), one
+    program: a wave ``t`` does not divide runs whole on every rank (the
+    exchange is its ``psum``; ``home_lanes`` is then every lane), a toy
+    wave under ``WINDOW_MIN_LANES`` is chunked in the caller's order,
+    not grouped, and with ``q`` = 2, ``t`` = 2 the state is sharded
+    over both axes, each q-rank grouping and chunking its own lanes.
+    All equal the one-chip engine bit for bit, in the caller's order."""
+    from opendht_tpu.parallel.sharded import lane_chunk, window_width
+    ids, sorted_ids, n_valid, _mesh, _state = window_network
+    q_local = lanes // q
+    assert (lane_chunk(q_local, t) < q_local) == chunked
+    assert (window_width(q_local, t) < q_local) == grouped
+    mesh = make_mesh(q * t, q=q, t=t)
+    targets = _uniform_targets(3800 + lanes, lanes)
+    out = tp_simulate_lookups(mesh, targets=targets,
+                              state=sharded_global_sort(mesh, ids),
+                              **WINDOW_KW)
+    _assert_equals_one_chip(out, sorted_ids, n_valid, targets)
+    at_home = np.asarray(out["home_lanes"])
+    assert at_home.shape == (q,) == np.asarray(out["window_rounds"]).shape
+    if chunked:
+        assert ((0 <= at_home) & (at_home <= q_local)).all()
+        assert at_home.sum() > lanes // 2 or not grouped
+    else:
+        assert at_home.tolist() == [q_local] * q
+
+
+@pytest.mark.parametrize("wave", ["one_a_home_in_order", "one_home"])
+def test_home_lanes_counts_the_lanes_that_run_where_their_rows_are(
+        window_network, wave):
+    """(f) ``home_lanes``: a toy wave of one target a home, in the order
+    of the shards (chunks of ONE lane, each its shard's own), reads W;
+    a wave whose targets all lie in shard 2's key range reads only the
+    lanes that happen to run there — a quarter — toy or grouped."""
+    _ids, sorted_ids, n_valid, mesh, state = window_network
+    if wave == "one_a_home_in_order":
+        targets = _uniform_targets(3830, 4)
+        targets[:, 0] = (np.arange(4, dtype=np.uint32) << 30) | (
+            targets[:, 0] >> 2)
+        want = [4]
+    else:
+        targets = _uniform_targets(3831)
+        targets[:, 0] = (targets[:, 0] >> 2) | np.uint32(2 << 30)
+        want = [WINDOW_Q // 4]
+    out = tp_simulate_lookups(mesh, targets=targets, state=state,
+                              **WINDOW_KW)
+    _assert_equals_one_chip(out, sorted_ids, n_valid, targets)
+    assert np.asarray(out["home_lanes"]).tolist() == want
+
+
+def test_a_lookups_rounds_run_on_one_shard_by_the_lowered_shapes():
+    """(e) The counts, from shapes: in the lowered text of
+    ``build_tp_lookup`` at a toy geometry (256 lookups, t = 4, α = 3,
+    S = 14, k = 8) every read of the replicated block LUT has 2·P·W/t
+    indices (P = α peers in a loop round, 1 in the bootstrap) and every
+    merge sort has W/t rows of S + P·k — where the program whose state
+    was replicated over ``t`` read 2·P·W and sorted W rows on every
+    chip — while the owner-shard gather still takes the whole wave's
+    index, all-gathered."""
+    import re
+    from opendht_tpu.parallel.sharded import build_tp_lookup
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    A = jax.ShapeDtypeStruct
+    u32, i32 = jnp.uint32, jnp.int32
+    rows, W, t, k, alpha, S = 4096, 256, 4, 8, 3, 14
+    block_lut = (1 << 12) + 1          # no other operand has this length
+    text = build_tp_lookup(make_mesh(t, q=1, t=t), rows, W, k, alpha, S, 48,
+                           2, True).lower(
+        A((t * rows, 5), u32), A((t, (1 << 10) + 1), i32),
+        A((block_lut,), i32), A((), i32), A((t, 2), i32), A((W, 5), u32),
+        A((), i32)).as_text()
+    lut_reads = {int(np.prod([int(d) for d in m.split("x")]))
+                 for m in re.findall(
+                     r'"stablehlo\.gather"[^\n]*: \(tensor<%dxi32>, '
+                     r'tensor<([0-9x]+)x1xi32>\)' % block_lut, text)}
+    assert lut_reads == {2 * alpha * W // t, 2 * W // t}
+    sorts = set(re.findall(
+        r'"stablehlo\.sort".*?\}\) : \(tensor<(\d+)x(\d+)xi32>', text,
+        flags=re.S))
+    assert sorts == {(str(W // t), str(S + alpha * k)),
+                     (str(W // t), str(S + k))}
+    # the row fetch crosses the mesh: the chunks' index all-gathered,
+    # the parts summed and cut back to the chunk, under stage owner_merge
+    assert "tensor<%dx%dxi32>) -> tensor<%dx%dxi32>" % (
+        alpha * k, W // t, alpha * k, W) in text
+    assert "tensor<2x%dx%dxui32>) -> tensor<2x%dx%dxui32>" % (
+        alpha * k, W, alpha * k, W // t) in text
+    assert '"stablehlo.all_reduce"' in text
+    assert '"stablehlo.all_gather"' in text

@@ -82,14 +82,17 @@ def _same_lookups(mesh, tb, one, targets, seed):
     ``simulate_lookups`` over the one-chip table, lookup for lookup.  A
     delta node is ``capacity + slot`` on one chip and ``t·capacity +
     slot`` sharded, the slot the place in the one sorted order of the
-    joined ids either way."""
+    joined ids either way.  ``expired_peers`` is the one-chip count:
+    each shard counts the requests of ITS chunk of the wave, and the
+    program sums them over ``t`` (PR 38).  ``narrow_rounds`` is not
+    compared: the width that cuts is a shard's chunk."""
     got = tp_simulate_lookups(mesh, targets=targets, state=tb.view,
                               seed=seed, **KW)
     want = simulate_lookups(one.view, None, targets, seed=seed, **KW)
-    for key in ("hops", "converged", "dist", "narrow_rounds"):
-        want_k = np.asarray(want[key])      # narrow_rounds: one a q-rank
-        np.testing.assert_array_equal(
-            np.asarray(got[key]).reshape(want_k.shape), want_k)
+    for key in ("hops", "converged", "dist"):
+        np.testing.assert_array_equal(np.asarray(got[key]),
+                                      np.asarray(want[key]), err_msg=key)
+    assert np.asarray(got["expired_peers"]).shape == (mesh.shape["q"],)
     assert int(np.sum(got["expired_peers"])) == int(want["expired_peers"])
     total = mesh.shape["t"] * tb.view.shard_n
     nodes = np.asarray(got["nodes"])
@@ -105,8 +108,10 @@ def _same_lookups(mesh, tb, one, targets, seed):
 def test_equals_the_one_chip_table_lookup_for_lookup(t):
     rng = np.random.default_rng(34 + t)
     mesh, tb, one, book = _pair(rng, t)
-    targets = jnp.asarray(_ids(rng, 4096))      # wide enough to cut (PR 29)
-    _same_lookups(mesh, tb, one, targets, 1)    # nobody left, nobody joined
+    # a chunk of 4,096 lanes a shard: wide enough to cut (PR 29, PR 38)
+    targets = jnp.asarray(_ids(rng, t * 4096))
+    got = _same_lookups(mesh, tb, one, targets, 1)  # nobody left or joined
+    assert int(got["narrow_rounds"][0]) >= 1
     expired = delta_nodes = 0
     for tick in range(5):
         _tick((tb, one), book, rng, 60, 60)
@@ -131,7 +136,8 @@ def test_equals_the_one_chip_table_over_lane_windows(wave):
     ONE shard's key range (that shard takes t passes a round, so no
     round counts for ``window_rounds``) and for targets within 12 live ids of a key
     range's edge (fallback windows that straddle it).  ``alive`` and
-    ``delta_window`` keep their full-width owner reads."""
+    ``delta_window`` keep their full-width owner reads, of the
+    all-gathered wave."""
     from opendht_tpu.parallel.sharded import window_width
     rng = np.random.default_rng(35)
     mesh, tb, one, book = _pair(rng, 4)
@@ -155,6 +161,26 @@ def test_equals_the_one_chip_table_over_lane_windows(wave):
         # (only a last round, whose few lookups still live — expired
         # peers keep stragglers going — span less than a window)
         assert rounds[0] <= 1 < np.max(got["hops"]) - 2
+
+
+@pytest.mark.parametrize("lanes, chunked", [(256, True), (258, False)],
+                         ids=["a_chunk_a_shard", "t_does_not_divide"])
+def test_expired_peers_is_the_one_chip_count(lanes, chunked):
+    """PR 38: each shard runs ``expire`` for ITS chunk of the wave and
+    counts the requests that found their peer gone; the program sums
+    the shards' counts once a wave, so the wave's ``expired_peers`` is
+    the one-chip engine's — also where ``t`` does not divide the wave
+    and every shard runs all of it (counted once, not ``t`` times)."""
+    from opendht_tpu.parallel.sharded import lane_chunk
+    rng = np.random.default_rng(38)
+    mesh, tb, one, book = _pair(rng, 4)
+    assert (lane_chunk(lanes, 4) < lanes) == chunked
+    for _ in range(5):
+        _tick((tb, one), book, rng, 60, 60)
+    assert tb.compactions == one.compactions == 0
+    got = _same_lookups(mesh, tb, one, jnp.asarray(_ids(rng, lanes)), 3)
+    assert int(got["expired_peers"][0]) > 0
+    assert np.asarray(got["converged"]).all()
 
 
 def test_one_chip_churn_program_is_the_parents():
@@ -206,11 +232,17 @@ def test_after_a_compaction_equals_a_fresh_build_of_the_live_ids(block_bits):
     assert len(book) == N + 60 == tb.n_live == tb.n_base
 
 
-def test_a_window_that_straddles_a_shard_edge_takes_rows_of_both_shards():
+@pytest.mark.parametrize("chunked", [False, True],
+                         ids=["whole_wave", "chunked"])
+def test_a_window_that_straddles_a_shard_edge_takes_rows_of_both_shards(
+        chunked):
     """The two churn primitives themselves, against the one-chip ones:
     the window of a target at the edge between shards 0 and 1 is the
     last four rows of shard 0's delta and the first four of shard 1's;
-    liveness is read on the owner, base row or delta row."""
+    liveness is read on the owner, base row or delta row.  With every
+    rank holding the whole wave (the exchange is one ``psum``) and with
+    each holding a quarter of it (``lane_exchange``: the shard that
+    asks is not the shard that owns)."""
     from jax.sharding import PartitionSpec as P
     from opendht_tpu.core.search import _churn_primitives
     from opendht_tpu.parallel.sharded import _tp_churn_primitives
@@ -230,23 +262,26 @@ def test_a_window_that_straddles_a_shard_edge_takes_rows_of_both_shards():
         jnp.asarray(targets))
     want_node = np.asarray(want_node)
     probe = np.concatenate([rng.integers(0, N, 500), want_node.reshape(-1)])
-    probe = probe[probe >= 0].astype(np.int32).reshape(1, -1)
+    probe = probe[probe >= 0]
+    probe = probe[:probe.size // 4 * 4].astype(np.int32).reshape(1, -1)
     want_alive = _churn_primitives(one.view)["alive"](jnp.asarray(probe))
 
     def local(shard_rows, tomb_bits, delta, n_delta, delta_lut, targets,
               nodes):
         prim = _tp_churn_primitives(
-            tb.view.shard_n, 1024, 4, shard_rows[0, 0], shard_rows[0, 1],
-            tomb_bits, delta, n_delta[0], delta_lut[0])
+            tb.view.shard_n, 1024, 4, chunked, shard_rows[0, 0],
+            shard_rows[0, 1], tomb_bits, delta, n_delta[0], delta_lut[0])
         node, ids = prim["delta_window"](targets)
         return node, jnp.stack(ids), prim["alive"](nodes)
 
     a = tb.view.arrays
+    lanes = "t" if chunked else None     # who holds which of the lookups
     node, ids, alive = jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(P("t", None), P("t"), P("t", None), P("t"), P("t", None),
-                  P(), P()),
-        out_specs=(P(), P(), P()), check_vma=False))(
+                  P(lanes, None), P(None, lanes)),
+        out_specs=(P(lanes, None), P(None, lanes, None), P(None, lanes)),
+        check_vma=False))(
         a["shard_rows"], a["tomb_bits"], a["delta"], a["n_delta"],
         a["delta_lut"], jnp.asarray(targets),
         jnp.asarray(np.where(probe >= c_one, probe - c_one + total, probe)))
@@ -262,7 +297,8 @@ def test_a_window_that_straddles_a_shard_edge_takes_rows_of_both_shards():
     # the edge targets' windows: the eight ids next to the edge, in order
     got = np.stack([np.asarray(ids[limb])[0] for limb in range(5)], axis=-1)
     np.testing.assert_array_equal(got, join)
-    _same_lookups(mesh, tb, one, jnp.asarray(targets), 4)
+    if chunked:
+        _same_lookups(mesh, tb, one, jnp.asarray(targets), 4)
 
 
 # -- membership (the cases of tests/test_churn_sim.py, over shards) ---------
@@ -403,8 +439,8 @@ def test_the_new_stages_are_named_where_they_run():
     for name in ("table_route", "table_apply", "table_compact",
                  "table_relayout"):
         assert f'device_stage("{name}")' in inspect.getsource(PC)
-    for name in ("alive_merge", "delta_merge"):
-        assert f'device_stage("{name}")' in inspect.getsource(sharded)
+    for name in ("alive_merge", "delta_merge"):      # lane_exchange's stage
+        assert f'"{name}"' in inspect.getsource(sharded._tp_churn_primitives)
     rng = np.random.default_rng(13)
     mesh, tb, _one, _book = _pair(rng, 4)
     a = tb.view.arrays

@@ -536,16 +536,19 @@ def test_stage_names_the_operations_of_the_compiled_round(staged_engine,
 def test_stage_names_reach_the_four_way_sharded_round(staged_tp_engine,
                                                       stage):
     """The tp twin runs the same engine under shard_map and inherits its
-    stages; its one in-loop collective is a stage of its own, the
-    all-reduce still one operation of the loop body, no call left."""
+    stages; the round's lane exchange is a stage of its own (PR 38: the
+    all-gather of the chunks' index and the all-reduce of the parts,
+    cut back to the chunk), each still one operation of the loop body
+    that carries the stage's name, no call left."""
     lowered, compiled = staged_tp_engine
     assert re.search(r"func\.func private @stage_%s(_\d+)?\(" % stage,
                      lowered)
     assert re.search(r'op_name="[^"]*/while/body/(?:[^"/]+/)*jit\(stage_%s\)/'
                      % stage, compiled)
     assert not re.search(r"[ =]call\(", compiled)
-    assert re.search(r'all-reduce[^\n]*op_name="[^"]*/while/body/(?:[^"/]+/)*'
-                     r'jit\(stage_owner_merge\)/', compiled)
+    for collective in ("all-gather", "all-reduce"):
+        assert re.search(collective + r'[^\n]*op_name="[^"]*/while/body/'
+                         r'(?:[^"/]+/)*jit\(stage_owner_merge\)/', compiled)
 
 
 def test_device_stage_names_the_jit_and_nothing_else():
