@@ -111,6 +111,26 @@ NARROW_DIVISOR = 8
 # 10M ids a cut of the last two rounds took 3.2 ms off a 4,096-lookup
 # wave of 28.2 and ADDED 3.0 to a 2,048-lookup wave of 15.2.
 NARROW_MIN_WAVE = 4096
+# LANE TILES (_lookup_engine): a loop wider than this runs each round
+# over its lanes a tile at a time.  What a round of this many lanes
+# writes and reads again — 25 MB of gathered rows behind a 12.6 MB
+# index, 3 MB of LUT edges — the TPU keeps in on-chip memory beside the
+# staged table view and the LUT; at eight times the width it cannot
+# (201 MB, 101 MB, 25 MB), and a gathered row then costs 6.04 ns where
+# this width pays 4.31, a LUT element 8.58 for 7.13.  Chosen from the
+# chip (PERF.md §6, PR 39): a wave of 1,048,576 lookups over 10M ids
+# takes 2,063.0 ms of device time untiled, 1,709.9 in tiles of this
+# width, 1,723.0 in tiles half as wide and 1,987.4 in tiles twice as
+# wide, whose rows fall out of on-chip memory again.
+ROUND_TILE_LANES = 131072
+
+
+def lane_tiles(width: int) -> int:
+    """The LANE TILES a loop of ``width`` lookups runs a round in: 1
+    (the round at once) up to ``ROUND_TILE_LANES`` and where the width
+    is no whole number of tiles."""
+    tiles = width // ROUND_TILE_LANES
+    return tiles if tiles > 1 and width % ROUND_TILE_LANES == 0 else 1
 
 
 def _mix32(x):
@@ -505,8 +525,9 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
 
     DEVICE STAGES: each part of a round runs through
     ``telemetry.device_stage`` — ``select``, ``block_bounds``,
-    ``reply_rows``, ``fetch_ids``, ``merge``, ``converge``, and once a
-    cut ``pack`` — an inner jit named ``stage_<name>``.  A part that is a function of its
+    ``reply_rows``, ``fetch_ids``, ``merge``, ``converge``, once a
+    cut ``pack``, and around a tile of a tiled round ``tile`` — an inner
+    jit named ``stage_<name>``.  A part that is a function of its
     arguments alone is decorated where it is defined; one that closes
     over values of the trace it runs in (the table, the LUT, the seed)
     is wrapped where it is CALLED, a fresh jit per call.  XLA inlines
@@ -560,6 +581,29 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     collectives are over ``t`` only).  The engine also returns
     ``narrow_rounds``, the number of rounds it ran under the wave's full
     width (0 = never cut).
+
+    LANE TILES: a round's lookups do not depend on each other (the one
+    cross-lane fact is the live count of the loop conditions), and what
+    a round costs on the TPU depends on where its intermediates live:
+    the gathered rows, their index and the LUT edges of up to
+    ``ROUND_TILE_LANES`` lanes stay in on-chip memory beside the staged
+    table view; those of a wider loop go through HBM.  So a loop wider
+    than that (:func:`lane_tiles`) runs each round as a ``fori_loop``
+    over tiles of its lanes: a tile's slice of the search state and of
+    the lanes' targets, positions, global indices and delta windows is
+    cut out, run through the round function a narrower loop runs whole,
+    and written back in place (stage ``tile``: the two copies, all that
+    tiling adds).  Reply streams are keyed by the lanes' global indices,
+    which the slice carries, so every output is the untiled engine's
+    bit for bit; counts that are sums add up over the tiles, and a
+    closure's ``one_pass`` counts a round once, where every tile's
+    gather was one.  Nothing selects this either: it is read off the
+    loop's width, a loop of one tile lowers to the program it was, and
+    a wave of 1,048,576 lookups runs eight tiles a round until its
+    first cut, to 131,072 lanes: one.  The engine returns
+    ``tiled_rounds``, the rounds it ran so, where the wave is wider
+    than a tile.  The bootstrap round and the once-a-wave work around
+    the loops stay at full width.
     """
     Q = targets.shape[0]
     S = search_nodes
@@ -729,6 +773,8 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         counts["expired_peers"] = jnp.int32(0)
     if one_pass is not None:
         counts["window_rounds"] = jnp.int32(0)
+    if lane_tiles(Q) > 1:
+        counts["tiled_rounds"] = jnp.int32(0)
 
     @device_stage("converge")
     def synced(cand_node, queried):
@@ -808,9 +854,15 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         return (jnp.where(gone, -1, x_rows), queried,
                 jnp.sum(gone, dtype=jnp.int32))
 
-    def make_body(tgt, pt, qidx, dw):
-        def body(state):
-            cand_node, cand_l, queried, hops, done, round_no, counts = state
+    def round_of(round_no):
+        """One loop round over SOME of a wave's lanes (all of them, or
+        one of its LANE TILES): ``step(lanes, state, counts)`` with
+        ``lanes`` their targets, positions, global indices and delta
+        windows and ``state`` their search state, returns the state and
+        the counts."""
+        def step(lanes, state, counts):
+            tgt, pt, qidx, dw = lanes
+            cand_node, cand_l, queried, hops, done = state
             sel, x_rows, x_d0, queried = select(cand_node, cand_l[0],
                                                 queried, done)
             if churn:
@@ -826,8 +878,51 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
                 counts = dict(counts, window_rounds=counts["window_rounds"]
                               + one_pass)
             hops, done = converge(cand_node, queried, sel, hops, done)
-            return (cand_node, cand_l, queried, hops, done, round_no + 1,
-                    counts)
+            return (cand_node, cand_l, queried, hops, done), counts
+        return step
+
+    def by_tiles(step, lanes, state, counts):
+        """LANE TILES: ``step`` over the lanes of ``state`` — at once
+        where they are no more than ``ROUND_TILE_LANES`` (or no whole
+        number of tiles), else a tile after the other inside the round:
+        a tile's slice of every array is cut out, stepped and written
+        back where it was (stage ``tile``: the copies are all this adds).
+        Returns the state, the counts and the number of tiles."""
+        tiles = lane_tiles(lanes[0].shape[0])
+        if tiles == 1:
+            return (*step(lanes, state, counts), 1)
+
+        @device_stage("tile")
+        def cut(i, wide):
+            return jax.tree.map(lambda a: lax.dynamic_slice_in_dim(
+                a, i * ROUND_TILE_LANES, ROUND_TILE_LANES), wide)
+
+        @device_stage("tile")
+        def paste(i, wide, part):
+            return jax.tree.map(lambda w, p: lax.dynamic_update_slice_in_dim(
+                w, p, i * ROUND_TILE_LANES, 0), wide, part)
+
+        def tile(i, carried):
+            state, counts = carried
+            part, counts = step(cut(i, lanes), cut(i, state), counts)
+            return paste(i, state, part), counts
+
+        return (*lax.fori_loop(0, tiles, tile, (state, counts)), tiles)
+
+    def make_body(tgt, pt, qidx, dw):
+        def body(state):
+            *lane_state, round_no, counts = state
+            lane_state, after, tiles = by_tiles(
+                round_of(round_no), (tgt, pt, qidx, dw), tuple(lane_state),
+                counts)
+            if tiles > 1:
+                after = dict(after, tiled_rounds=counts["tiled_rounds"] + 1)
+                if "window_rounds" in counts:
+                    # a round is one pass where every tile of it was
+                    after["window_rounds"] = counts["window_rounds"] + (
+                        after["window_rounds"] - counts["window_rounds"]
+                    ) // tiles
+            return (*lane_state, round_no + 1, after)
         return body
 
     if live_count is None:
@@ -982,6 +1077,9 @@ def _simulate_lookups_jit(sorted_ids, n_valid, targets, *, seed: int = 0,
       narrow_rounds int32     — rounds the wave ran under its full width
                                 (:func:`_lookup_engine`, SURVIVOR
                                 COMPACTION; 0 = one loop did it all)
+      tiled_rounds int32      — of a wave wider than ``ROUND_TILE_LANES``
+                                only: rounds it ran tile by tile
+                                (:func:`_lookup_engine`, LANE TILES)
 
     Single-device instantiation of :func:`_lookup_engine`.  The
     table-sharded multi-chip form (table rows partitioned over a mesh
@@ -1126,6 +1224,9 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     ``_lookup_engine`` names), the wave-width / hops distributions,
     and ``dht_search_narrow_rounds``: how many of the wave's rounds ran
     under its full width (the engine's own count, 0 = it never cut);
+    of a wave wider than ``ROUND_TILE_LANES`` also
+    ``dht_search_tiled_rounds``: how many of its rounds ran over its
+    lanes tile by tile (:func:`_lookup_engine`, LANE TILES);
     from the tp twin also ``dht_search_window_rounds``: how many of its
     loop rounds every shard gathered in one pass over its lane window,
     and ``dht_search_home_lanes``: how many of its lanes ran on the
@@ -1150,9 +1251,16 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     reg.histogram("dht_search_wave_seconds", mode=mode).observe(elapsed_s)
     reg.histogram("dht_search_wave_width", mode=mode).observe(wave_width)
     # ONE fetch for all: the counts ride the copy of ``hops``
-    hops, narrow, expired, windowed, at_home = jax.device_get(
+    hops, narrow, expired, windowed, at_home, tiled = jax.device_get(
         (out["hops"], out["narrow_rounds"], out.get("expired_peers"),
-         out.get("window_rounds"), out.get("home_lanes")))
+         out.get("window_rounds"), out.get("home_lanes"),
+         out.get("tiled_rounds")))
+    if tiled is not None:
+        # a wave wider than ROUND_TILE_LANES: its rounds that ran over
+        # the lanes tile by tile (the engine's own count; a narrower
+        # wave runs none and the series does not exist)
+        reg.histogram("dht_search_tiled_rounds", mode=mode).observe(
+            int(tiled))
     if expired is not None:
         # CHURN: the wave's queried peers that were gone (the engine's
         # own count; on a frozen table the series does not exist)
@@ -1236,6 +1344,9 @@ def simulate_lookups(sorted_ids, n_valid, targets, **kw):
     (:func:`_lookup_engine`, SURVIVOR COMPACTION).  Nothing to
     configure, and results are bit-identical to one full-width loop;
     ``narrow_rounds`` in the result says how many rounds ran narrow.
+    A wave wider than ``ROUND_TILE_LANES`` runs its full-width rounds
+    over its lanes a tile at a time (LANE TILES, bit-identical too;
+    ``tiled_rounds``).
 
     Telemetry envelope over the compiled engine: :func:`_run_wave`'s
     three host-side spans (``perf_counter`` plus the matching

@@ -46,16 +46,24 @@ def _build() -> Optional[str]:
     if os.path.exists(out):
         return out
     srcs = [os.path.join(_SRC_DIR, s) for s in _SOURCES]
+    # a temporary of this process's own: several processes may build at
+    # once (the workers of a test run on a fresh cache), and each has to
+    # rename a whole file of its own into place
+    tmp = "%s.%d.tmp" % (out, os.getpid())
     cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           "-o", out + ".tmp"] + srcs
+           "-o", tmp] + srcs
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(out + ".tmp", out)
+        os.replace(tmp, out)
         return out
     except (subprocess.SubprocessError, OSError) as e:
         detail = getattr(e, "stderr", b"")
         log.warning("native build failed: %s %s", e,
                     detail.decode(errors="replace") if detail else "")
+        try:
+            os.remove(tmp)      # what a failed or timed-out g++ left
+        except OSError:
+            pass
         return None
 
 

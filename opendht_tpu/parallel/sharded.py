@@ -907,6 +907,9 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             # the same one, when every shard's survivors fit
             live_count=(lambda done: lax.pmax(jnp.sum(~done), "t"))
             if chunked else None)
+        # a chunk wide enough for LANE TILES counts its tiled rounds;
+        # the one-chip engine's series, not carried over the mesh
+        out.pop("tiled_rounds", None)
         per_lookup = {name: out[name]
                       for name in ("nodes", "dist", "hops", "converged")}
         if chunked:

@@ -28,3 +28,32 @@ def pytest_configure(config):
         "quick: fast broad-coverage smoke modules — `pytest -m quick` "
         "is the sub-minute iteration tier; the full suite (CI, "
         "pre-merge) runs everything")
+
+
+import pytest  # noqa: E402
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """``small_tiles(lanes)``: from that call to the end of the test a loop
+    of more than ``lanes`` lookups runs its rounds in LANE TILES
+    (``core.search.ROUND_TILE_LANES``, 131,072 on the chip) — the constant,
+    and builders that never traced under the other one: a jit's cache does
+    not know the constant it was traced with."""
+    def patch(lanes: int = 1024) -> None:
+        from opendht_tpu.core import search
+        from opendht_tpu.parallel import sharded
+        engine = search._simulate_lookups_jit.__wrapped__
+
+        def _simulate_lookups_jit(*args, **kw):
+            # a function of its own: jax keys its traces by the function
+            return engine(*args, **kw)
+
+        monkeypatch.setattr(search, "ROUND_TILE_LANES", lanes)
+        monkeypatch.setattr(search, "_simulate_lookups_jit", jax.jit(
+            _simulate_lookups_jit,
+            static_argnames=("k", "alpha", "search_nodes", "max_hops",
+                             "state_limbs", "block_mode")))
+        monkeypatch.setattr(sharded, "build_tp_lookup",
+                            sharded.build_tp_lookup.__wrapped__)
+    return patch

@@ -391,6 +391,19 @@ def test_table_telemetry_spans_counters_and_the_waves_histogram():
     assert gauges["dht_churn_delta_rows"] == tbl.n_delta
 
 
+def test_lane_tiles_sum_a_rounds_expired_peers(churned, small_tiles):
+    """Under churn a round in LANE TILES marks and counts the same
+    expired requests as the round at once (the tiles' counts add up)."""
+    tbl, _, _, targets, ref = churned
+    small_tiles(128)
+    out = jax.device_get(simulate_lookups(
+        tbl.view, None, jnp.asarray(targets), seed=3, state_limbs=2, **KW))
+    assert int(out["tiled_rounds"]) > 0 and "tiled_rounds" not in ref
+    for key in ref:
+        np.testing.assert_array_equal(out[key], ref[key], err_msg=key)
+    assert int(out["expired_peers"]) > 0
+
+
 def test_record_wave_on_a_frozen_table_has_no_expired_series():
     rng = np.random.default_rng(9)
     s, _, n = sort_table(jnp.asarray(_ids(rng, 512)))

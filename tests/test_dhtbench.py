@@ -35,6 +35,12 @@ or resurrected a node, its book (``dhtbench/reference_churn.py``) is held
 to a Python set, and the ``stage`` metric files are fourteen: the cell
 reads ``fetch_ids`` and the four stages of the churn model and of the
 mutable table through files of its own.
+
+PR 39's cell ``northstar-10m.wave-1048576`` (driver ``sim``, UNEDITED) is
+rehearsed at the narrowest wave the engine still cuts and with a tile a
+quarter of it, so that the metric of the rounds it runs in LANE TILES
+reads some, and its manifest entries are held to the configuration's
+file: one wave of 2^20 lookups — eight tiles — on one chip.
 """
 
 import json
@@ -86,6 +92,77 @@ def test_sim_cell_at_toy_size_reports_every_registry_metric(manifest, trace):  #
         assert set(m) == {"value", "unit"}
         assert m["value"] > 0 or (name in NARROW_ROUNDS_METRICS
                                   and m["value"] == 0)
+
+
+# PR 39: the north star's cell reads driver ``sim``'s registry metrics (its
+# driver hands them to every cell of its own; ``sim_narrow_rounds_per_wave``
+# names its one cell) and what the width added to the program: the rounds
+# the engine ran in LANE TILES (registry) and the tiles' copies (stage)
+NORTHSTAR = "northstar-10m.wave-1048576"
+NORTHSTAR_REGISTRY_METRICS = (SIM_REGISTRY_METRICS
+                              - {"sim_narrow_rounds_per_wave"}
+                              | {"northstar_tiled_rounds_per_wave"})
+
+
+@pytest.mark.parametrize("trace", [False, True])
+def test_northstar_cell_at_toy_size_tiles_cuts_and_prints_the_contract_line(
+        manifest, small_tiles, trace):
+    """The cell through ``drivers/sim.py`` at the narrowest wave that
+    still steps down (``NARROW_MIN_WAVE`` lookups: 4,096 -> 512 lanes),
+    with a tile of 1,024 lanes: its full-width rounds run in four tiles,
+    as the chip's run in eight of 131,072, and their count is read."""
+    from opendht_tpu.core.search import NARROW_MIN_WAVE as width
+    small_tiles(width // 4)
+    line = run.run_cell(NORTHSTAR, 2 ** 31 + 39039, 1.0, trace,
+                        rehearsal={"n_ids": 4096, "wave_targets": width,
+                                   "target_sets": 2})
+    line = json.loads(json.dumps(line))
+    assert set(line) == RESULT_KEYS
+    assert line["correct"] is True and line["failed"] == 0
+    assert line["attempted"] > 0 and line["attempted"] % width == 0
+    if trace:
+        assert set(line["metrics"]) == NORTHSTAR_REGISTRY_METRICS
+        assert line["metrics"]["northstar_tiled_rounds_per_wave"][
+            "value"] >= 4
+    else:
+        assert set(line["metrics"]) == {m["name"]
+                                        for m in manifest["end_to_end"]
+                                        if NORTHSTAR in m.get("workloads",
+                                                              (NORTHSTAR,))}
+        assert {"sim_lookups_per_s", "setup_s"} <= set(line["metrics"])
+    for m in line["metrics"].values():
+        assert set(m) == {"value", "unit"} and m["value"] > 0
+
+
+def test_the_northstar_cell_is_one_wave_of_a_million_on_one_chip(manifest):
+    from opendht_tpu.core.search import lane_tiles
+    cell, config, driver, files = run.resolve(NORTHSTAR)
+    assert driver.__name__ == "dhtbench.drivers.sim" and cell["chips"] == 1
+    assert (config["sizes"]["concurrent_lookups"]
+            == cell["traffic"]["wave_targets"] == 2 ** 20)
+    assert lane_tiles(2 ** 20) == 8 and lane_tiles(2 ** 16) == 1
+    # every metric file the harness hands the cell is in the manifest with
+    # the cell in its list, and the cell is in no other metric's list
+    listed = {m["name"] for m in manifest["per_layer"]
+              if NORTHSTAR in m.get("workloads", ())}
+    assert listed == set(files) and len(listed) == 14
+    assert "sim_narrow_rounds_per_wave" not in listed
+    per_layer = {m["name"]: m for m in manifest["per_layer"]}
+    for name, f in files.items():
+        assert all(per_layer[name][key] == f[key]
+                   for key in ("unit", "better", "layer", "moves"))
+    assert NORTHSTAR in [m for m in manifest["end_to_end"]
+                         if m["name"] == "sim_lookups_per_s"][0]["workloads"]
+    entry = [c for c in manifest["configs"] if c["name"] == config["name"]]
+    assert entry[0]["source"] == config["source"] and \
+        len(config["source"]) < 200 and \
+        entry[0]["reduced"] == config["reduced"] == ["chips"]
+    work = [w for w in manifest["workloads"] if w["name"] == NORTHSTAR]
+    assert work == [{"name": NORTHSTAR, "config": config["name"],
+                     "traffic": "wave-1048576", "chips": 1,
+                     "why": cell["why"]}] and len(cell["why"]) <= 200
+    four = [w for w in manifest["workloads"] if w["chips"] == 4]
+    assert len(four) <= len(manifest["workloads"]) // 2
 
 
 # the same for the four-chip cell (PR 28): its two envelope spans under
@@ -193,13 +270,14 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
              for f in sorted(os.listdir(mdir))}
     staged = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage"}
-    assert len(staged) == 32
+    assert len(staged) == 33
     assert {name: s["stage"] for name, s in staged.items()
             if s["value"] == "stage_ms_per"} == {
         "sim_fetch_ids_ms_per_wave": "fetch_ids",
         "sim_reply_rows_ms_per_wave": "reply_rows",
         "sim_block_bounds_ms_per_wave": "block_bounds",
         "sim_merge_ms_per_wave": "merge",
+        "northstar_tile_ms_per_wave": "tile",
         "host4_owner_merge_ms_per_wave": "owner_merge",
         "host4_fetch_ids_ms_per_wave": "fetch_ids",
         "host4_block_bounds_ms_per_wave": "block_bounds",
@@ -243,7 +321,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
     from opendht_tpu.parallel import sharded
     import inspect
     assert '"owner_merge"' in inspect.getsource(sharded.build_tp_lookup)
-    for name in ("expire", "delta_window"):
+    for name in ("expire", "delta_window", "tile"):
         assert f'device_stage("{name}")' in inspect.getsource(search)
     for name in ("table_apply", "table_compact"):
         assert f'device_stage("{name}")' in inspect.getsource(churn_table)
@@ -254,7 +332,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
     assert {s["stage"] for s in staged.values() if "stage" in s} \
         <= set(STAGES) | {"owner_merge", "expire", "delta_window",
                           "table_apply", "table_compact", "table_route",
-                          "table_relayout"}
+                          "table_relayout", "tile"}
     # the one metric that divides by a stage's time and not the window's
     shares = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage_share"}
@@ -436,7 +514,7 @@ def test_every_metric_of_the_tp_churn_cell_is_in_the_manifest(manifest):
     assert all(m["cells"] == [cell] for m in files.values())
     for m in manifest["end_to_end"]:
         if m["name"] != "setup_s":
-            assert m["workloads"][-1] == cell
+            assert cell in m["workloads"]
     entry = [c for c in manifest["configs"] if c["name"] == config["name"]]
     assert entry[0]["source"] == config["source"] and \
         entry[0]["reduced"] == config["reduced"] == ["chips"]

@@ -194,6 +194,43 @@ def test_helpers_raise_without_lib(monkeypatch):
         wrappers.UdpEngine(0)
 
 
+def test_concurrent_first_builds_all_load_the_library(tmp_path):
+    """Six processes that find an empty cache at once (the workers of a
+    test run under a fresh ``HOME``) each build and rename a file of
+    their own: every one ends with a library it can load.  With one
+    shared temporary name the first to finish took the others' file from
+    under them, and a whole test file skipped as ``unavailable``."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, OPENDHT_TPU_CACHE=str(tmp_path))
+    code = ("from opendht_tpu.native import build; "
+            "print(build.available())")
+    procs = [subprocess.Popen([sys.executable, "-c", code], env=env,
+                              stdout=subprocess.PIPE, text=True)
+             for _ in range(6)]
+    assert [p.communicate(timeout=300)[0].strip() for p in procs] \
+        == ["True"] * 6
+    assert [f for f in os.listdir(tmp_path) if f.endswith(".tmp")] == []
+
+
+def test_a_failed_build_leaves_no_temporary(tmp_path, monkeypatch):
+    """A compiler that dies after it began to write: no library, and the
+    process's own temporary is gone with it."""
+    import os
+    import subprocess
+    from opendht_tpu.native import build
+    monkeypatch.setenv("OPENDHT_TPU_CACHE", str(tmp_path))
+
+    def dies(cmd, **_kw):
+        open(cmd[cmd.index("-o") + 1], "wb").close()
+        raise subprocess.CalledProcessError(1, cmd, stderr=b"half way")
+
+    monkeypatch.setattr(build.subprocess, "run", dies)
+    assert build._build() is None
+    assert os.listdir(tmp_path) == []
+
+
 def test_udp_v6_roundtrip():
     with native.UdpEngine(0) as a, native.UdpEngine(0) as b:
         if not (a.has_v6 and b.has_v6):

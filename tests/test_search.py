@@ -208,6 +208,23 @@ def _in_sub_waves(sorted_ids, n, targets, q=4, **kw):
                                n, np.asarray(targets), **kw)
 
 
+def _record_live_count_widths(monkeypatch):
+    """Hand ``_lookup_engine`` the ``live_count`` hook it would default to
+    (``jnp.sum(~done)``) and return the list of widths it is asked at, one
+    entry a loop, filled as the engine is traced."""
+    from opendht_tpu.core import search as S
+    engine, asked = S._lookup_engine, []
+
+    def hooked(*args, **kwargs):
+        def live_count(done):
+            asked.append(done.shape[0])
+            return jnp.sum(~done)
+        return engine(*args, live_count=live_count, **kwargs)
+
+    monkeypatch.setattr(S, "_lookup_engine", hooked)
+    return asked
+
+
 def _assert_same_outputs(out, ref):
     for key in OUTPUTS:
         np.testing.assert_array_equal(np.asarray(out[key]),
@@ -262,6 +279,180 @@ def test_cut_edge_cases(cut_network, width, max_hops, narrow, unconverged):
         sorted_ids, n, targets, q=8 if width == 32768 else 4, **kw))
 
 
+# -- the north star's width (PR 39, dhtbench/configs/northstar-10m.json):
+# a wave of 2^20 lookups steps down THREE times (1,048,576 -> 131,072 ->
+# 16,384 -> 2,048 lanes).  Here the same cascade at a width the CPU runs
+# in half a minute: 64 x NARROW_MIN_WAVE lookups, 262,144 -> 32,768 ->
+# 4,096 -> 512.
+
+WIDE_KW = dict(seed=11, alpha=2, state_limbs=2)
+
+
+@pytest.fixture(scope="module")
+def wide_wave(cut_network):
+    """``cut_network``'s 3,000 ids under 64 x NARROW_MIN_WAVE targets of
+    its own (the fixture holds 8 x), run ONCE through the public entry."""
+    import jax
+    from opendht_tpu.core.search import NARROW_MIN_WAVE
+    sorted_ids, n, _ = cut_network
+    targets = jax.random.bits(jax.random.fold_in(jax.random.PRNGKey(3000), 39),
+                              (64 * NARROW_MIN_WAVE, 5), dtype=jnp.uint32)
+    return sorted_ids, n, targets, simulate_lookups(sorted_ids, n, targets,
+                                                    **WIDE_KW)
+
+
+def test_three_steps_down(wide_wave, monkeypatch):
+    """Three cuts — and two tiles — equal, bit for bit, the same targets
+    as eight untiled sub-waves of 32,768 — each of which steps down twice,
+    which ``two_steps_down`` ties to the uncut loops — and the live count
+    is asked at exactly the four widths."""
+    import jax
+    from opendht_tpu.core import search as S
+    from opendht_tpu.core.search import NARROW_DIVISOR, NARROW_MIN_WAVE
+    sorted_ids, n, targets, out = wide_wave
+    assert targets.shape[0] == 262144
+    # two LANE TILES wide at the constant the chip runs with: its six
+    # full-width rounds ran tile by tile, the sub-waves' whole
+    assert S.lane_tiles(262144) == 2 and int(out["tiled_rounds"]) == 6
+    assert int(out["narrow_rounds"]) == 3
+    assert np.asarray(out["converged"]).all()
+    assert np.asarray(out["hops"]).max() == 9
+    ref = _in_sub_waves(sorted_ids, n, targets, q=8, **WIDE_KW)
+    assert set(np.asarray(ref["narrow_rounds"]).tolist()) == {2, 3}
+    _assert_same_outputs(out, ref)
+
+    asked = _record_live_count_widths(monkeypatch)
+    jax.eval_shape(functools.partial(S._simulate_lookups_jit.__wrapped__,
+                                     **WIDE_KW), sorted_ids, n, targets)
+    widths = [64 * NARROW_MIN_WAVE // NARROW_DIVISOR ** i for i in range(4)]
+    assert asked == widths == [262144, 32768, 4096, 512]
+
+
+def test_a_wide_wave_agrees_with_the_plain_references(wide_wave):
+    """Hop statistics of a wave wider than 65,536 against the plain
+    reference ``scalar_lookup`` on 48 lanes drawn over the WHOLE index
+    range: medians within 2 rounds, the tolerance and the reason
+    ``test_hop_parity_with_scalar_reference`` states (the reference
+    draws its replies from a generator, the engine from a counter hash:
+    parity is statistical); and on the same lanes the closest-8 sets
+    against the exact XOR top-8 at sim-10m's 90%."""
+    sorted_ids, n, targets, out = wide_wave
+    lanes = np.random.default_rng(39).choice(targets.shape[0], 48,
+                                             replace=False)
+    assert (lanes > 65535).sum() >= 24 and lanes.max() > 200000
+    ids_np, t_np = np.asarray(sorted_ids), np.asarray(targets)[lanes]
+    hops_scalar = []
+    for i, t in enumerate(t_np):
+        _, h, conv = scalar_lookup(ids_np, int(n), t, alpha=2,
+                                   rng=np.random.default_rng(390 + i))
+        assert conv
+        hops_scalar.append(h)
+    hops_wide = np.asarray(out["hops"])[lanes]
+    assert abs(np.median(hops_wide) - np.median(hops_scalar)) <= 2, (
+        np.median(hops_wide), np.median(hops_scalar))
+    _, true_idx = xor_topk(jnp.asarray(t_np), sorted_ids, k=8)
+    nodes = np.asarray(out["nodes"])[lanes]
+    agree = sum(set(a.tolist()) == set(b.tolist())
+                for a, b in zip(nodes, np.asarray(true_idx)))
+    assert agree >= int(np.ceil(0.9 * len(lanes))), agree
+
+
+def test_the_reply_hash_counter_does_not_wrap_at_the_north_stars_width():
+    """``_reply_rows`` counts ``(round * q_total + q) * R + slot`` in
+    uint32: no two (round, lookup, slot) of a wave may alias.  At the
+    north star's sizes the largest counter is 1.23e9; a wider wave, a
+    larger alpha or a longer budget must not wrap it silently."""
+    import inspect
+    import json
+    import os
+    from opendht_tpu.core import search as S
+    with open(os.path.join(os.path.dirname(__file__), "..", "dhtbench",
+                           "configs", "northstar-10m.json")) as f:
+        sizes = json.load(f)["sizes"]
+    max_hops = inspect.signature(
+        S._simulate_lookups_jit.__wrapped__).parameters["max_hops"].default
+    assert max_hops == 48 and sizes["concurrent_lookups"] == 2 ** 20
+    # round_no runs 0 (the bootstrap) .. max_hops, q < q_total, slot < R
+    R = sizes["alpha"] * sizes["k"]
+    assert (max_hops + 1) * sizes["concurrent_lookups"] * R < 2 ** 32
+
+
+# -- LANE TILES (PR 39): a loop wider than ROUND_TILE_LANES runs a round
+# over its lanes a tile at a time.  ``wide_wave`` above is two tiles wide
+# at the constant the chip runs with; here the tile is 1,024 lanes, so a
+# wave the CPU runs in a second is four or eight tiles.
+
+@pytest.mark.parametrize("state_limbs", [2, 5])
+def test_a_round_in_lane_tiles_is_the_round_at_once(cut_network, small_tiles,
+                                                    state_limbs):
+    """Bit for bit the untiled engine's outputs, cut included; the engine
+    counts its tiled rounds — the full-width ones — and ``record_wave``
+    files them, for a wave wider than a tile only; the tp twin runs a
+    chunk wider than a tile in tiles too and keeps its own outputs."""
+    from opendht_tpu import telemetry
+    sorted_ids, n, targets = cut_network
+    targets = targets[:8192]
+    kw = dict(seed=11, alpha=2, state_limbs=state_limbs)
+    reg = telemetry.get_registry()
+    before = reg.snapshot()
+    ref = simulate_lookups(sorted_ids, n, targets, **kw)
+    assert "tiled_rounds" not in ref
+    series = 'dht_search_tiled_rounds{mode="single"}'
+    assert series not in telemetry.snapshot_diff(
+        before, reg.snapshot())["histograms"]
+
+    small_tiles(1024)
+    out = simulate_lookups(sorted_ids, n, targets, **kw)
+    _assert_same_outputs(out, ref)
+    rounds = int(np.asarray(ref["hops"]).max())
+    assert int(out["narrow_rounds"]) == int(ref["narrow_rounds"]) == 3
+    assert int(out["tiled_rounds"]) == rounds - 3 == 6
+    moved = telemetry.snapshot_diff(before, reg.snapshot())["histograms"]
+    assert (moved[series]["count"], moved[series]["sum"]) == (1, 6)
+
+    halves = _in_sub_waves(sorted_ids, n, targets, q=2, **kw)   # 4 tiles each
+    assert "tiled_rounds" not in halves
+    _assert_same_outputs(halves, ref)
+
+
+def test_a_tiled_round_counts_once_where_a_closure_reports(
+        cut_network, small_tiles, monkeypatch):
+    """A gather closure that reports (the tp twin's ``one_pass``): every
+    tile of a round reports, and the round counts as one pass once, where
+    every tile was one."""
+    from opendht_tpu.core import search as S
+    sorted_ids, n, targets = cut_network
+    gather = S.fused_gather_planar
+    monkeypatch.setattr(S, "fused_gather_planar", lambda *a: (
+        gather(*a), jnp.int32(1)))
+    small_tiles(1024)
+    out = simulate_lookups(sorted_ids, n, targets[:4096], seed=11, alpha=2,
+                           state_limbs=2)
+    rounds = int(np.asarray(out["hops"]).max())
+    assert int(out["window_rounds"]) == rounds == 7
+    assert int(out["tiled_rounds"]) == 6 and int(out["narrow_rounds"]) == 1
+
+
+def test_the_tile_stage_is_in_a_tiled_program_only(cut_network, small_tiles):
+    """Up to a tile's width the engine lowers to the program it was:
+    nothing is cut out or written back, and no operation carries the
+    stage's name."""
+    from opendht_tpu.core import search as S
+    sorted_ids, n, targets = cut_network
+    assert S.lane_tiles(S.ROUND_TILE_LANES) == 1
+    assert S.lane_tiles(8 * S.ROUND_TILE_LANES) == 8
+    assert S.lane_tiles(S.ROUND_TILE_LANES * 3 // 2) == 1   # no whole tiles
+
+    def lowered():
+        return S._simulate_lookups_jit.lower(
+            sorted_ids, n, targets[:4096], seed=11, alpha=2,
+            state_limbs=2).as_text()
+
+    assert "stage_tile" not in lowered()
+    small_tiles(1024)
+    assert "stage_tile" in lowered()
+
+
 @pytest.mark.parametrize("n_ids", [0, 5])
 def test_cut_with_nobody_live(n_ids):
     """Nobody live at the cut: an empty table (every lookup done before
@@ -301,15 +492,7 @@ def test_an_explicit_live_count_hook_is_the_engine_without_one(
     targets = targets[:NARROW_MIN_WAVE]
     kw = dict(seed=11, alpha=2, state_limbs=2)
     out = S._simulate_lookups_jit(sorted_ids, n, targets, **kw)
-    engine, asked = S._lookup_engine, []
-
-    def hooked(*args, **kwargs):
-        def live_count(done):
-            asked.append(done.shape[0])
-            return jnp.sum(~done)
-        return engine(*args, live_count=live_count, **kwargs)
-
-    monkeypatch.setattr(S, "_lookup_engine", hooked)
+    asked = _record_live_count_widths(monkeypatch)
     ref = jax.jit(functools.partial(
         S._simulate_lookups_jit.__wrapped__, **kw))(sorted_ids, n, targets)
     assert set(asked) == {NARROW_MIN_WAVE, NARROW_MIN_WAVE // NARROW_DIVISOR}
