@@ -220,7 +220,7 @@ def _lut_block_bounds(lut, t0, prefix_len):
     return g[0], g[1]
 
 
-def _guarded_lower_bound(sorted_ids, n, lut):
+def _guarded_lower_bound(sorted_ids, n, lut, top2=None):
     """Positioning closure: LUT-started bounded search when every LUT
     bucket fits the in-bucket step budget, else the full-depth binary
     search — decided ON DEVICE with one ``lax.cond`` per call site.
@@ -243,6 +243,12 @@ def _guarded_lower_bound(sorted_ids, n, lut):
     precondition (64-bit duplicate neighbors) take the full 5-limb
     search instead — exactness never depends on probabilistic
     assumptions.
+
+    ``top2``: the table's transposed top two limbs ``[2, N]``, taken
+    once by a caller that calls the closure once a lane window
+    (parallel/sharded.py ``window_lower_bound``), so that no call copies
+    them again; None takes them at the fast path's search and
+    correction, where one call a wave does.
     """
     N = sorted_ids.shape[0]
     # same budget _lower_bound will actually use (ONE shared definition)
@@ -263,7 +269,7 @@ def _guarded_lower_bound(sorted_ids, n, lut):
 
     def fast(q):
         lb = _lower_bound(sorted_ids, q, n, lut=lut, lut_steps=None,
-                          limbs=2)
+                          limbs=2, sorted_t=top2)
         # exact correction: row[lb] < q is only possible when the row's
         # top 64 bits EQUAL the probe's (the 64-bit search guarantees
         # row64 >= q64), so gather 2 limbs to detect equality and fetch
@@ -272,7 +278,8 @@ def _guarded_lower_bound(sorted_ids, n, lut):
         # probability ~N/2^64) — the common path pays 2/5 of the
         # correction gather
         cl = jnp.clip(lb, 0, N - 1)
-        g2 = jnp.take(sorted_t_full[:2], cl, axis=1)
+        g2 = jnp.take(sorted_t_full[:2] if top2 is None else top2, cl,
+                      axis=1)
         eq64 = (g2[0] == q[:, 0]) & (g2[1] == q[:, 1]) & (lb < nn)
 
         def tail_bump(_):
@@ -413,6 +420,14 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
           got, operation for operation (tests/test_sharded.py pins the
           lowered text's hash).
       lower(flat [M, 5]) -> [M] int32 global lower-bound positions.
+          Its optional count: a closure handed in with
+          ``block_bounds`` (so that the engine calls it once a wave, on
+          the targets, outside every loop) may return ``(positions,
+          passes)`` — ``passes`` an int32 of its own meaning (the tp
+          twin's: the lane windows a shard searched,
+          ``parallel/sharded.py window_lower_bound``) — and the engine
+          returns it as ``position_passes``; a closure that returns
+          bare positions gets the program it always got.
       block_bounds(t0, prefix_len) -> (lo, ub) prefix-block edges
           (optional third primitive): t0 = targets' first limb
           (broadcastable against prefix_len).  When provided (the
@@ -611,6 +626,8 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
     NL = state_limbs
 
     pos_t_full = lower(targets)                        # [Q], fallback replies
+    pos_t_full, position_passes = (pos_t_full if isinstance(pos_t_full, tuple)
+                                   else (pos_t_full, None))
     churn = alive is not None
     # CHURN: the delta rows around each target, positioned and fetched
     # once a wave — (node [Q, DW], 5 id planes [Q, DW]), node −1 = none
@@ -1039,6 +1056,8 @@ def _lookup_engine(gather_planar, lower, n, targets, q_index, q_total,
         "converged": converged & ~empty,
         "narrow_rounds": last_round - cut_round,
         **counts,
+        **({} if position_passes is None else
+           {"position_passes": position_passes}),
     }
 
 
@@ -1229,8 +1248,9 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     lanes tile by tile (:func:`_lookup_engine`, LANE TILES);
     from the tp twin also ``dht_search_window_rounds``: how many of its
     loop rounds every shard gathered in one pass over its lane window,
-    and ``dht_search_home_lanes``: how many of its lanes ran on the
-    shard that holds their rows.
+    ``dht_search_home_lanes``: how many of its lanes ran on the
+    shard that holds their rows, and ``dht_search_position_passes``:
+    over how many lane windows a shard searched the targets' positions.
     Shared by the single-device engine and the tp-sharded twin
     (``mode="tp"``, parallel/sharded.py); both time the whole of this
     call as ``dht_search_record_seconds``.
@@ -1251,10 +1271,10 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
     reg.histogram("dht_search_wave_seconds", mode=mode).observe(elapsed_s)
     reg.histogram("dht_search_wave_width", mode=mode).observe(wave_width)
     # ONE fetch for all: the counts ride the copy of ``hops``
-    hops, narrow, expired, windowed, at_home, tiled = jax.device_get(
+    hops, narrow, expired, windowed, at_home, tiled, passes = jax.device_get(
         (out["hops"], out["narrow_rounds"], out.get("expired_peers"),
          out.get("window_rounds"), out.get("home_lanes"),
-         out.get("tiled_rounds")))
+         out.get("tiled_rounds"), out.get("position_passes")))
     if tiled is not None:
         # a wave wider than ROUND_TILE_LANES: its rounds that ran over
         # the lanes tile by tile (the engine's own count; a narrower
@@ -1282,6 +1302,14 @@ def record_wave(out, elapsed_s: float, wave_width: int, *,
         # skewed one's: the lanes whose rows their own shard holds
         reg.histogram("dht_search_home_lanes", mode=mode).observe(
             int(np.sum(at_home)))
+    if passes is not None:
+        # the tp twin's: the lane windows over which the shard that took
+        # the most searched the wave's targets for their positions (one
+        # value a q-rank, each the most over its t-ranks;
+        # parallel/sharded.py window_lower_bound) — 1 where the wave's
+        # lanes grouped by home shard, up to t where they pile on one
+        reg.histogram("dht_search_position_passes", mode=mode).observe(
+            int(np.max(passes)))
     reg.histogram("dht_search_hops", mode=mode).observe_many(hops)
     # one value a wave: on a mesh the slowest q-rank's (each cuts when
     # its own survivors fit)

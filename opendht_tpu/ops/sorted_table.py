@@ -259,7 +259,7 @@ def _lex_lt(g, q_l, limbs: int):
 
 def _lower_bound(sorted_ids, queries, n_valid, lut=None,
                  lut_steps: int = LUT_BUCKET_STEPS,
-                 limbs: int = N_LIMBS):
+                 limbs: int = N_LIMBS, sorted_t=None):
     """First index i in [0, n_valid] with sorted_ids[i] >= q, batched.
 
     Fixed-depth binary search (static ceil(log2 N)+1 steps) — no
@@ -273,6 +273,10 @@ def _lower_bound(sorted_ids, queries, n_valid, lut=None,
     then the lower bound in the TRUNCATED key order; see
     core/search.py ``_guarded_lower_bound`` for the exact-correction
     construction (truncated search + one full-width compare step).
+
+    ``sorted_t``: the finished ``[limbs, N]`` view the probes read, from
+    a caller that takes it once for several searches (parallel/sharded.py
+    ``window_lower_bound``); None takes it here.
     """
     N = sorted_ids.shape[0]
     Q = queries.shape[0]
@@ -293,7 +297,8 @@ def _lower_bound(sorted_ids, queries, n_valid, lut=None,
     # gather probe rows limb-planar from the transposed table: a [Q, 5]
     # row gather pads 5 lanes → 128 in TPU tiled layout; [5, Q] columns
     # stay unpadded and the lex compare runs on 1-D planes
-    sorted_t = sorted_ids.T[:limbs]                          # [limbs, N]
+    if sorted_t is None:
+        sorted_t = sorted_ids.T[:limbs]                      # [limbs, N]
     q_l = [queries[:, l] for l in range(limbs)]
 
     def body(_, lohi):

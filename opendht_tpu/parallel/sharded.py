@@ -64,7 +64,8 @@ from ..ops.ids import N_LIMBS
 from ..ops.xor_topk import xor_topk, select_topk, mask_invalid
 from ..ops.sorted_table import (sort_table, window_topk, build_prefix_lut,
                                 default_lut_bits, expand_table, expanded_topk,
-                                fused_gather_planar, loop_gather_view, _EROW)
+                                fused_gather_planar, loop_gather_view, _EROW,
+                                _lex_lt)
 from ..core.search import (simulate_lookups, _lookup_engine, _run_wave,
                            _guarded_lower_bound, _lut_block_bounds,
                            TARGET_NODES, ALPHA, SEARCH_NODES)
@@ -493,6 +494,71 @@ def window_gather(view, rows, base, n_owned, capacity: int, limbs: int,
     return planes, (passes <= 1).astype(jnp.int32)
 
 
+def window_lower_bound(sorted_ids, n, lut, n_t: int):
+    """One shard's part of a wave's POSITIONING, over a lane window:
+    ``part(targets [M, 5])`` gives ``(lower, passes)``, ``lower`` [M]
+    int32 the count of the shard's ``n`` valid sorted rows below each
+    target — ``_guarded_lower_bound``'s answer, bit for bit — and
+    ``passes`` the lane windows it searched (int32).
+
+    Where a shard's key range does not hold a target its count needs no
+    search: 0 where the target is at or below the shard's first row,
+    ``n`` where it is above its last.  A search's probes cost a lane
+    HBM's price whatever the answer, and the lanes whose answer is an
+    edge all start from the same LUT bucket, so their gathers crowd
+    into few tiles (PERF.md §7 (1)).  So:
+
+    - every lane is compared with the shard's first and last valid row
+      (two 160-bit compares, two rows read), which answers the lanes
+      outside the range and marks the lanes inside it;
+    - the guarded search runs over ``window_width(M, t)`` lanes at a
+      time, in as many passes as cover the marked lanes
+      (:func:`lane_window`), each written over the compared values.
+      The passes are unrolled to their most, ``ceil(M / width)``, each
+      behind a ``cond``, and not a loop: the search's rarer tiers slice
+      the table, and a slice in a loop body is a table-sized copy a trip
+      (ops.sorted_table ``loop_gather_view``).  Its closure is built
+      here, once: the guard's scans and the ``[2, rows]`` view that
+      its fast path's probes and correction read (the slice the
+      engine's gathers take of a shard, so the compiler takes it once)
+      lie outside the passes, and no pass copies the table.
+
+    On a wave grouped by home shard the marked lanes are the shard's
+    home run, and one pass covers them; a skewed wave takes up to ``t``
+    passes, an empty shard none.  A wave under ``WINDOW_MIN_LANES`` is
+    the one full-width search it always was, one pass.  The pass count
+    is this shard's own: keep every collective outside.
+    """
+    lower = _guarded_lower_bound(sorted_ids, n, lut, sorted_ids.T[:2])
+    nn = jnp.asarray(n, jnp.int32)
+
+    def part(targets):
+        lanes = targets.shape[0]
+        width = window_width(lanes, n_t)
+        if width >= lanes:
+            return lower(targets), jnp.int32(1)
+        t_l = [targets[:, l] for l in range(N_LIMBS)]
+        first = sorted_ids[0][:, None]
+        last = sorted_ids[jnp.maximum(nn - 1, 0)][:, None]
+        above = _lex_lt(last, t_l, N_LIMBS)
+        inside = (nn > 0) & _lex_lt(first, t_l, N_LIMBS) & ~above
+        start, passes = lane_window(inside, width)
+
+        def one_pass(p, lb):
+            at = jnp.minimum(start + p * width, lanes - width)
+            return lax.dynamic_update_slice_in_dim(
+                lb, lower(lax.dynamic_slice_in_dim(targets, at, width)), at,
+                axis=0)
+
+        lb = jnp.where(above, nn, 0)
+        for p in range(-(-lanes // width)):
+            lb = lax.cond(p < passes, functools.partial(one_pass, p),
+                          lambda lb: lb, lb)
+        return lb, passes
+
+    return part
+
+
 def lane_chunk(lanes: int, n_t: int) -> int:
     """The lanes of a ``q``-rank's wave that ONE ``t``-rank runs the
     engine over (:func:`build_tp_lookup`, THE SEARCH STATE), a static
@@ -534,7 +600,7 @@ def lane_exchange(owner_read, chunked: bool, stage: Optional[str] = None,
     (:func:`window_gather` windows the minor one).  Not ``chunked`` (a
     chunk that is the whole wave): no gather and no slice, the ``psum``
     it always was.  Both collectives run under device stage ``stage``
-    (none: unstaged, as the positioning's always was); ``has_aux``:
+    (none: unstaged); ``has_aux``:
     ``owner_read`` returns ``(part, aux)`` and ``aux`` — this shard's
     own report, no lane's — comes back beside the answer.
     """
@@ -593,10 +659,11 @@ def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int,
     ``alive_merge``, inside the engine's ``expire``.
     ``delta_window(targets)``: the targets' place in the global order of
     the deltas is the sum of their places in the shards' (as ``lower``
-    is for the base), and the ``DELTA_WINDOW`` rows around it are
-    fetched by their owners — a window that straddles a shard edge takes
-    rows of both — and summed: two exchanges, stage ``delta_merge``,
-    inside the engine's ``delta_window``.
+    is for the base, each shard's over a lane window:
+    :func:`window_lower_bound`), and the ``DELTA_WINDOW`` rows around it
+    are fetched by their owners — a window that straddles a shard edge
+    takes rows of both — and summed: two exchanges, stage
+    ``delta_merge``, inside the engine's ``delta_window``.
     """
     total = n_t * shard_n                  # where the delta's nodes start
     d_base, d_total = device_stage("delta_merge")(
@@ -617,8 +684,8 @@ def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int,
     def alive(nodes):
         return gone(nodes, nodes.ndim - 1) == 0
 
-    place = lane_exchange(_guarded_lower_bound(delta, n_delta, delta_lut),
-                          chunked, "delta_merge")
+    place = lane_exchange(window_lower_bound(delta, n_delta, delta_lut, n_t),
+                          chunked, "delta_merge", has_aux=True)
     delta_t = delta.T
 
     def owner_rows(slot):
@@ -629,7 +696,7 @@ def _tp_churn_primitives(shard_n: int, delta_rows: int, n_t: int,
     rows = lane_exchange(owner_rows, chunked, "delta_merge")
 
     def delta_window(targets):
-        slot = (place(targets)[None, :] - DELTA_WINDOW // 2
+        slot = (place(targets)[0][None, :] - DELTA_WINDOW // 2
                 + jnp.arange(DELTA_WINDOW, dtype=jnp.int32)[:, None])
         ids = rows(slot, 1, 2)
         node = jnp.where((slot >= 0) & (slot < d_total), total + slot, -1)
@@ -672,7 +739,7 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     ``t``, the shard's part computed for the whole wave as before, the
     parts summed back to the lanes' shard: ``gather_planar``
     (stage ``owner_merge``: the round's two collectives), ``lower``
-    (positioning, once a wave) and under churn ``alive`` and
+    (stage ``position``, once a wave) and under churn ``alive`` and
     ``delta_window``.  ``block_bounds`` needs nothing: the block LUT is
     the replicated GLOBAL prefix LUT (``shard_table_state`` assembled
     it with a single one-shot psum of the per-shard LUTs at table-build
@@ -722,16 +789,22 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
     a window every lane reads a row of the shard
     (:func:`owner_local_index`): a lane that is not owned reads a spare
     row of its own, never the one row a clip would send them all to,
-    and is zeroed before the collective.  The lookup-major final fetch
-    and the churn primitives' owner reads keep one full-width pass.
+    and is zeroed before the collective.  The positioning runs the
+    same way (:func:`window_lower_bound`): a shard answers the targets
+    outside its key range by two compares and searches its home run,
+    in one window of a grouped wave, for the base and under churn for
+    its delta.  The lookup-major final fetch and the churn primitives'
+    other owner reads keep one full-width pass.
     The program also returns ``window_rounds``, one count a ``q``-rank:
     the wave's in-loop round gathers that EVERY shard served in one
     pass (the engine sums each shard's report, one ``pmin`` over ``t``
     once a wave) — the wave's loop rounds where the grouping holds —
     and ``home_lanes``, the lanes of the rank's wave that run on their
     home shard (all but a few hundred of a uniform wave's; a skewed
-    wave reads low, and says why ``window_rounds`` fell).  Every other
-    output bit is the one-chip engine's.
+    wave reads low, and says why ``window_rounds`` fell), and
+    ``position_passes``, the most windows a shard's positioning took
+    (the engine's optional count of ``lower``, one ``pmax`` over ``t``).
+    Every other output bit is the one-chip engine's.
 
     ``delta_rows`` is part of the geometry like ``shard_n``: the delta
     slab of each shard of a table under membership CHURN
@@ -768,9 +841,11 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
             base = (ti * shard_n).astype(jnp.int32)
             n = jnp.asarray(n_valid, jnp.int32)
             n_local = jnp.clip(n - base, 0, shard_n)
-        local_lower = _guarded_lower_bound(sorted_shard, n_local,
-                                           local_lut[0])
         sorted_t = sorted_shard.T                        # [5, shard_n]
+        # the positioning's part, built here: its guard's scans over the
+        # shard run once a wave, under no stage as they always did
+        position = window_lower_bound(sorted_shard, n_local, local_lut[0],
+                                      n_t)
         # what the gathers read, decided once: gather_planar runs inside
         # the engine's while_loop bodies, where a slice of a shard too
         # large for on-chip memory (25M rows: 200 MB) is copied every
@@ -789,7 +864,12 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
         # order is the in-order concatenation of shard ranges — one
         # [M]-int32 exchange over the table axis.  Called ONCE per wave
         # (the pre-loop target positioning), never inside the hop loop.
-        lower = lane_exchange(local_lower, chunked)
+        # A shard searches only the targets inside its key range, over
+        # a lane window (window_lower_bound: one pass on a grouped
+        # wave), and reports its passes — the engine's optional count;
+        # the search and its exchange are stage position
+        lower = lane_exchange(device_stage("position")(position), chunked,
+                              "position", has_aux=True)
 
         def block_bounds(t0, prefix_len):
             # ZERO collectives: the block LUT is the replicated GLOBAL
@@ -941,19 +1021,22 @@ def build_tp_lookup(mesh: Mesh, shard_n: int, q_total: int, k: int,
         out.update(per_lookup, home_lanes=home_lanes)
         # the wave's in-loop gathers that EVERY shard served in one pass
         out["window_rounds"] = lax.pmin(out["window_rounds"], "t")
+        # the most lane windows any shard's positioning searched
+        out["position_passes"] = lax.pmax(out["position_passes"], "t")
         # one count a q-rank (t-ranks run the same rounds and cut in the
         # same one; the other counts are sums or the least over them)
         return {name: value[None] if name in per_rank else value
                 for name, value in out.items()}
 
     per_rank = ("narrow_rounds", "expired_peers", "window_rounds",
-                "home_lanes")
+                "home_lanes", "position_passes")
     in_specs = ((P("t", None), P("t", None), P(), P(), P("t", None),
                  P("q", None), P()) if weighted else
                 (P("t", None), P("t", None), P(), P(), P("q", None), P()))
     out_specs = {"nodes": P("q", None), "dist": P("q", None, None),
                  "hops": P("q"), "converged": P("q"), "narrow_rounds": P("q"),
-                 "window_rounds": P("q"), "home_lanes": P("q")}
+                 "window_rounds": P("q"), "home_lanes": P("q"),
+                 "position_passes": P("q")}
     if delta_rows:
         in_specs += (P("t"), P("t", None), P("t"), P("t", None))
         out_specs["expired_peers"] = P("q")
@@ -989,7 +1072,8 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
     distributed primitives cheap:
 
     - positioning (once per wave): global lower_bound = ONE sum of
-      per-shard local counts;
+      per-shard local counts, each shard searching only the targets
+      inside its key range (:func:`window_lower_bound`);
     - reply-block edges (per hop): two LOCAL reads of the replicated
       global block LUT — ZERO collectives (see
       :func:`build_tp_lookup`);
@@ -1014,9 +1098,12 @@ def tp_simulate_lookups(mesh: Mesh, sorted_ids=None, n_valid=None,
     the rounds after the cut of a CHUNK (``NARROW_MIN_WAVE`` applies to
     the chunk's width).  The result also carries ``window_rounds`` [q]:
     the wave's loop rounds in which every shard gathered in one pass
-    (``dht_search_window_rounds{mode="tp"}``), and ``home_lanes`` [q]:
+    (``dht_search_window_rounds{mode="tp"}``), ``home_lanes`` [q]:
     the lanes that ran on the shard whose key range holds their target
-    (``dht_search_home_lanes{mode="tp"}``).
+    (``dht_search_home_lanes{mode="tp"}``), and ``position_passes`` [q]:
+    the most lane windows a shard searched to position the wave's
+    targets (``dht_search_position_passes{mode="tp"}``: 1 on a grouped
+    wave, :func:`window_lower_bound`).
 
     Callers serving a stable table should pass ``state=`` from
     ``sharded_global_sort`` or

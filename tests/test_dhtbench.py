@@ -171,7 +171,8 @@ HOST4_REGISTRY_METRICS = {"host4_record_ms_per_wave",
                           "host4_dispatch_ms_per_wave", "host4_table_build_s",
                           "host4_narrow_rounds_per_wave",
                           "host4_window_rounds_per_wave",
-                          "host4_home_lanes_per_wave"}
+                          "host4_home_lanes_per_wave",
+                          "host4_position_passes_per_wave"}
 
 
 @pytest.mark.parametrize("trace", [False, True])
@@ -270,7 +271,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
              for f in sorted(os.listdir(mdir))}
     staged = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage"}
-    assert len(staged) == 33
+    assert len(staged) == 35
     assert {name: s["stage"] for name, s in staged.items()
             if s["value"] == "stage_ms_per"} == {
         "sim_fetch_ids_ms_per_wave": "fetch_ids",
@@ -282,6 +283,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
         "host4_fetch_ids_ms_per_wave": "fetch_ids",
         "host4_block_bounds_ms_per_wave": "block_bounds",
         "host4_merge_ms_per_wave": "merge",
+        "host4_position_ms_per_wave": "position",
         "churn_fetch_ids_ms_per_wave": "fetch_ids",
         "churn_expire_ms_per_wave": "expire",
         "churn_delta_window_ms_per_wave": "delta_window",
@@ -301,7 +303,8 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
         "host4churn_apply_ms_per_wave": "table_apply",
         "host4churn_compact_ms_per_wave": "table_compact",
         "host4churn_compact_ms": "table_compact",
-        "host4churn_relayout_ms": "table_relayout"}
+        "host4churn_relayout_ms": "table_relayout",
+        "host4churn_position_ms_per_wave": "position"}
     # one compaction's time is over the compactions, not over the waves:
     # it does not move with the waves' speed or the window's length
     assert {name for name, s in staged.items() if s.get("per") != "waves"
@@ -320,7 +323,8 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
     from opendht_tpu.ops import churn_table
     from opendht_tpu.parallel import sharded
     import inspect
-    assert '"owner_merge"' in inspect.getsource(sharded.build_tp_lookup)
+    for name in ("owner_merge", "position"):
+        assert f'"{name}"' in inspect.getsource(sharded.build_tp_lookup)
     for name in ("expire", "delta_window", "tile"):
         assert f'device_stage("{name}")' in inspect.getsource(search)
     for name in ("table_apply", "table_compact"):
@@ -332,7 +336,7 @@ def test_each_stage_metric_file_names_a_stage_of_the_program():
     assert {s["stage"] for s in staged.values() if "stage" in s} \
         <= set(STAGES) | {"owner_merge", "expire", "delta_window",
                           "table_apply", "table_compact", "table_route",
-                          "table_relayout", "tile"}
+                          "table_relayout", "tile", "position"}
     # the one metric that divides by a stage's time and not the window's
     shares = {name: m["source"] for name, m in specs.items()
               if m["source"]["kind"] == "stage_share"}
@@ -510,7 +514,7 @@ def test_every_metric_of_the_tp_churn_cell_is_in_the_manifest(manifest):
     assert config["driver"] == "sim_tp_churn" and _cell["chips"] == 4
     listed = {m["name"] for m in manifest["per_layer"]
               if cell in m.get("workloads", ())}
-    assert listed == set(files) and len(listed) == 23
+    assert listed == set(files) and len(listed) == 24
     assert all(m["cells"] == [cell] for m in files.values())
     for m in manifest["end_to_end"]:
         if m["name"] != "setup_s":

@@ -1044,3 +1044,173 @@ def test_a_lookups_rounds_run_on_one_shard_by_the_lowered_shapes():
         alpha * k, W, alpha * k, W // t) in text
     assert '"stablehlo.all_reduce"' in text
     assert '"stablehlo.all_gather"' in text
+
+
+# -- PR 46: the positioning over a lane window (parallel/sharded.py
+# window_lower_bound) ---------------------------------------------------------
+# A shard answers the targets outside its key range by two compares and
+# searches the rest over lane windows: every lane must read what the
+# full-width search gives, on every shard, whatever the wave or table.
+
+POSITION_Q = 4096
+POSITION_CASES = ["grouped", "edge_rows", "top32", "top64", "one_shard",
+                  "empty_shard", "small_wave", "tie64", "clustered"]
+
+
+def _positioning_parts(mesh, sorted_ids, n_valid, targets):
+    """Every shard's windowed positioning, its passes, and its
+    full-width ``_guarded_lower_bound``, over the uniform row split of a
+    sorted table: ``[t, M]``, ``[t]``, ``[t, M]``."""
+    from jax import lax
+    from jax.sharding import PartitionSpec as P
+    from opendht_tpu.core.search import _guarded_lower_bound
+    from opendht_tpu.parallel import shard_table_state
+    from opendht_tpu.parallel.sharded import window_lower_bound
+    state = shard_table_state(mesh, sorted_ids, n_valid)
+    shard_n, n_t = state.shard_n, mesh.shape["t"]
+
+    def local(rows, lut, n, targets):
+        n_local = jnp.clip(n - lax.axis_index("t") * shard_n, 0, shard_n)
+        part, passes = window_lower_bound(rows, n_local, lut[0], n_t)(targets)
+        full = _guarded_lower_bound(rows, n_local, lut[0])(targets)
+        return part[None], passes[None], full[None]
+
+    fn = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P("t", None), P("t", None), P(), P()),
+        out_specs=(P("t", None), P("t"), P("t", None)), check_vma=False))
+    a = state.arrays
+    return [np.asarray(x) for x in fn(a["sorted_ids"], a["local_lut"],
+                                      a["n_valid"], jnp.asarray(targets))]
+
+
+def _keys(ids):
+    """160-bit ids as byte strings that sort as the ids do."""
+    return np.ascontiguousarray(ids.astype(">u4")).view("S20").ravel()
+
+
+def _step(ids, delta):
+    """160-bit ``ids + delta``, wrapping."""
+    out = []
+    for row in ids.tolist():
+        v = (int.from_bytes(np.array(row, ">u4").tobytes(), "big")
+             + delta) % 2 ** 160
+        out.append(np.frombuffer(v.to_bytes(20, "big"), ">u4"))
+    return np.array(out, np.uint32)
+
+
+@pytest.mark.parametrize("case", POSITION_CASES)
+def test_windowed_positioning_is_the_full_width_search_on_every_shard(case):
+    """The windowed part of every shard equals ``_guarded_lower_bound``'s
+    full-width part lane for lane, and both the count of the shard's
+    valid rows below each target: on a grouped uniform wave (one pass a
+    shard); on targets equal to each shard's first and last row and
+    both ± 1; on targets that share the top 32 or 64 bits with a shard's
+    first row (where the top-limb home hint is off); on a wave skewed
+    into one shard (``t`` passes there, none elsewhere); with an empty
+    shard (no pass, all 0); on a wave under ``WINDOW_MIN_LANES`` (the
+    full-width search, one pass); and on tables that send the guard to
+    its slower tiers (64-bit ties; LUT buckets past the step budget)."""
+    from opendht_tpu.parallel.sharded import window_width
+    if len(jax.devices()) < 8:
+        pytest.skip("needs 8 virtual devices")
+    t, rows = 4, 16384
+    mesh = make_mesh(t, q=1, t=t)
+    rng = np.random.default_rng(4600 + POSITION_CASES.index(case))
+    ids = _rand_ids(rng, rows)
+    if case == "tie64":
+        ids[100:4200, :2] = ids[100, :2]     # ties within and across shards
+    elif case == "clustered":
+        ids[:12000, 0] = 0x7777AAAA          # buckets far past the budget
+    n_valid = 12000 if case == "empty_shard" else rows
+    sorted_ids = np.asarray(sort_table(jnp.asarray(ids[:n_valid]))[0])
+    sorted_ids = pad_to_multiple(sorted_ids, rows)[0]
+    shard_n = rows // t
+    n_local = np.clip(n_valid - np.arange(t) * shard_n, 0, shard_n)
+    first = sorted_ids[::shard_n]
+    last = sorted_ids[np.arange(t) * shard_n + np.maximum(n_local - 1, 0)]
+    lanes = 1024 if case == "small_wave" else POSITION_Q
+    targets = _rand_ids(rng, lanes)
+    if case in ("grouped", "edge_rows", "top32", "top64"):
+        # a uniform wave grouped by home as the program groups it, a
+        # quarter of the lanes a home: each shard's run is one window
+        draw = _rand_ids(rng, 4 * lanes)
+        home = (draw[:, 0][:, None] >= first[1:, 0][None, :]).sum(axis=1)
+        targets = np.concatenate([draw[home == s][:lanes // t]
+                                  for s in range(t)])
+    if case == "edge_rows":
+        # each kind of edge target in a tile of its own, in shard 0's
+        # run: for every other shard the lanes it must search lie
+        # outside its home run, and an edge taken for the wrong side
+        # leaves them outside its windows
+        for at, edge in enumerate((last, _step(first, 1), _step(last, -1),
+                                   first, _step(first, -1), _step(last, 1))):
+            targets[128 * at:128 * at + t] = edge
+    elif case in ("top32", "top64"):
+        # in each home's run, targets that share the top limbs of the
+        # home's first row: the home hint is the shard's, and those
+        # below its first row are the shard before's
+        shared = 1 if case == "top32" else 2
+        for s in range(t):
+            run = s * (lanes // t) + rng.choice(lanes // t, 128,
+                                                replace=False)
+            targets[run, :shared] = first[s, :shared]
+    elif case == "one_shard":
+        lo, hi = int(first[2, 0]) + 1, int(last[2, 0])
+        targets[:, 0] = rng.integers(lo, hi, lanes, dtype=np.uint32)
+    elif case == "tie64":
+        targets[:lanes // 4, :2] = ids[100, :2]
+    elif case == "clustered":
+        targets[:lanes // 2, 0] = 0x7777AAAA
+    part, passes, full = _positioning_parts(mesh, sorted_ids, n_valid,
+                                            targets)
+    np.testing.assert_array_equal(part, full)
+    for s in range(t):
+        mine = _keys(sorted_ids[s * shard_n:s * shard_n + n_local[s]])
+        np.testing.assert_array_equal(
+            part[s], np.searchsorted(mine, _keys(targets)), err_msg=str(s))
+    width = window_width(lanes, t)
+    inside = [((_keys(targets) > _keys(first[s:s + 1]))
+               & (_keys(targets) <= _keys(last[s:s + 1]))).any()
+              and n_local[s] > 0 for s in range(t)]
+    if case == "small_wave":
+        assert width == lanes and passes.tolist() == [1] * t
+    else:
+        assert width < lanes
+        assert [bool(p) for p in passes] == inside
+    if case == "grouped":
+        assert passes.tolist() == [1] * t
+    elif case == "one_shard":
+        assert passes.tolist() == [0, 0, t, 0]
+    elif case == "empty_shard":
+        assert passes[3] == 0 and (part[3] == 0).all()
+
+
+@pytest.mark.parametrize("wave", ["grouped", "one_home"])
+def test_position_passes_count_the_windows_a_shard_searched(window_network,
+                                                            wave):
+    """``position_passes``, the most lane windows any shard's positioning
+    searched: 1 on a grouped wave whose homes are balanced, ``t`` on a
+    wave whose targets all lie in one shard's key range — and the wave's
+    outputs are the one-chip engine's either way.  ``record_wave`` files
+    it as ``dht_search_position_passes{mode="tp"}``."""
+    from opendht_tpu import telemetry
+    _ids, sorted_ids, n_valid, mesh, state = window_network
+    targets = _uniform_targets(4610)
+    if wave == "grouped":
+        home = np.arange(WINDOW_Q, dtype=np.uint32) % 4
+        np.random.default_rng(4611).shuffle(home)
+        targets[:, 0] = (home << 30) | (np.uint32(1) << 29) | (
+            targets[:, 0] >> 3)
+        want = 1
+    else:
+        targets[:, 0] = (targets[:, 0] >> 2) | np.uint32(2 << 30)
+        want = mesh.shape["t"]
+    reg = telemetry.get_registry()
+    before = reg.snapshot()
+    out = tp_simulate_lookups(mesh, targets=targets, state=state,
+                              **WINDOW_KW)
+    _assert_equals_one_chip(out, sorted_ids, n_valid, targets)
+    assert np.asarray(out["position_passes"]).tolist() == [want]
+    moved = telemetry.snapshot_diff(before, reg.snapshot())["histograms"]
+    filed = moved['dht_search_position_passes{mode="tp"}']
+    assert filed["count"] == 1 and filed["sum"] == want

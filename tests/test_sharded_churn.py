@@ -451,5 +451,65 @@ def test_the_new_stages_are_named_where_they_run():
         a["shard_rows"], jnp.asarray(_ids(rng, 64)), jnp.int32(1),
         a["tomb_bits"], a["delta"], a["n_delta"], a["delta_lut"]).as_text()
     for name in ("alive_merge", "delta_merge", "expire", "delta_window",
-                 "owner_merge"):
+                 "owner_merge", "position"):
         assert f"stage_{name}" in lowered
+
+
+@pytest.mark.parametrize("order", ["grouped", "shuffled"])
+def test_the_deltas_place_over_lane_windows_is_the_full_width_one(order):
+    """The delta's positioning (``_tp_churn_primitives``' ``place``)
+    runs over lane windows (``window_lower_bound``): on every shard it
+    gives what the full-width ``_guarded_lower_bound`` gives, the count
+    of the shard's delta rows below each target — with arrivals in
+    three shards' key ranges only, so that the fourth's delta is empty
+    (no pass, all 0) — on a wave grouped by key range (one pass a
+    shard) and on the same wave shuffled."""
+    from jax.sharding import PartitionSpec as P
+    from opendht_tpu.core.search import _guarded_lower_bound
+    from opendht_tpu.parallel.sharded import window_lower_bound, window_width
+    t, lanes = 4, 4096
+    rng = np.random.default_rng(4620)
+    mesh, tb, _one, book = _pair(rng, t)
+    join = _ids(rng, 400)
+    join[:, 0] %= np.uint32(3 << 30)           # no arrival in shard 3's
+    _tick((tb,), book, rng, 40, 0, join=join)
+    a = tb.view.arrays
+    n_delta = np.asarray(a["n_delta"])
+    assert n_delta[3] == 0 and (n_delta[:3] > 0).all()
+    delta = np.asarray(a["delta"]).reshape(t, -1, 5)
+    # a quarter of the lanes a key range, a hundred of each delta's own
+    # rows among them
+    targets = _ids(rng, lanes)
+    home = np.repeat(np.arange(t, dtype=np.uint32), lanes // t)
+    targets[:, 0] = (home << 30) | (targets[:, 0] >> 2)
+    for s in range(t - 1):
+        run = s * (lanes // t) + rng.choice(lanes // t, 100, replace=False)
+        targets[run] = delta[s][rng.integers(0, n_delta[s], 100)]
+    if order == "shuffled":
+        rng.shuffle(targets)
+
+    def local(delta, n, lut, targets):
+        part, passes = window_lower_bound(delta, n[0], lut[0], t)(targets)
+        full = _guarded_lower_bound(delta, n[0], lut[0])(targets)
+        return part[None], passes[None], full[None]
+
+    fn = jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P("t", None), P("t"), P("t", None), P()),
+        out_specs=(P("t", None), P("t"), P("t", None)), check_vma=False))
+    part, passes, full = (np.asarray(x) for x in fn(
+        a["delta"], a["n_delta"], a["delta_lut"], jnp.asarray(targets)))
+    assert window_width(lanes, t) < lanes
+    np.testing.assert_array_equal(part, full)
+
+    def keys(ids):
+        return np.ascontiguousarray(ids.astype(">u4")).view("S20").ravel()
+
+    for s in range(t):
+        np.testing.assert_array_equal(
+            part[s], np.searchsorted(keys(delta[s][:n_delta[s]]),
+                                     keys(targets)), err_msg=str(s))
+    assert passes[3] == 0 and (part[3] == 0).all()
+    if order == "grouped":
+        assert passes.tolist() == [1, 1, 1, 0]
+    else:
+        assert (passes[:3] >= 2).all()
